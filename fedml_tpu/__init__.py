@@ -53,6 +53,11 @@ def init(args: Optional[Arguments] = None, check_env: bool = True) -> Arguments:
     from fedml_tpu.parallel.multihost import maybe_initialize_multihost
 
     maybe_initialize_multihost(args)
+    # persistent XLA cache at JAX_COMPILATION_CACHE_DIR or the fixed
+    # in-checkout default — before the first compile of the run
+    from fedml_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     # per-silo override yamls (parity: _update_client_specific_args /
     # hierarchical server/client_silo config paths)
     from fedml_tpu.arguments import update_client_specific_args

@@ -17,7 +17,8 @@ Design notes (pallas_guide.md):
   instead of materialising repeated K/V in HBM;
 - causal masking skips whole kv blocks past the diagonal via ``pl.when``
   (compute is masked, the DMA pipeline stays regular);
-- off-TPU (CPU tests) the same kernels run under ``interpret=True``.
+- off-TPU (CPU tests) the same kernels run under ``interpret=True``;
+  which form a call gets is decided in ``ops/dispatch.py``.
 
 The public entry is :func:`flash_attention` — identical math to
 ``jax.nn.dot_product_attention`` for supported shapes, verified by tests.
@@ -31,22 +32,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs no TPU
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from fedml_tpu.ops.dispatch import INTERPRET, REFERENCE, kernel_mode
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -395,14 +385,36 @@ def flash_attention(
 ) -> jax.Array:
     """Tiled online-softmax attention. q: [B,H,T,D]; k/v: [B,Hkv,S,D].
 
-    Dispatches to the Pallas kernels on TPU; off-TPU it uses the plain-XLA
-    reference path (the kernels still run under ``interpret=True`` when
-    forced, which is how the unit tests exercise them on CPU).
+    ``interpret=None`` (model code): the compiled kernels on a TPU, the
+    plain-XLA reference elsewhere. ``interpret=True`` runs the kernels
+    under the Pallas interpreter (CPU unit tests; refused on a TPU);
+    ``interpret=False`` always emits the compiled kernels — see
+    :mod:`fedml_tpu.ops.dispatch`.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        if not _on_tpu():
-            return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-        interpret = False
-    return _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+    mode = kernel_mode(interpret, off_tpu=REFERENCE)
+    if mode == REFERENCE:
+        return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    return _flash(q, k, v, sm_scale, causal, block_q, block_k,
+                  mode == INTERPRET)
+
+
+def make_sharded_flash_attention(mesh, batch_axes, head_axis,
+                                 causal: bool = True):
+    """:func:`flash_attention` as an ``attention_fn(q, k, v)`` for a
+    program GSPMD partitions over ``mesh``.
+
+    The SPMD partitioner refuses a bare Mosaic call ("Mosaic kernels
+    cannot be automatically partitioned"), so the call sits in a
+    ``shard_map``: each device runs the kernel on its own batch rows
+    (``batch_axes``) and heads (``head_axis``). Attention mixes neither,
+    so there is no communication and q/k/v are never gathered. On a
+    one-device mesh the map is the identity.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(batch_axes, head_axis, None, None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)

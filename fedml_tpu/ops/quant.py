@@ -36,6 +36,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from fedml_tpu.ops.dispatch import INTERPRET, kernel_mode
 
 
 @jax.tree_util.register_pytree_node_class
@@ -343,11 +346,6 @@ def quantize_params_int4(params: Any, fmt: str = "int4",
 # int8 (half the bytes — decode is weight-bandwidth-bound), convert
 # in-VMEM on the VPU, and feed the MXU in bf16. Scales fold into outputs.
 
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs no TPU
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 # VMEM budget for the weight tile: scoped vmem is 16 MB, and the tile
 # shares it with x, the accumulator, and the output block
 _TILE_BYTES = 6 * 1024 * 1024
@@ -388,9 +386,14 @@ def _dequant_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref):
                       ).astype(o_ref.dtype)
 
 
-def pallas_dequant_matmul(x, q, scale, dtype):
+def pallas_dequant_matmul(x, q, scale, dtype, interpret=None):
     """``(x @ dequant(q)) * scale`` with the convert fused into the tile
-    load. x: [B, H] (or [..., H], flattened), q: int8 [H, F], scale [F]."""
+    load. x: [B, H] (or [..., H], flattened), q: int8 [H, F], scale [F].
+
+    ``interpret=None``: compiled on a TPU, the Pallas interpreter
+    elsewhere (CPU tests); ``False`` always emits the compiled kernel —
+    see :mod:`fedml_tpu.ops.dispatch`. Shapes the kernel was not written
+    for (below) take the XLA dequant lowering on every backend."""
     lead = x.shape[:-1]
     h, f = q.shape
     bh, bf = _pick_tiles(h, f)
@@ -402,7 +405,7 @@ def pallas_dequant_matmul(x, q, scale, dtype):
     # The kernel's MXU dot runs on bf16 operands, so it is exact only for
     # bf16 compute — fp32 requests take the XLA dequant lowering instead
     # of silently truncating activations (ADVICE r4).
-    if (bh == 0 or rows > 128 or pltpu is None
+    if (bh == 0 or rows > 128
             or jnp.dtype(dtype) != jnp.dtype(jnp.bfloat16)):
         return (x.reshape(*lead, h) @ q.astype(dtype)) * scale.astype(dtype)
     x2 = x.reshape(-1, h).astype(jnp.bfloat16)
@@ -418,7 +421,7 @@ def pallas_dequant_matmul(x, q, scale, dtype):
         out_specs=pl.BlockSpec((b, bf), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, f), dtype),
         scratch_shapes=[pltpu.VMEM((b, bf), jnp.float32)],
-        interpret=jax.devices()[0].platform != "tpu",  # CPU tests
+        interpret=kernel_mode(interpret, off_tpu=INTERPRET) == INTERPRET,
     )(x2, q, scale.reshape(1, f))
     return out.reshape(*lead, f)
 

@@ -57,6 +57,7 @@ logger = logging.getLogger(__name__)
 __all__ = [
     "MultichipPlan",
     "agg_mesh",
+    "efficiency_basis",
     "is_single_core_virtual_mesh",
     "plan_multichip",
     "shard_stacked",
@@ -86,6 +87,19 @@ def is_single_core_virtual_mesh(n_devices: Optional[int] = None) -> bool:
     except Exception:  # pragma: no cover - backend init failure
         return False
     return n > 1 and n > (os.cpu_count() or 1)
+
+
+def efficiency_basis(devices) -> str:
+    """What a scaling sweep over ``devices`` can honestly report.
+
+    Decided by the device PLATFORM, never by how many host cores there
+    are: virtual CPU devices share one host's memory system whatever the
+    core count, so ``wall_1 / wall_N`` (``serialized-virtual-mesh``,
+    ideal 1.0 — pure partition overhead) is all they measure. Only real
+    accelerators report ``wall-clock`` (``wall_1 / (N * wall_N)``).
+    """
+    return ("serialized-virtual-mesh" if devices[0].platform == "cpu"
+            else "wall-clock")
 
 
 @dataclass

@@ -49,9 +49,9 @@ _cache_counters_lock = threading.Lock()
 def install_compile_cache_counters() -> None:
     """Count XLA compiles and compilation-cache traffic as typed counters.
 
-    Installed once per process. The jax compilation-cache events — which
-    differ across jax versions — are matched by substring so
-    hits/misses/requests each land in their own counter on any 0.4.x.
+    Installed once per process. The jax compilation-cache events are
+    matched by substring so hits/misses/requests each land in their own
+    counter (a miss is an entry written; a hit is a compile skipped).
     (The number of actual backend compiles is already the ``count`` of
     the ``jax/compile_ms`` histogram the span layer maintains — no
     second duration listener needed.)

@@ -10,7 +10,7 @@ execution:
 
 - first call per input signature: ``jitted.lower(*args).compile()`` —
   exactly ONE backend compile (the jit path and the AOT path do not share
-  a cache in jax 0.4.x, so letting both run would double-compile), and
+  an executable cache, so letting both run would double-compile), and
   the executable's ``cost_analysis()`` FLOPs / bytes-accessed plus
   ``memory_analysis()`` argument/output/temp HBM come free off the same
   object;
@@ -21,8 +21,9 @@ execution:
   back to the keyed-variant slow path, and a brand-new signature becomes
   a new variant (that is the recompile counter treedef churn is read off).
 
-Anything that fails to lower/compile/execute through the AOT path falls
-back permanently to the raw jitted callable for that signature — the
+A call whose signature the AOT staging API rejects (``TypeError``) falls
+back permanently to the raw jitted callable for that signature; a
+compiler error is raised to the caller, once. The
 catalog records the fallback and the program still gets compile-time
 attribution via the ``jax.monitoring`` listener (compiles that fire while
 a cataloged call is on this thread's stack are booked to that program;
@@ -288,6 +289,14 @@ class CatalogedProgram:
         return self._jitted.lower(*args, **kwargs)
 
     @property
+    def last_compiled(self):
+        """The executable the most recent call ran (``None`` before the
+        first call, or when that signature fell back to the raw jit) —
+        ``as_text()`` / ``memory_analysis()`` without a second compile."""
+        last = self._last
+        return None if last is None or last.fallback else last.compiled
+
+    @property
     def name(self) -> str:
         return self._name
 
@@ -391,7 +400,13 @@ class CatalogedProgram:
         t0 = time.perf_counter()
         try:
             compiled = self._jitted.lower(*args, **kwargs).compile()
-        except Exception as e:  # AOT unsupported here — fall back forever
+        except TypeError as e:
+            # AOT staging rejected the call's signature (an argument the
+            # staged API cannot take) — fall back to the raw jit forever.
+            # Anything the COMPILER says (out of memory, a kernel it will
+            # not partition, a bad sharding) propagates: retrying it
+            # through the jit would pay the whole compile a second time
+            # to raise the same error.
             variant = _Variant(statics=statics, fallback=True)
             with self._lock:
                 self._variants[key] = variant
@@ -420,9 +435,7 @@ class CatalogedProgram:
     def _analyze(self, compiled, variant: _Variant) -> None:
         rec = self.record
         try:
-            cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-                cost = cost[0] if cost else {}
+            cost = compiled.cost_analysis() or {}
             variant.flops = float(cost.get("flops", 0.0) or 0.0)
             variant.bytes_accessed = float(
                 cost.get("bytes accessed", 0.0) or 0.0)
@@ -489,6 +502,11 @@ class ProgramCatalog:
         with self._lock:
             self._programs[name] = prog
         return prog
+
+    def program(self, name: str) -> Optional[CatalogedProgram]:
+        """The live wrapper registered under ``name`` (latest wins)."""
+        with self._lock:
+            return self._programs.get(name)
 
     # -- compile attribution (jax.monitoring) ------------------------------
     def on_compile_event(self, ms: float) -> None:

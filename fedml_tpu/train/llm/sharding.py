@@ -100,6 +100,11 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def data_parallel_size(mesh: Mesh) -> int:
+    """Devices the batch dimension is split over (the dp x fsdp extent)."""
+    return int(mesh.shape["dp"] * mesh.shape["fsdp"])
+
+
 def init_sharded_params(model, sample_tokens, mesh: Mesh, seed: int = 0,
                         zeros: bool = False):
     """Initialise parameters *already sharded* — no host-side full copy.

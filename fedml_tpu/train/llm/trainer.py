@@ -29,6 +29,7 @@ import optax
 from fedml_tpu.models.llm.llama import LlamaConfig, LlamaForCausalLM, causal_lm_loss
 from fedml_tpu.train.llm.sharding import (
     batch_sharding,
+    data_parallel_size,
     init_sharded_params,
     mesh_from_args,
     replicated,
@@ -97,8 +98,13 @@ class LLMTrainer:
         self.model = LlamaForCausalLM(cfg)
         self.mesh = mesh if mesh is not None else mesh_from_args(args)
         self.seq_len = int(getattr(args, "max_seq_length", 512))
-        self.batch_size = int(getattr(args, "per_device_batch_size",
-                                      getattr(args, "batch_size", 8)))
+        # per_device_batch_size is PER DEVICE: every [B, T] batch is split
+        # over the mesh's (dp, fsdp) axes, so the global batch this engine
+        # builds, samples and evaluates with is it x dp*fsdp — B=1 means
+        # one sequence per chip on one chip and on four
+        self.batch_size = int(getattr(
+            args, "per_device_batch_size",
+            getattr(args, "batch_size", 8))) * data_parallel_size(self.mesh)
         self.accum = int(getattr(args, "gradient_accumulation_steps", 1))
         self.lora_only = cfg.lora_rank > 0
 
@@ -151,26 +157,42 @@ class LLMTrainer:
         from fedml_tpu.train.llm.sharding import LOGICAL_RULES
 
         # sequence parallelism: when the mesh has an sp axis, attention runs
-        # as an explicit ring over the ICI instead of GSPMD's all-gather
+        # as an explicit ring over the ICI instead of GSPMD's all-gather.
+        # Otherwise the flash kernel runs per shard of the axes batch and
+        # heads already ride (GSPMD cannot partition a Mosaic call itself).
         attention_fn = None
         sp_size = dict(zip(self.mesh.axis_names, self.mesh.devices.shape)).get("sp", 1)
         if sp_size > 1 and bool(getattr(args, "use_ring_attention", True)):
             from fedml_tpu.parallel.ring_attention import make_ring_attention_fn
 
             attention_fn = make_ring_attention_fn(self.mesh, "sp", causal=True)
+        elif cfg.use_flash:
+            from fedml_tpu.ops.flash_attention import (
+                make_sharded_flash_attention,
+            )
+
+            rules = dict(LOGICAL_RULES)
+            attention_fn = make_sharded_flash_attention(
+                self.mesh, rules["batch"], rules["heads"])
 
         moe_aux_w = float(getattr(self.cfg, "moe_aux_weight", 0.01))
         is_moe = int(getattr(self.cfg, "num_experts", 0)) > 0
+
+        # the compiled programs outlive this object in the process-wide
+        # catalog: their closures hold the (param-free) module, never
+        # ``self`` — or every trainer's whole params tree would stay
+        # resident until the next one re-registers the program names
+        model = self.model
 
         def apply_fn(p, x):
             # activation constraints inside the model resolve against these
             # logical→mesh rules (otherwise they are silent no-ops)
             with nn.logical_axis_rules(LOGICAL_RULES):
                 if not is_moe:
-                    return self.model.apply(p, x, attention_fn=attention_fn)
+                    return model.apply(p, x, attention_fn=attention_fn)
                 # collect each layer's sown load-balance term: without the
                 # aux pressure in the objective the router collapses
-                logits, state = self.model.apply(
+                logits, state = model.apply(
                     p, x, attention_fn=attention_fn,
                     mutable=["intermediates"],
                 )
@@ -184,7 +206,7 @@ class LLMTrainer:
             # evaluation reports PURE cross-entropy: no aux regularizer, so
             # perplexity and dense-baseline comparisons stay meaningful
             with nn.logical_axis_rules(LOGICAL_RULES):
-                return self.model.apply(p, x, attention_fn=attention_fn)
+                return model.apply(p, x, attention_fn=attention_fn)
 
         self._eval_loss_fn = causal_lm_loss(eval_apply_fn)
         self._train_step = None  # compiled lazily once shardings exist

@@ -1,11 +1,10 @@
-"""Version shims for the narrow slice of the jax API the engine uses.
+"""The narrow slice of the jax API the engine uses, in one place.
 
-The codebase targets the current jax surface (``jax.shard_map``,
-``jax.lax.pcast``); older runtimes (0.4.x) ship the same functionality
-under experimental names or simply don't enforce the varying-type system
-that ``pcast`` feeds. Routing every call site through this module keeps
-the simulators importable across the jax versions the fleet actually
-runs — one hasattr probe at import, zero per-call overhead.
+One installation runs this repo (jax 0.9 here and on the chip machine),
+so these are thin pass-throughs to the current surface
+(``jax.shard_map``, ``jax.lax.axis_size``, ``jax.lax.pcast``) kept only
+to spare the call sites, plus two sharding-introspection helpers for the
+program catalog.
 """
 from __future__ import annotations
 
@@ -15,57 +14,24 @@ import jax
 
 Pytree = Any
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax < 0.6: same callable, experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_HAS_PCAST = hasattr(jax.lax, "pcast")
-
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=None, axis_names=None):
-    """``jax.shard_map`` wherever it lives in this jax version.
-
-    ``check_vma`` / ``axis_names`` are the current-jax spellings; on the
-    experimental (0.4.x) shard_map they translate to ``check_rep`` and
-    ``auto`` (the complement: axes NOT manually mapped).
-    """
+    """``jax.shard_map``; ``None`` keeps jax's default for either knob."""
     kwargs = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-    else:
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    if axis_names is not None:
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
 
 
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` with a psum(1) fallback for older jax.
-
-    Inside shard_map/pmap the axis size is static, so the fallback's
-    psum of a constant folds to a compile-time constant — no collective
-    is actually emitted.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+axis_size = jax.lax.axis_size
 
 
 def pcast_varying(tree: Pytree, axis_names) -> Pytree:
-    """Cast replicated leaves to device-varying over ``axis_names``.
-
-    On jax versions without ``jax.lax.pcast`` there is no varying-type
-    check to satisfy — the cast is the identity.
-    """
-    if not _HAS_PCAST:
-        return tree
+    """Cast replicated leaves to device-varying over ``axis_names``."""
     return jax.tree.map(
         lambda p: jax.lax.pcast(p, tuple(axis_names), to="varying"), tree
     )
@@ -74,19 +40,14 @@ def pcast_varying(tree: Pytree, axis_names) -> Pytree:
 def sharding_mesh_axes(sharding) -> dict:
     """``{axis_name: size}`` of a sharding's mesh, or ``{}``.
 
-    Version-tolerant introspection for the program catalog's mesh/
-    sharding records: ``NamedSharding`` exposes a mesh on every jax this
-    repo runs; anything else (``SingleDeviceSharding``, GSPMD opaque
-    shardings from older compilers) reports no axes rather than raising.
+    Introspection for the program catalog's mesh/sharding records:
+    ``NamedSharding`` exposes a mesh; anything else
+    (``SingleDeviceSharding``, opaque GSPMD shardings) reports no axes.
     """
     mesh = getattr(sharding, "mesh", None)
     if mesh is None:
         return {}
-    try:
-        return {str(name): int(size)
-                for name, size in dict(mesh.shape).items()}
-    except Exception:  # pragma: no cover - exotic mesh type
-        return {}
+    return {str(name): int(size) for name, size in dict(mesh.shape).items()}
 
 
 def pspec_str(sharding) -> str:

@@ -15,11 +15,12 @@ efficiency** plus the **per-shard HBM plan** of the sharded round:
   planner that depth-reduces on a single-core virtual mesh instead of
   letting XLA:CPU's 40 s collective-rendezvous timer abort the run.
 
-Efficiency basis (recorded as ``efficiency_basis``): on real multi-chip
-hardware, ``wall_1 / (N * wall_N)`` — the classic fraction of linear
-speedup. On a single-core VIRTUAL mesh (CI, this box) N devices
-time-share one core, so N-fold speedup is physically impossible and the
-honest basis is ``wall_1 / wall_N`` (**serialized-virtual-mesh**): a
+Efficiency basis (recorded as ``efficiency_basis``, decided by the
+device platform — ``parallel/multichip.efficiency_basis``): on real
+multi-chip hardware, ``wall_1 / (N * wall_N)`` — the classic fraction of
+linear speedup. Virtual CPU devices, however many cores the host has,
+share one host, so a CPU timing is never reported as hardware scaling:
+the basis there is ``wall_1 / wall_N`` (**serialized-virtual-mesh**): a
 perfect partition costs the same total compute as one device, so 1.0 is
 ideal and the ratio measures pure partition overhead — the collectives,
 layout shuffles and lane bookkeeping the sharding added.
@@ -118,10 +119,7 @@ def run_multichip_bench() -> Dict:
     import numpy as np
 
     from fedml_tpu.models.llm.llama import LlamaConfig
-    from fedml_tpu.parallel.multichip import (
-        is_single_core_virtual_mesh,
-        plan_multichip,
-    )
+    from fedml_tpu.parallel.multichip import efficiency_basis, plan_multichip
     from fedml_tpu.telemetry.profiling import get_catalog
     from fedml_tpu.train.llm.sharding import make_mesh
     from fedml_tpu.train.llm.trainer import LLMTrainer
@@ -152,7 +150,8 @@ def run_multichip_bench() -> Dict:
 
     cfg = LlamaConfig.tiny(lora_rank=4, use_flash=False)
     batch, seq = 4, 32
-    virtual = is_single_core_virtual_mesh(sweep[-1])
+    basis = efficiency_basis(devices)
+    virtual = basis == "serialized-virtual-mesh"
     rng = np.random.default_rng(0)
     toks = rng.integers(0, cfg.vocab_size,
                         size=(n_clients, local_steps, batch, seq),
@@ -201,7 +200,6 @@ def run_multichip_bench() -> Dict:
 
     # efficiency per N against the 1-device reference (see module
     # docstring for the virtual-mesh basis)
-    basis = "serialized-virtual-mesh" if virtual else "wall-clock"
     eff = {
         nd: (walls[1] / walls[nd] if virtual
              else walls[1] / (nd * walls[nd]))
