@@ -1,0 +1,5 @@
+from benchmarks.harness import scopes
+
+
+def read(ctx):
+    return scopes.idle_ms(ctx, "stage")

@@ -180,14 +180,15 @@ def rope_tables(positions: jax.Array, head_dim: int, theta: float):
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array):
     """x: [B, H, T, D]; cos/sin: [B, T, D/2] or [T, D/2]."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    if cos.ndim == 2:
-        cos, sin = cos[None, None], sin[None, None]
-    else:
-        cos, sin = cos[:, None], sin[:, None]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+    with jax.named_scope("rope"):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        if cos.ndim == 2:
+            cos, sin = cos[None, None], sin[None, None]
+        else:
+            cos, sin = cos[:, None], sin[:, None]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).astype(x.dtype)
 
 
 def _maybe_packed_param(module, name, init_box, shape, dtype):
@@ -284,9 +285,12 @@ class LlamaAttention(nn.Module):
         q = dense(h * d, "q_proj", ("embed", "heads"))(x)
         k = dense(hkv * d, "k_proj", ("embed", "heads"))(x)
         v = dense(hkv * d, "v_proj", ("embed", "heads"))(x)
-        q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
-        k = k.reshape(b, t, hkv, d).transpose(0, 2, 1, 3)
-        v = v.reshape(b, t, hkv, d).transpose(0, 2, 1, 3)
+        # flax names the projections; what is not a module gets a scope of
+        # its own, so a device trace can tell the glue from the matmuls
+        with jax.named_scope("attn_layout"):
+            q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+            k = k.reshape(b, t, hkv, d).transpose(0, 2, 1, 3)
+            v = v.reshape(b, t, hkv, d).transpose(0, 2, 1, 3)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
 
@@ -333,7 +337,8 @@ class LlamaAttention(nn.Module):
                 from fedml_tpu.ops.flash_attention import reference_attention
 
                 out = reference_attention(q, k, v, causal=True)
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+        with jax.named_scope("attn_layout"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         out = dense(cfg.hidden_size, "o_proj", ("heads", "embed"))(out)
         return out, new_cache
 
@@ -495,10 +500,12 @@ class LlamaForCausalLM(nn.Module):
             (cfg.vocab_size, cfg.hidden_size),
             cfg.param_dtype,
         )
-        x = emb.astype(cfg.dtype)[tokens]
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens]
         if positions is None:
             positions = jnp.arange(tokens.shape[1])
-        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        with jax.named_scope("rope"):
+            cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
         block = LlamaBlock
         if cfg.remat and cfg.remat_policy != "none" and kv_caches is None:
@@ -514,22 +521,23 @@ class LlamaForCausalLM(nn.Module):
             )
             new_caches.append(new_cache)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        if cfg.tie_word_embeddings:
-            logits = x @ emb.astype(cfg.dtype).T
-        else:
-            head = _maybe_packed_param(
-                self,
-                "lm_head",
-                nn.with_logical_partitioning(
-                    nn.initializers.normal(0.02), ("embed", "vocab")
-                ),
-                (cfg.hidden_size, cfg.vocab_size),
-                cfg.param_dtype,
-            )
-            from fedml_tpu.ops.quant import matmul_maybe_quantized
+        with jax.named_scope("lm_head"):
+            if cfg.tie_word_embeddings:
+                logits = x @ emb.astype(cfg.dtype).T
+            else:
+                head = _maybe_packed_param(
+                    self,
+                    "lm_head",
+                    nn.with_logical_partitioning(
+                        nn.initializers.normal(0.02), ("embed", "vocab")
+                    ),
+                    (cfg.hidden_size, cfg.vocab_size),
+                    cfg.param_dtype,
+                )
+                from fedml_tpu.ops.quant import matmul_maybe_quantized
 
-            logits = matmul_maybe_quantized(x, head, cfg.dtype)
-        logits = logits.astype(jnp.float32)
+                logits = matmul_maybe_quantized(x, head, cfg.dtype)
+            logits = logits.astype(jnp.float32)
         if kv_caches is not None:
             return logits, new_caches
         return logits
@@ -556,12 +564,13 @@ def causal_lm_loss(apply_fn):
         out = apply_fn(params, x)  # y: next tokens [B, T]
         # MoE apply_fns return (logits, aux_loss); dense ones return logits
         logits, aux = out if isinstance(out, tuple) else (out, 0.0)
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
-        valid = (y >= 0).astype(jnp.float32) * mask[:, None]
-        total = jnp.sum(ce * valid)
-        denom = jnp.maximum(jnp.sum(valid), 1.0)
-        pred = jnp.argmax(logits, axis=-1)
-        correct = jnp.sum((pred == y).astype(jnp.float32) * valid)
-        return total / denom + aux, (correct, denom)
+        with jax.named_scope("loss"):
+            ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+            valid = (y >= 0).astype(jnp.float32) * mask[:, None]
+            total = jnp.sum(ce * valid)
+            denom = jnp.maximum(jnp.sum(valid), 1.0)
+            pred = jnp.argmax(logits, axis=-1)
+            correct = jnp.sum((pred == y).astype(jnp.float32) * valid)
+            return total / denom + aux, (correct, denom)
 
     return loss_fn

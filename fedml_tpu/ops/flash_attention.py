@@ -148,6 +148,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -304,6 +305,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[scratch((bq, d))],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv accumulate over q heads within a group as well: run per q-head
@@ -325,6 +327,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
         ],
         scratch_shapes=[scratch((bk, d)), scratch((bk, d))],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     dk = dk_h.reshape(b, hkv, group, s, d).sum(axis=2).astype(k.dtype)
     dv = dv_h.reshape(b, hkv, group, s, d).sum(axis=2).astype(v.dtype)

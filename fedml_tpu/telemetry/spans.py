@@ -21,10 +21,18 @@ JAX compile-vs-execute split: a ``jax.monitoring`` duration listener
 attributes backend-compile seconds to whatever span is open when XLA
 compiles, so a span's ``compile_ms`` attr separates "first round pays the
 bridge" from steady-state execution.
+
+One clock with the device trace: ``Tracer.span`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name for the same interval,
+so every context-managed span of the repo sits on a host line of any
+profiler capture (the benchmark's, ``TraceController``'s, an operator's
+XProf/Perfetto view) above the device lines. ``begin()/end()`` pairs may
+cross threads and stay in-memory only.
 """
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
 import contextvars
 import json
@@ -255,12 +263,21 @@ def _notify_span_listeners(rec: Dict) -> None:
             pass
 
 
+# a memory-only tracer keeps this many of its newest records (a fused LLM
+# round leaves six spans: several hundred rounds)
+RING_RECORDS = 4096
+
+
 class Tracer:
     """Span factory + buffered JSONL sink.
 
-    Completed spans buffer in memory and flush to ``<sink_dir>/<filename>``
-    when the buffer passes ``buffer_limit``, on ``flush()``, and at
-    interpreter exit — a crash loses at most one buffer, not the run.
+    With a ``sink_dir``, completed spans buffer in memory and flush to
+    ``<sink_dir>/<filename>`` when the buffer passes ``buffer_limit``, on
+    ``flush()``, and at interpreter exit — a crash loses at most one
+    buffer, not the run. Without one (the default ``get_tracer()`` until
+    ``configure()``), the tracer is a bounded ring of its newest
+    ``RING_RECORDS`` records, which ``records()`` returns: the in-memory
+    copy a reader in the same process uses.
     """
 
     def __init__(self, sink_dir: Optional[str] = None,
@@ -271,8 +288,12 @@ class Tracer:
         self._limit = max(int(buffer_limit), 1)
         self.service = service
         self._lock = threading.Lock()
-        self._records: List[Dict] = []
+        self._records = ([] if sink_dir is not None
+                         else collections.deque(maxlen=RING_RECORDS))
         install_jax_compile_listener()
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         _live_tracers.add(self)
 
     @property
@@ -320,14 +341,7 @@ class Tracer:
             rec["service"] = self.service
         if span.attrs:
             rec["attrs"] = span.attrs
-        overflow = None
-        with self._lock:
-            self._records.append(rec)
-            if len(self._records) >= self._limit:
-                overflow = self._records
-                self._records = []
-        if overflow is not None:
-            self._write(overflow)
+        self._keep(rec)
         # a condensed copy rides the flight-recorder ring so a crash dump
         # shows the last spans even when the sink buffer died with them
         flight_recorder.on_span(rec)
@@ -357,14 +371,7 @@ class Tracer:
             rec["service"] = self.service
         if attrs:
             rec["attrs"] = attrs
-        overflow = None
-        with self._lock:
-            self._records.append(rec)
-            if len(self._records) >= self._limit:
-                overflow = self._records
-                self._records = []
-        if overflow is not None:
-            self._write(overflow)
+        self._keep(rec)
         _notify_span_listeners(rec)
         return rec
 
@@ -372,13 +379,28 @@ class Tracer:
     def span(self, name: str, **attrs: Any) -> Iterator[_ActiveSpan]:
         s = self.begin(name, **attrs)
         token = _current.set(s)
+        # the same interval on the profiler's clock: a host-line event in
+        # any capture that is running (about a microsecond when none is)
         try:
-            yield s
+            with self._annotation(name, span_id=s.span_id,
+                                  parent_id=s.parent_id or ""):
+                yield s
         finally:
             _current.reset(token)
             self.end(s)
 
     # -- sink -------------------------------------------------------------
+    def _keep(self, rec: Dict) -> None:
+        overflow = None
+        with self._lock:
+            self._records.append(rec)
+            # a memory-only tracer is a ring: the deque drops its oldest
+            if self._dir is not None and len(self._records) >= self._limit:
+                overflow = self._records
+                self._records = []
+        if overflow is not None:
+            self._write(overflow)
+
     def records(self) -> List[Dict]:
         with self._lock:
             return list(self._records)
@@ -394,6 +416,8 @@ class Tracer:
         return path
 
     def flush(self) -> Optional[str]:
+        if self._dir is None:
+            return None  # nowhere to land them: the ring keeps them
         with self._lock:
             records, self._records = self._records, []
         return self._write(records)

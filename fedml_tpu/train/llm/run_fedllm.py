@@ -17,17 +17,23 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from fedml_tpu.core.mlops.event import MLOpsProfilerEvent
 from fedml_tpu.data.dataset import FederatedDataset
 from fedml_tpu.models.llm.llama import LlamaConfig
 from fedml_tpu.simulation.sampling import sample_clients
+from fedml_tpu.telemetry import get_tracer
 from fedml_tpu.train.llm.federated import LLMAggregator, LLMClientTrainer
 
 logger = logging.getLogger(__name__)
 
 
 class FedLLMAPI:
-    """Round loop: sample clients → local LoRA steps → weighted average."""
+    """Round loop: sample clients → local LoRA steps → weighted average.
+
+    Every round is one trace of the process tracer: ``round/<n>/run`` (the
+    fused path's root) over ``sample``, ``stage``, ``dispatch``, ``wait``
+    and, when they run, ``eval`` and ``checkpoint``; the host path leaves
+    ``sample``, ``client/<id>/train`` and ``aggregate``.
+    """
 
     def __init__(self, args: Any, device: Any, dataset: FederatedDataset,
                  cfg: LlamaConfig = None, mesh=None):
@@ -41,7 +47,6 @@ class FedLLMAPI:
             self.cfg, args, mesh=mesh, engine=self.client.engine
         )
         self.global_exchange = self.aggregator.get_init_params()
-        self.event = MLOpsProfilerEvent(args)
         self.test_history: List[dict] = []
         # on_device_round: true fuses the ENTIRE round (client-switch,
         # local steps, LoRA FedAvg) into one donated-buffer XLA program —
@@ -82,69 +87,90 @@ class FedLLMAPI:
     def _train_one_round_on_device(self, round_idx: int) -> Dict:
         """The fused-round fast path: one XLA program per round."""
         engine = self.client.engine
-        client_ids = sample_clients(self.args, round_idx)
+        tracer = get_tracer()
         batch = engine.batch_size
         steps = int(getattr(self.args, "local_steps_per_round", 0) or 0)
         if steps <= 0:
             # default: one optimizer step per local epoch, each on a fresh
             # random batch (the fixed-shape SPMD analogue of an epoch sweep)
             steps = int(getattr(self.args, "epochs", 1))
-        key = (len(client_ids), steps)
-        if self._fed_round_key != key:
-            self._fed_round = engine.compile_federated_round(*key)
-            self._fed_round_key = key
+        with tracer.span(f"round/{round_idx}/run", steps=steps) as run:
+            with tracer.span(f"round/{round_idx}/sample") as sp:
+                client_ids = sample_clients(self.args, round_idx)
+                sp.attrs["clients"] = len(client_ids)
+            key = (len(client_ids), steps)
+            if self._fed_round_key != key:
+                self._fed_round = engine.compile_federated_round(*key)
+                self._fed_round_key = key
 
-        xs = np.zeros((len(client_ids), steps, batch, engine.seq_len), np.int32)
-        ys = np.zeros_like(xs)
-        ms = np.ones((len(client_ids), steps, batch), np.float32)
-        weights = np.zeros((len(client_ids),), np.float32)
-        rng = np.random.default_rng(
-            int(getattr(self.args, "random_seed", 0)) * 9973 + round_idx)
-        for i, cid in enumerate(client_ids):
-            x, y = self.dataset.train_data_local_dict[cid]
-            x, y = np.asarray(x), np.asarray(y)
-            idx = rng.integers(0, x.shape[0], size=(steps, batch))
-            xs[i], ys[i] = x[idx], y[idx]
-            weights[i] = float(self.dataset.train_data_local_num_dict[cid])
+            with tracer.span(f"round/{round_idx}/stage") as sp:
+                xs = np.zeros(
+                    (len(client_ids), steps, batch, engine.seq_len), np.int32)
+                ys = np.zeros_like(xs)
+                ms = np.ones((len(client_ids), steps, batch), np.float32)
+                weights = np.zeros((len(client_ids),), np.float32)
+                rng = np.random.default_rng(
+                    int(getattr(self.args, "random_seed", 0)) * 9973
+                    + round_idx)
+                for i, cid in enumerate(client_ids):
+                    x, y = self.dataset.train_data_local_dict[cid]
+                    x, y = np.asarray(x), np.asarray(y)
+                    idx = rng.integers(0, x.shape[0], size=(steps, batch))
+                    xs[i], ys[i] = x[idx], y[idx]
+                    weights[i] = float(
+                        self.dataset.train_data_local_num_dict[cid])
+                sp.attrs.update(
+                    rows=ms.size, tokens=xs.size,
+                    bytes=xs.nbytes + ys.nbytes + ms.nbytes + weights.nbytes)
+            run.attrs.update(clients=len(client_ids), tokens=xs.size)
 
-        self.event.log_event_started("round", round_idx)
-        t0 = time.time()
-        engine.params, engine.opt_state, self.global_exchange, loss = (
-            self._fed_round(engine.params, engine.opt_state,
-                            self.global_exchange, xs, ys, ms, weights))
-        loss = float(loss)  # jit returns futures: block BEFORE stopping t
-        dt = time.time() - t0
-        self.event.log_event_ended("round", round_idx)
-        report = {"round": round_idx, "round_sec": dt, "train_loss": loss}
-        self._maybe_test_and_checkpoint(round_idx, report)
+            t0 = time.time()
+            # until the program returns its futures: the host→device copy
+            # of the feed (it goes in as numpy), the catalog's wrapper, the
+            # enqueue — and the compile, when this signature is new
+            with tracer.span(f"round/{round_idx}/dispatch",
+                             program="llm/fused_round"):
+                engine.params, engine.opt_state, self.global_exchange, loss = (
+                    self._fed_round(engine.params, engine.opt_state,
+                                    self.global_exchange, xs, ys, ms, weights))
+            with tracer.span(f"round/{round_idx}/wait"):
+                loss = float(loss)  # jit returns futures: block BEFORE stopping t
+            dt = time.time() - t0
+            report = {"round": round_idx, "round_sec": dt, "train_loss": loss}
+            self._maybe_test_and_checkpoint(round_idx, report)
         return report
 
     def train_one_round(self, round_idx: int) -> Dict:
         if self.on_device:
             return self._train_one_round_on_device(round_idx)
-        client_ids = sample_clients(self.args, round_idx)
+        tracer = get_tracer()
+        with tracer.span(f"round/{round_idx}/sample") as sp:
+            client_ids = sample_clients(self.args, round_idx)
+            sp.attrs["clients"] = len(client_ids)
         payloads = []
-        self.event.log_event_started("round", round_idx)
         t0 = time.time()
         for cid in client_ids:
             self.client.set_id(cid)
             self.client.set_round(round_idx)
             data = self.dataset.train_data_local_dict[cid]
-            # run_local_training = attack/DP/FHE hook chain around train()
-            updated, _metrics = self.client.run_local_training(
-                self.global_exchange, data, None, self.args
-            )
             n = self.dataset.train_data_local_num_dict[cid]
+            # run_local_training = attack/DP/FHE hook chain around train()
+            with tracer.span(f"round/{round_idx}/client/{cid}/train",
+                             n_samples=n):
+                updated, _metrics = self.client.run_local_training(
+                    self.global_exchange, data, None, self.args
+                )
             payloads.append((float(n), updated))
         # full ServerAggregator hook chain: defense/DP before-hooks,
         # defense-wrapped FedMLAggOperator, central-DP/contribution after
-        model_list, _ = self.aggregator.on_before_aggregation(payloads)
-        self.global_exchange = self.aggregator.aggregate(model_list)
-        self.global_exchange = self.aggregator.on_after_aggregation(
-            self.global_exchange
-        )
+        with tracer.span(f"round/{round_idx}/aggregate",
+                         clients=len(payloads)):
+            model_list, _ = self.aggregator.on_before_aggregation(payloads)
+            self.global_exchange = self.aggregator.aggregate(model_list)
+            self.global_exchange = self.aggregator.on_after_aggregation(
+                self.global_exchange
+            )
         dt = time.time() - t0
-        self.event.log_event_ended("round", round_idx)
 
         report = {"round": round_idx, "round_sec": dt}
         self._maybe_test_and_checkpoint(round_idx, report)
@@ -155,16 +181,19 @@ class FedLLMAPI:
         if round_idx % max(freq, 1) == 0 or round_idx == int(
             getattr(self.args, "comm_round", 1)
         ) - 1:
-            metrics = self.aggregator.test(
-                self.global_exchange, self.dataset.test_data_global, None, self.args
-            )
+            with get_tracer().span(f"round/{round_idx}/eval"):
+                metrics = self.aggregator.test(
+                    self.global_exchange, self.dataset.test_data_global,
+                    None, self.args
+                )
             report.update(metrics)
             self.test_history.append(report)
             logger.info("fedllm round %d: %s", round_idx, metrics)
         ckpt_dir = getattr(self.args, "checkpoint_dir", None)
         every = int(getattr(self.args, "save_every_rounds", 0) or 0)
         if ckpt_dir and every and round_idx % every == 0:
-            self.aggregator.save_round(str(ckpt_dir), round_idx)
+            with get_tracer().span(f"round/{round_idx}/checkpoint"):
+                self.aggregator.save_round(str(ckpt_dir), round_idx)
 
     def train(self) -> Dict:
         t0 = time.time()

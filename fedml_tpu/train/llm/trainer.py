@@ -495,7 +495,8 @@ class LLMTrainer:
                 x_g, y_g, m_g, w_g = inp
 
                 def lane(o, x_c, y_c, m_c):
-                    p = merge_lora(params, global_lora)
+                    with jax.named_scope("client_switch"):
+                        p = merge_lora(params, global_lora)
 
                     def local(c, batch):
                         p_c, o_c = c
@@ -507,9 +508,10 @@ class LLMTrainer:
 
                         (loss, _), grads = jax.value_and_grad(
                             loss_of, has_aux=True)(wrt)
-                        updates, o_c = tx.update(grads, o_c, wrt)
-                        p_c = merge_trainable(
-                            p_c, optax.apply_updates(wrt, updates))
+                        with jax.named_scope("optimizer"):
+                            updates, o_c = tx.update(grads, o_c, wrt)
+                            p_c = merge_trainable(
+                                p_c, optax.apply_updates(wrt, updates))
                         return (p_c, o_c), loss
 
                     (p, o), losses = jax.lax.scan(
@@ -521,19 +523,21 @@ class LLMTrainer:
                 # contraction over the lane axis IS the FedAvg partial
                 # sum — the only cross-lane (dp) communication in the
                 # round, and it moves adapters, not the base
-                acc = jax.tree.map(
-                    lambda a, l: a + jnp.einsum(
-                        "c,c...->...", w_g, l.astype(jnp.float32)),
-                    acc, loras)
+                with jax.named_scope("fedavg"):
+                    acc = jax.tree.map(
+                        lambda a, l: a + jnp.einsum(
+                            "c,c...->...", w_g, l.astype(jnp.float32)),
+                        acc, loras)
                 return (opt_states, acc), jnp.mean(losses)
 
             acc0 = jax.tree.map(
                 lambda v: jnp.zeros(v.shape, jnp.float32), global_lora)
             (opt_states, acc), losses = jax.lax.scan(
                 group, (opt_states, acc0), (xs, ys, ms, weights))
-            wsum = jnp.sum(weights)
-            new_global = jax.tree.map(
-                lambda a, g: (a / wsum).astype(g.dtype), acc, global_lora)
+            with jax.named_scope("fedavg"):
+                wsum = jnp.sum(weights)
+                new_global = jax.tree.map(
+                    lambda a, g: (a / wsum).astype(g.dtype), acc, global_lora)
             return params, opt_states, new_global, jnp.mean(losses)
 
         from jax.sharding import NamedSharding
@@ -593,7 +597,10 @@ class LLMTrainer:
                 params, opt_state, acc = carry
                 x_c, y_c, m_c, w = inp
                 # client-switch: reset adapters to the round's global state
-                params = merge_lora(params, global_lora)
+                # (tree surgery: it lowers to no operation of its own today,
+                # so the scope names only what a later change adds here)
+                with jax.named_scope("client_switch"):
+                    params = merge_lora(params, global_lora)
 
                 def local(c, batch):
                     p, o = c
@@ -605,24 +612,28 @@ class LLMTrainer:
 
                     (loss, _), grads = jax.value_and_grad(
                         loss_of, has_aux=True)(wrt)
-                    updates, o = tx.update(grads, o, wrt)
-                    p = merge_trainable(p, optax.apply_updates(wrt, updates))
+                    with jax.named_scope("optimizer"):
+                        updates, o = tx.update(grads, o, wrt)
+                        p = merge_trainable(
+                            p, optax.apply_updates(wrt, updates))
                     return (p, o), loss
 
                 (params, opt_state), losses = jax.lax.scan(
                     local, (params, opt_state), (x_c, y_c, m_c))
-                lora = extract_lora(params)
-                acc = jax.tree.map(
-                    lambda a, l: a + w * l.astype(jnp.float32), acc, lora)
+                with jax.named_scope("fedavg"):
+                    lora = extract_lora(params)
+                    acc = jax.tree.map(
+                        lambda a, l: a + w * l.astype(jnp.float32), acc, lora)
                 return (params, opt_state, acc), jnp.mean(losses)
 
             acc0 = jax.tree.map(
                 lambda v: jnp.zeros(v.shape, jnp.float32), global_lora)
             (params, opt_state, acc), losses = jax.lax.scan(
                 client, (params, opt_state, acc0), (xs, ys, ms, weights))
-            wsum = jnp.sum(weights)
-            new_global = jax.tree.map(
-                lambda a, g: (a / wsum).astype(g.dtype), acc, global_lora)
+            with jax.named_scope("fedavg"):
+                wsum = jnp.sum(weights)
+                new_global = jax.tree.map(
+                    lambda a, g: (a / wsum).astype(g.dtype), acc, global_lora)
             # params keep the LAST client's adapters — the next round's
             # client-switch overwrites them with new_global anyway, and
             # emitting the same value as two outputs (params leaf + global

@@ -9,6 +9,7 @@ one process at a time may load libtpu, so only the xdist worker that is
 handed this file does (see /opt/skills/guides/on-chip-measurement §2).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,15 @@ def test_flash_attention_compiles_for_v5e(one_chip, backward):
                       argnums=(0, 1, 2))
     compiled = jax.jit(fn).lower(qkv, qkv, qkv).compile()
     assert _kernels(compiled) == (3 if backward else 1)  # fwd | fwd, dq, dkv
+    # each kernel under its own name, in the instruction's name and its
+    # op_name: what a device trace shows and the benchmark's readers match
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    names = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"][:len(calls)]
+    for name in names:
+        (line,) = [c for c in calls
+                   if re.search(rf'op_name="[^"]*[/(]{name}[/)]', c)]
+        assert name in line.split(" = ")[0]
 
 
 @pytest.mark.parametrize("h,f", [(4096, 11008), (11008, 4096), (4096, 32000)])
