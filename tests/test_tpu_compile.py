@@ -52,12 +52,13 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+@pytest.mark.parametrize("shape", [(1, 32, 512, 128), (1, 32, 4096, 64)],
+                         ids=["t512_d128", "t4096_d64"])  # the two cells'
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-def test_flash_attention_compiles_for_v5e(one_chip, backward):
+def test_flash_attention_compiles_for_v5e(one_chip, backward, shape):
     from fedml_tpu.ops.flash_attention import flash_attention
 
-    qkv = jax.ShapeDtypeStruct((1, 32, 512, 128), jnp.bfloat16,
-                               sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False)
