@@ -64,9 +64,23 @@ class LlamaConfig:
     remat_policy: str = "full"
     use_flash: bool = True
 
+    # what the round's program hands back beside the loss: nothing here
+    round_stats = ()
+
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def aux_loss_weight(self) -> float:
+        """Weight of the sown load-balance terms in the train loss."""
+        return float(self.moe_aux_weight) if self.num_experts > 0 else 0.0
+
+    def module(self) -> "LlamaForCausalLM":
+        """The flax module of this configuration: what the trainer, the
+        aggregator and ``model_hub.create`` ask a configuration object for
+        (``ZayaConfig.module`` answers with its own)."""
+        return LlamaForCausalLM(self)
 
     # -- presets (kw overrides win — e.g. a reduced-depth 7B) ------------
     @staticmethod
@@ -562,8 +576,10 @@ def causal_lm_loss(apply_fn):
 
     def loss_fn(params, x, y, mask):
         out = apply_fn(params, x)  # y: next tokens [B, T]
-        # MoE apply_fns return (logits, aux_loss); dense ones return logits
-        logits, aux = out if isinstance(out, tuple) else (out, 0.0)
+        # MoE apply_fns return (logits, aux_loss) and, where the model
+        # counts something a round, a dict of those counts; dense ones
+        # return logits
+        logits, aux, *stats = out if isinstance(out, tuple) else (out, 0.0)
         with jax.named_scope("loss"):
             ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
             valid = (y >= 0).astype(jnp.float32) * mask[:, None]
@@ -571,6 +587,6 @@ def causal_lm_loss(apply_fn):
             denom = jnp.maximum(jnp.sum(valid), 1.0)
             pred = jnp.argmax(logits, axis=-1)
             correct = jnp.sum((pred == y).astype(jnp.float32) * valid)
-            return total / denom + aux, (correct, denom)
+            return total / denom + aux, (correct, denom, *stats)
 
     return loss_fn

@@ -74,11 +74,10 @@ def create(args: Any, output_dim: int = 10) -> nn.Module:
         if "stackoverflow" in dataset or "reddit" in dataset:
             return RNNStackOverflow(vocab_size=max(output_dim, 4))
         return RNNOriginalFedAvg(vocab_size=max(output_dim, 4))
-    if name in ("llama", "llama_lora", "transformer"):
-        from fedml_tpu.models.llm.llama import LlamaConfig, LlamaForCausalLM
+    if name in ("llama", "llama_lora", "transformer", "zaya"):
+        from fedml_tpu.models.llm import config_from_args
 
-        cfg = LlamaConfig.from_args(args, vocab_size=max(output_dim, 32))
-        return LlamaForCausalLM(cfg)
+        return config_from_args(args, vocab_size=max(output_dim, 32)).module()
     raise ValueError(f"unknown model {name!r}")
 
 

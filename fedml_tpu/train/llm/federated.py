@@ -22,7 +22,6 @@ import numpy as np
 
 from fedml_tpu.core.alg_frame.client_trainer import ClientTrainer
 from fedml_tpu.core.alg_frame.server_aggregator import ServerAggregator
-from fedml_tpu.models.llm.llama import LlamaConfig
 from fedml_tpu.train.llm.trainer import LLMTrainer
 
 logger = logging.getLogger(__name__)
@@ -38,7 +37,7 @@ class LLMClientTrainer(ClientTrainer):
     and returns the updated exchangeable params.
     """
 
-    def __init__(self, cfg: LlamaConfig, args: Any, mesh=None):
+    def __init__(self, cfg: Any, args: Any, mesh=None):
         super().__init__(model=None, args=args)
         self.engine = LLMTrainer(cfg, args, mesh=mesh)
         self.engine.init(seed=int(getattr(args, "random_seed", 0)))
@@ -107,7 +106,7 @@ class LLMAggregator(ServerAggregator):
     apply unchanged. Reference: ``run_fedllm.py:460`` LLMAggregator.
     """
 
-    def __init__(self, cfg: LlamaConfig, args: Any, mesh=None,
+    def __init__(self, cfg: Any, args: Any, mesh=None,
                  engine: Optional[LLMTrainer] = None):
         super().__init__(model=None, args=args)
         self.engine = engine or LLMTrainer(cfg, args, mesh=mesh)

@@ -18,7 +18,7 @@ from typing import Any, Dict, List
 import numpy as np
 
 from fedml_tpu.data.dataset import FederatedDataset
-from fedml_tpu.models.llm.llama import LlamaConfig
+from fedml_tpu.models.llm import config_from_args
 from fedml_tpu.simulation.sampling import sample_clients
 from fedml_tpu.telemetry import get_tracer
 from fedml_tpu.train.llm.federated import LLMAggregator, LLMClientTrainer
@@ -32,14 +32,19 @@ class FedLLMAPI:
     Every round is one trace of the process tracer: ``round/<n>/run`` (the
     fused path's root) over ``sample``, ``stage``, ``dispatch``, ``wait``
     and, when they run, ``eval`` and ``checkpoint``; the host path leaves
-    ``sample``, ``client/<id>/train`` and ``aggregate``.
+    ``sample``, ``client/<id>/train`` and ``aggregate``. A model that
+    routes tokens to experts also leaves the point event ``round/<n>/moe``
+    (:meth:`_moe_event`).
     """
 
     def __init__(self, args: Any, device: Any, dataset: FederatedDataset,
-                 cfg: LlamaConfig = None, mesh=None):
+                 cfg: Any = None, mesh=None):
+        """``cfg``: a model configuration object (``LlamaConfig``,
+        ``ZayaConfig``); without one it is built from ``args`` by the
+        ``model`` they name (``models.llm.config_from_args``)."""
         self.args = args
         self.dataset = dataset
-        self.cfg = cfg or LlamaConfig.from_args(args, vocab_size=dataset.class_num)
+        self.cfg = cfg or config_from_args(args, vocab_size=dataset.class_num)
         # one engine serves every simulated client (params are swapped in);
         # this is exactly the reference's sp-backend memory model
         self.client = LLMClientTrainer(self.cfg, args, mesh=mesh)
@@ -130,15 +135,42 @@ class FedLLMAPI:
             # enqueue — and the compile, when this signature is new
             with tracer.span(f"round/{round_idx}/dispatch",
                              program="llm/fused_round"):
-                engine.params, engine.opt_state, self.global_exchange, loss = (
-                    self._fed_round(engine.params, engine.opt_state,
-                                    self.global_exchange, xs, ys, ms, weights))
+                (engine.params, engine.opt_state, self.global_exchange, loss,
+                 *stats) = self._fed_round(
+                     engine.params, engine.opt_state, self.global_exchange,
+                     xs, ys, ms, weights)
             with tracer.span(f"round/{round_idx}/wait"):
                 loss = float(loss)  # jit returns futures: block BEFORE stopping t
+                stats = {k: np.asarray(v) for s in stats for k, v in s.items()}
             dt = time.time() - t0
+            if "moe_tokens" in stats:
+                self._moe_event(round_idx, stats, xs.size, ms.size // batch)
             report = {"round": round_idx, "round_sec": dt, "train_loss": loss}
             self._maybe_test_and_checkpoint(round_idx, report)
         return report
+
+    @staticmethod
+    def _moe_event(round_idx: int, stats: Dict[str, np.ndarray],
+                   tokens: int, steps: int) -> Dict:
+        """``round/<n>/moe``: how the round's tokens spread over the
+        experts. ``moe_tokens`` ``[layers, experts]`` and ``moe_live``
+        ``[layers]`` are summed over the round's ``steps``.
+        ``max_over_mean`` is the busiest expert's load over the mean load
+        in the worst layer; ``dropped`` the tokens that reached no expert in
+        some layer (a dropless layer reads 0); ``live_share`` the share of
+        (step, layer, expert) triples in which the expert got a token — a
+        step's grouped products read only those experts' matrices."""
+        counts = stats["moe_tokens"]
+        layers, experts = counts.shape
+        per_layer = counts.sum(axis=1)
+        return get_tracer().event(
+            f"round/{round_idx}/moe", layers=int(layers),
+            experts=int(experts), tokens=int(tokens), steps=int(steps),
+            max_over_mean=float(
+                (counts.max(axis=1) * experts / per_layer).max()),
+            live_share=float(
+                stats["moe_live"].sum() / (steps * layers * experts)),
+            dropped=int((tokens - per_layer).max()))
 
     def train_one_round(self, round_idx: int) -> Dict:
         if self.on_device:

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from fedml_tpu.models.llm.llama import LlamaConfig, LlamaForCausalLM, causal_lm_loss
+from fedml_tpu.models.llm.llama import causal_lm_loss
 from fedml_tpu.train.llm.sharding import (
     batch_sharding,
     data_parallel_size,
@@ -45,8 +45,10 @@ def is_lora_path(path: Tuple) -> bool:
 
 
 def is_trainable_path(path: Tuple) -> bool:
-    """LoRA adapters + the MoE router (tiny, no LoRA twin, and the
-    load-balance loss must be able to act on it)."""
+    """LoRA adapters + ``LlamaMoE``'s router, the leaf named ``router``
+    (tiny, no LoRA twin, and the load-balance loss must be able to act on
+    it). ``zaya``'s router MLP lives under ``moe/router_mlp`` and is NOT
+    caught here: that model's rounds train the adapters alone."""
     return is_lora_path(path) or any(
         str(getattr(p, "key", p)) == "router" for p in path
     )
@@ -90,12 +92,18 @@ def merge_lora(params: Pytree, lora: dict) -> Pytree:
 
 
 class LLMTrainer:
-    """Compiled causal-LM fine-tuning over a named mesh."""
+    """Compiled causal-LM fine-tuning over a named mesh.
 
-    def __init__(self, cfg: LlamaConfig, args: Any, mesh=None):
+    ``cfg`` is a model configuration object (``LlamaConfig``,
+    ``ZayaConfig``): the trainer asks it for its flax module
+    (``cfg.module()``), the weight of sown auxiliary losses
+    (``cfg.aux_loss_weight``) and the names of the counts its module sows
+    for a round (``cfg.round_stats``), and knows no model by name."""
+
+    def __init__(self, cfg: Any, args: Any, mesh=None):
         self.cfg = cfg
         self.args = args
-        self.model = LlamaForCausalLM(cfg)
+        self.model = cfg.module()
         self.mesh = mesh if mesh is not None else mesh_from_args(args)
         self.seq_len = int(getattr(args, "max_seq_length", 512))
         # per_device_batch_size is PER DEVICE: every [B, T] batch is split
@@ -175,8 +183,8 @@ class LLMTrainer:
             attention_fn = make_sharded_flash_attention(
                 self.mesh, rules["batch"], rules["heads"])
 
-        moe_aux_w = float(getattr(self.cfg, "moe_aux_weight", 0.01))
-        is_moe = int(getattr(self.cfg, "num_experts", 0)) > 0
+        aux_w = float(cfg.aux_loss_weight)
+        stat_names = tuple(cfg.round_stats)
 
         # the compiled programs outlive this object in the process-wide
         # catalog: their closures hold the (param-free) module, never
@@ -188,17 +196,21 @@ class LLMTrainer:
             # activation constraints inside the model resolve against these
             # logical→mesh rules (otherwise they are silent no-ops)
             with nn.logical_axis_rules(LOGICAL_RULES):
-                if not is_moe:
+                if not aux_w and not stat_names:
                     return model.apply(p, x, attention_fn=attention_fn)
-                # collect each layer's sown load-balance term: without the
-                # aux pressure in the objective the router collapses
                 logits, state = model.apply(
                     p, x, attention_fn=attention_fn,
                     mutable=["intermediates"],
                 )
-                auxes = jax.tree.leaves(state["intermediates"])
-                aux = moe_aux_w * sum(auxes) / max(len(auxes), 1)
-                return logits, aux
+                sown = dict(state["intermediates"])
+                # counts the module sows once, at its top (a tuple of one)
+                stats = {k: sown.pop(k)[0] for k in stat_names}
+                # what is left is each layer's sown load-balance term:
+                # without the aux pressure in the objective a trained
+                # router collapses
+                auxes = jax.tree.leaves(sown)
+                aux = aux_w * sum(auxes) / max(len(auxes), 1)
+                return (logits, aux, stats) if stats else (logits, aux)
 
         self._loss_fn = causal_lm_loss(apply_fn)
 
@@ -576,7 +588,10 @@ class LLMTrainer:
         of rounds/s to the host-side merge on a 1-core box).
 
         Returns ``fed_round(params, opt_state, global_lora, xs, ys, ms,
-        weights) -> (params, opt_state, new_global_lora, mean_loss)`` with
+        weights) -> (params, opt_state, new_global_lora, mean_loss)`` — and,
+        for a model whose configuration names ``round_stats``, a fifth
+        output: the dict of those counts summed over the round's clients
+        and steps (``zaya``: ``moe_tokens`` ``[layers, experts]``) — with
         ``xs``/``ys``: ``[n_clients, local_steps, B, T]`` token batches,
         ``ms``: ``[n_clients, local_steps, B]`` masks, ``weights``:
         ``[n_clients]`` aggregation weights (normalized internally, same
@@ -610,25 +625,27 @@ class LLMTrainer:
                     def loss_of(t):
                         return loss_fn(merge_trainable(p, t), x, y, m)
 
-                    (loss, _), grads = jax.value_and_grad(
+                    (loss, (_, _, *stats)), grads = jax.value_and_grad(
                         loss_of, has_aux=True)(wrt)
                     with jax.named_scope("optimizer"):
                         updates, o = tx.update(grads, o, wrt)
                         p = merge_trainable(
                             p, optax.apply_updates(wrt, updates))
-                    return (p, o), loss
+                    return (p, o), (loss, stats)
 
-                (params, opt_state), losses = jax.lax.scan(
+                (params, opt_state), (losses, stats) = jax.lax.scan(
                     local, (params, opt_state), (x_c, y_c, m_c))
                 with jax.named_scope("fedavg"):
                     lora = extract_lora(params)
                     acc = jax.tree.map(
                         lambda a, l: a + w * l.astype(jnp.float32), acc, lora)
-                return (params, opt_state, acc), jnp.mean(losses)
+                return (params, opt_state, acc), (jnp.mean(losses),
+                                                  over_steps(stats))
 
+            over_steps = lambda tree: jax.tree.map(lambda s: s.sum(0), tree)
             acc0 = jax.tree.map(
                 lambda v: jnp.zeros(v.shape, jnp.float32), global_lora)
-            (params, opt_state, acc), losses = jax.lax.scan(
+            (params, opt_state, acc), (losses, stats) = jax.lax.scan(
                 client, (params, opt_state, acc0), (xs, ys, ms, weights))
             with jax.named_scope("fedavg"):
                 wsum = jnp.sum(weights)
@@ -639,7 +656,8 @@ class LLMTrainer:
             # emitting the same value as two outputs (params leaf + global
             # leaf) would break donation aliasing; callers needing live
             # params to hold the aggregate use load_exchange_state
-            return params, opt_state, new_global, jnp.mean(losses)
+            out = (params, opt_state, new_global, jnp.mean(losses))
+            return out + tuple(over_steps(stats))
 
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
@@ -667,7 +685,7 @@ class LLMTrainer:
             in_shardings=(self.shardings, opt_shardings, lora_shardings,
                           data_spec, data_spec, data_spec, rep),
             out_shardings=(self.shardings, opt_shardings, lora_shardings,
-                           rep),
+                           rep) + (rep,) * bool(self.cfg.round_stats),
             donate_argnums=(0, 1, 2),
         ), multi_shape=True)
 

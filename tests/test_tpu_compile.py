@@ -95,6 +95,36 @@ def test_dequant_matmul_compiles_for_v5e(one_chip, h, f):
     assert _kernels(compiled) == 1
 
 
+def test_grouped_matmul_compiles_for_v5e(one_chip):
+    """``moe_gmm`` and its row gradient ``moe_gmm_t`` at the widths of
+    ``zaya1-8b.round-mid``: 1,024 tokens over 16 experts of 2048 x 2048,
+    each kernel under its own name."""
+    from fedml_tpu.ops import grouped_matmul as gmm
+
+    m, k, n, e = 1024, 2048, 2048, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, w, expert):
+        layout = gmm.group_layout(expert, e)
+        out = gmm.grouped_matmul(gmm.dispatch(x, layout), w, layout,
+                                 interpret=False)
+        return gmm.combine(out, layout).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        sds((m, k), jnp.bfloat16), sds((e, k, n), jnp.bfloat16),
+        sds((m,), jnp.int32)).compile()
+    assert _kernels(compiled) == 2
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(c.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                  for c in calls) == ["moe_gmm", "moe_gmm_t"]
+    # no transposed copy of the experts' 134 MB is made for the backward
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    assert " sort(" not in text and " scatter(" not in text
+
+
 def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2):
     """``llm/fused_round`` at the 7B widths, lowered from shapes alone.
 
