@@ -26,6 +26,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from fedml_tpu.models.llm.head_loss import HeadInputs, head_loss
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -497,14 +499,18 @@ class LlamaBlock(nn.Module):
 class LlamaForCausalLM(nn.Module):
     """Token ids [B, T] → logits [B, T, V].
 
-    ``__call__(tokens)`` is the training forward; ``decode_step`` threads an
-    explicit KV cache for serving (``fedml_tpu/serving``).
+    ``__call__(tokens)`` is the forward that serving, conversion and the
+    parity tests read; ``head_inputs=True`` stops before the head's product
+    and returns :class:`HeadInputs` (the final hidden state and the head's
+    matrix), which is what the training loss takes; ``decode_step`` threads
+    an explicit KV cache for serving (``fedml_tpu/serving``).
     """
 
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, kv_caches=None, attention_fn=None):
+    def __call__(self, tokens, positions=None, kv_caches=None, attention_fn=None,
+                 head_inputs=False):
         cfg = self.cfg
         emb = self.param(
             "embed_tokens",
@@ -535,19 +541,21 @@ class LlamaForCausalLM(nn.Module):
             )
             new_caches.append(new_cache)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        head = emb if cfg.tie_word_embeddings else _maybe_packed_param(
+            self,
+            "lm_head",
+            nn.with_logical_partitioning(
+                nn.initializers.normal(0.02), ("embed", "vocab")
+            ),
+            (cfg.hidden_size, cfg.vocab_size),
+            cfg.param_dtype,
+        )
+        if head_inputs:
+            return HeadInputs(x, head, cfg.tie_word_embeddings)
         with jax.named_scope("lm_head"):
             if cfg.tie_word_embeddings:
                 logits = x @ emb.astype(cfg.dtype).T
             else:
-                head = _maybe_packed_param(
-                    self,
-                    "lm_head",
-                    nn.with_logical_partitioning(
-                        nn.initializers.normal(0.02), ("embed", "vocab")
-                    ),
-                    (cfg.hidden_size, cfg.vocab_size),
-                    cfg.param_dtype,
-                )
                 from fedml_tpu.ops.quant import matmul_maybe_quantized
 
                 logits = matmul_maybe_quantized(x, head, cfg.dtype)
@@ -570,23 +578,24 @@ def causal_lm_loss(apply_fn):
     """Next-token CE over a [B, T] token batch; mask is [B] sample validity.
 
     Matches the trainer contract in ``ml/trainer/local_sgd.py`` so the LLM
-    drops into every federated engine unchanged.
+    drops into every federated engine unchanged: ``loss, (correct, denom,
+    *stats)``, where ``correct`` counts the valid rows whose target's logit
+    is the row's maximum (a target that ties it exactly counts; see
+    ``head_loss``).
     """
-    import optax
 
     def loss_fn(params, x, y, mask):
         out = apply_fn(params, x)  # y: next tokens [B, T]
-        # MoE apply_fns return (logits, aux_loss) and, where the model
-        # counts something a round, a dict of those counts; dense ones
-        # return logits
-        logits, aux, *stats = out if isinstance(out, tuple) else (out, 0.0)
+        # apply_fns return the model's HeadInputs (``head_inputs=True``);
+        # MoE ones (HeadInputs, aux_loss) and, where the model counts
+        # something a round, a dict of those counts
+        head, aux, *stats = out if isinstance(out, tuple) else (out, 0.0)
         with jax.named_scope("loss"):
-            ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
             valid = (y >= 0).astype(jnp.float32) * mask[:, None]
-            total = jnp.sum(ce * valid)
             denom = jnp.maximum(jnp.sum(valid), 1.0)
-            pred = jnp.argmax(logits, axis=-1)
-            correct = jnp.sum((pred == y).astype(jnp.float32) * valid)
+        # the product, the cross-entropy and their gradient in one
+        total, correct = head_loss(head, y, valid)
+        with jax.named_scope("loss"):
             return total / denom + aux, (correct, denom, *stats)
 
     return loss_fn

@@ -58,6 +58,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from fedml_tpu.models.llm.head_loss import HeadInputs
 from fedml_tpu.models.llm.llama import (LoRADense, RMSNorm, apply_rope,
                                         rope_tables)
 from fedml_tpu.ops import grouped_matmul as gmm
@@ -363,7 +364,9 @@ class ZayaBlock(nn.Module):
 
 
 class ZayaForCausalLM(nn.Module):
-    """Token ids [B, T] → logits [B, T, V] in float32.
+    """Token ids [B, T] → logits [B, T, V] in float32, or with
+    ``head_inputs=True`` the :class:`HeadInputs` the training loss takes
+    (the final hidden state and the tied embedding, no product made).
 
     What flows from layer to layer is the pair ``(x, s)``: the residual
     stream and the router's state. Every call sows ``moe_tokens``, the
@@ -376,7 +379,7 @@ class ZayaForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None,
-                 attention_fn=None):
+                 attention_fn=None, head_inputs=False):
         cfg = self.cfg
         if kv_caches is not None:
             raise NotImplementedError(
@@ -413,6 +416,8 @@ class ZayaForCausalLM(nn.Module):
         self.sow("intermediates", "moe_live",
                  jnp.sum(counts > 0, axis=1, dtype=jnp.int32))
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
+        if head_inputs:
+            return HeadInputs(x, emb, True)
         with jax.named_scope("lm_head"):
             # tied, and straight to float32: no bfloat16 copy of [T, V]
             return jnp.einsum("bth,vh->btv", x, emb.astype(cfg.dtype),

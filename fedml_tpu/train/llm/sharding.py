@@ -117,7 +117,11 @@ def init_sharded_params(model, sample_tokens, mesh: Mesh, seed: int = 0,
     train program's compile+execute, not training statistics.
     """
     key = jax.random.key(seed)
-    abstract = jax.eval_shape(model.init, key, sample_tokens)
+    # ONE bound method for both uses: JAX keeps a trace by the function
+    # object, weakly, and a second ``model.init`` is a new object, so the
+    # jit below would trace the whole model again (2 s at 24 layers)
+    init = model.init
+    abstract = jax.eval_shape(init, key, sample_tokens)
     shardings = logical_shardings(abstract, mesh)
     if zeros:
         ab, sh = unbox(abstract), unbox(shardings)
@@ -127,6 +131,6 @@ def init_sharded_params(model, sample_tokens, mesh: Mesh, seed: int = 0,
             lambda: jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), ab),
             out_shardings=sh)
         return zeros_fn(), sh
-    init_fn = jax.jit(model.init, out_shardings=shardings)
+    init_fn = jax.jit(init, out_shardings=shardings)
     params = init_fn(key, sample_tokens)
     return unbox(params), unbox(shardings)

@@ -17,6 +17,7 @@ Parity target: ``train/llm/hf_trainer.py:28`` (HFTrainer w/ checkpointing)
 """
 from __future__ import annotations
 
+import dataclasses
 import logging
 import os
 from typing import Any, Tuple
@@ -191,17 +192,23 @@ class LLMTrainer:
         # ``self`` — or every trainer's whole params tree would stay
         # resident until the next one re-registers the program names
         model = self.model
+        # the mesh cuts the head's vocabulary axis; the loss cuts its
+        # blocks inside each shard (models/llm/head_loss.py)
+        vocab_shards = self.mesh.shape[dict(LOGICAL_RULES)["vocab"]]
+
+        def head_inputs(p, x, **kwargs):
+            out = model.apply(p, x, attention_fn=attention_fn,
+                              head_inputs=True, **kwargs)
+            head, *state = out if kwargs else (out,)
+            return (dataclasses.replace(head, shards=vocab_shards), *state)
 
         def apply_fn(p, x):
             # activation constraints inside the model resolve against these
             # logical→mesh rules (otherwise they are silent no-ops)
             with nn.logical_axis_rules(LOGICAL_RULES):
                 if not aux_w and not stat_names:
-                    return model.apply(p, x, attention_fn=attention_fn)
-                logits, state = model.apply(
-                    p, x, attention_fn=attention_fn,
-                    mutable=["intermediates"],
-                )
+                    return head_inputs(p, x)[0]
+                head, state = head_inputs(p, x, mutable=["intermediates"])
                 sown = dict(state["intermediates"])
                 # counts the module sows once, at its top (a tuple of one)
                 stats = {k: sown.pop(k)[0] for k in stat_names}
@@ -210,7 +217,7 @@ class LLMTrainer:
                 # router collapses
                 auxes = jax.tree.leaves(sown)
                 aux = aux_w * sum(auxes) / max(len(auxes), 1)
-                return (logits, aux, stats) if stats else (logits, aux)
+                return (head, aux, stats) if stats else (head, aux)
 
         self._loss_fn = causal_lm_loss(apply_fn)
 
@@ -218,7 +225,7 @@ class LLMTrainer:
             # evaluation reports PURE cross-entropy: no aux regularizer, so
             # perplexity and dense-baseline comparisons stay meaningful
             with nn.logical_axis_rules(LOGICAL_RULES):
-                return model.apply(p, x, attention_fn=attention_fn)
+                return head_inputs(p, x)[0]
 
         self._eval_loss_fn = causal_lm_loss(eval_apply_fn)
         self._train_step = None  # compiled lazily once shardings exist

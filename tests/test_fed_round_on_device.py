@@ -29,8 +29,20 @@ def _copy(t):
     return jax.tree.map(jnp.copy, t)
 
 
-def test_fused_round_matches_host_loop():
-    cfg = LlamaConfig.tiny(lora_rank=4, use_flash=False)
+# bfloat16 is what every cell computes in. The two sides are two XLA
+# programs, and at its default the CPU compiler is allowed excess precision:
+# it drops a rounding to bfloat16 wherever it can, and not the same ones in
+# both, so they part by an ulp somewhere in the layers' backward pass and
+# Adam carries that into the adapters (PERF.md, PR 31's finding, has the
+# leaf, the step and the sizes, on this tree and on its parent). With
+# every rounding the source writes kept, the two agree to the order of
+# FedAvg's sum. float32 has nothing to drop.
+@pytest.mark.parametrize("dtype, options", [
+    (jnp.bfloat16, {"xla_allow_excess_precision": False}),
+    (jnp.float32, {}),
+], ids=["bfloat16", "float32"])
+def test_fused_round_matches_host_loop(dtype, options):
+    cfg = LlamaConfig.tiny(lora_rank=4, use_flash=False, dtype=dtype)
     tr = LLMTrainer(cfg, _Args())
     tr.init(seed=0)
     n_clients, steps, batch, seq = 3, 2, 4, 16
@@ -48,12 +60,13 @@ def test_fused_round_matches_host_loop():
     # host round loop — exactly what the fused program replaces
     from fedml_tpu.ml.aggregator.agg_operator import FedMLAggOperator
 
+    train_step = jax.jit(tr._train_step.jitted, compiler_options=options)
     p, o = _copy(p0), _copy(o0)
     uploads = []
     for c in range(n_clients):
         p = merge_lora(p, _copy(g0))
         for s in range(steps):
-            p, o, _ = tr._train_step(
+            p, o, _ = train_step(
                 p, o,
                 jnp.asarray(xs[c, s][None]), jnp.asarray(ys[c, s][None]),
                 jnp.asarray(ms[c, s][None]),
@@ -61,7 +74,8 @@ def test_fused_round_matches_host_loop():
         uploads.append(_copy(extract_lora(p)))
     host_global = FedMLAggOperator.agg_with_weights(uploads, list(w))
 
-    fed = tr.compile_federated_round(n_clients, steps)
+    fed = jax.jit(tr.compile_federated_round(n_clients, steps).jitted,
+                  compiler_options=options)
     p1, o1, fused_global, loss = fed(p0, o0, g0, xs, ys, ms, w)
     assert np.isfinite(float(loss))
     assert set(fused_global) == set(host_global)
@@ -95,6 +109,13 @@ def test_fused_round_chains_via_donation():
         p, o, g, loss = fed(p, o, g, xs, ys, ms, w)
         losses.append(float(loss))
     assert losses[-1] < losses[0]  # same data every round → loss must drop
+    # the trainer told the loss that its mesh cuts the vocabulary in 2
+    # (``mesh_tp``), so the head is walked inside each shard
+    from fedml_tpu.telemetry import get_tracer
+
+    plans = [r["attrs"] for r in get_tracer().records()
+             if r["name"] == "loss/plan"]
+    assert plans[-1]["shards"] == 2 and plans[-1]["vocab"] == cfg.vocab_size
 
 
 def test_fused_round_requires_lora():

@@ -222,11 +222,14 @@ def test_span_name_lint_passes():
 
 
 # (scope, also in the backward pass). The optimizer and FedAvg are not
-# differentiated; the frozen embedding has no gradient. ``client_switch``
+# differentiated; the frozen embedding has no gradient; the head's two
+# products are both made in the forward rule of the loss's own VJP
+# (models/llm/head_loss.py), whose backward rule only multiplies by the
+# cotangent, under ``loss``. ``client_switch``
 # is in the source too but lowers to no operation: merge_lora swaps tree
 # leaves, and what the device runs for it are the scan's own carry copies.
 SCOPES = [("optimizer", False), ("fedavg", False), ("embed", False),
-          ("loss", True), ("lm_head", True), ("rope", True),
+          ("loss", True), ("lm_head", False), ("rope", True),
           ("attn_layout", True)]
 
 
