@@ -312,3 +312,30 @@ def head_loss(inputs: HeadInputs, y, w):
             return _finish(m, s, target, w)
     return _head_loss(inputs.hidden, inputs.head, y, w, inputs.tied,
                       inputs.shards)
+
+
+def causal_lm_loss(apply_fn):
+    """Next-token CE over a [B, T] token batch; mask is [B] sample validity.
+
+    Matches the trainer contract in ``ml/trainer/local_sgd.py`` so the LLM
+    drops into every federated engine unchanged: ``loss, (correct, denom,
+    *stats)``, where ``correct`` counts the valid rows whose target's logit
+    is the row's maximum (a target that ties it exactly counts; see
+    ``head_loss``).
+    """
+
+    def loss_fn(params, x, y, mask):
+        out = apply_fn(params, x)  # y: next tokens [B, T]
+        # apply_fns return the model's HeadInputs (``head_inputs=True``);
+        # MoE ones (HeadInputs, aux_loss) and, where the model counts
+        # something a round, a dict of those counts
+        head, aux, *stats = out if isinstance(out, tuple) else (out, 0.0)
+        with jax.named_scope("loss"):
+            valid = (y >= 0).astype(jnp.float32) * mask[:, None]
+            denom = jnp.maximum(jnp.sum(valid), 1.0)
+        # the product, the cross-entropy and their gradient in one
+        total, correct = head_loss(head, y, valid)
+        with jax.named_scope("loss"):
+            return total / denom + aux, (correct, denom, *stats)
+
+    return loss_fn

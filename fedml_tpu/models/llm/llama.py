@@ -20,13 +20,17 @@ Compute dtype is bf16 by default (MXU-native); params stay fp32 masters.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.llm.head_loss import HeadInputs, head_loss
+from fedml_tpu.models.llm import preset_from_args
+from fedml_tpu.models.llm.causal_lm import CausalLM
+from fedml_tpu.models.llm.layers import (RMSNorm, apply_rope,
+                                         causal_attention, lora_dense,
+                                         merge_heads)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,6 +76,10 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        return self.head_dim
 
     @property
     def aux_loss_weight(self) -> float:
@@ -128,164 +136,23 @@ class LlamaConfig:
         kw.setdefault("remat", False)
         return LlamaConfig(**kw)
 
-    PRESETS = ("tiny", "llama2_7b", "llama2_13b", "llama3_8b")
+    # what ``model_size`` may say, and the preset it means
+    PRESETS = {"tiny": "tiny", "llama2_7b": "llama2_7b", "7b": "llama2_7b",
+               "llama2_13b": "llama2_13b", "13b": "llama2_13b",
+               "llama3_8b": "llama3_8b", "8b": "llama3_8b"}
+    # the fields a user's yaml may override by name
+    YAML_FIELDS = ("lora_rank", "lora_alpha", "max_position_embeddings",
+                   "num_hidden_layers", "hidden_size", "num_experts",
+                   "num_experts_per_tok", "moe_capacity_factor")
 
-    @staticmethod
-    def from_args(args: Any, vocab_size: Optional[int] = None) -> "LlamaConfig":
-        preset = str(
-            getattr(args, "model_size", None)
-            or getattr(args, "model_name", "tiny")
-        ).lower().replace("-", "_")
-        kw = {}
-        for field in ("lora_rank", "lora_alpha", "max_position_embeddings",
-                      "num_hidden_layers", "hidden_size", "num_experts",
-                      "num_experts_per_tok", "moe_capacity_factor"):
-            if getattr(args, field, None) is not None:
-                kw[field] = type(LlamaConfig.__dataclass_fields__[field].default)(
-                    getattr(args, field)
-                )
-        if getattr(args, "use_flash_attention", None) is not None:
-            kw["use_flash"] = bool(args.use_flash_attention)
-        if getattr(args, "remat_policy", None) is not None:
-            kw["remat_policy"] = str(args.remat_policy)
-        if bool(getattr(args, "base_params_bf16", False)):
-            kw["param_dtype"] = jnp.bfloat16
-        builder = {
-            "tiny": LlamaConfig.tiny,
-            "llama2_7b": LlamaConfig.llama2_7b,
-            "7b": LlamaConfig.llama2_7b,
-            "llama2_13b": LlamaConfig.llama2_13b,
-            "13b": LlamaConfig.llama2_13b,
-            "llama3_8b": LlamaConfig.llama3_8b,
-            "8b": LlamaConfig.llama3_8b,
-        }.get(preset, LlamaConfig.tiny)
-        # build the preset bare, then overlay user overrides — presets pass
-        # their architecture fields explicitly, so builder(**kw) would raise
-        # 'multiple values' for overlapping keys
-        cfg = builder()
-        if kw:
-            cfg = dataclasses.replace(cfg, **kw)
-        if vocab_size is not None and preset == "tiny":
-            cfg = dataclasses.replace(cfg, vocab_size=max(vocab_size, 32))
-        return cfg
+    @classmethod
+    def from_args(cls, args: Any, vocab_size: Optional[int] = None) -> "LlamaConfig":
+        return preset_from_args(cls, args, vocab_size)
 
 
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)
-        normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + self.eps)
-        return (normed * scale).astype(self.dtype)
-
-
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """cos/sin tables for rotary embeddings; positions [B, T] or [T]."""
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
-    angles = positions.astype(jnp.float32)[..., None] * freqs  # [..., T, D/2]
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array):
-    """x: [B, H, T, D]; cos/sin: [B, T, D/2] or [T, D/2]."""
-    with jax.named_scope("rope"):
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        if cos.ndim == 2:
-            cos, sin = cos[None, None], sin[None, None]
-        else:
-            cos, sin = cos[:, None], sin[:, None]
-        return jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-        ).astype(x.dtype)
-
-
-def _maybe_packed_param(module, name, init_box, shape, dtype):
-    """``self.param``, except a 4-bit packed kernel is read straight from
-    the variable dict.
-
-    Flax's param path leaf-compares the stored value against the
-    initializer's eval_shape; an int8 :class:`QuantizedTensor` passes
-    (its data keeps the kernel shape) but a :class:`QuantizedTensor4`
-    legitimately differs — packed nibbles are ``[n_blocks, block//2]``.
-    The packed base is frozen (never initialized, never differentiated),
-    so skipping the shape check loses nothing.
-    """
-    from fedml_tpu.ops.quant import QuantizedTensor4
-
-    scope = module.scope
-    if scope.has_variable("params", name):
-        v = scope.get_variable("params", name)
-        # raw model.init params keep flax partitioning boxes; the packed
-        # value may live inside one (the trainer stores unboxed)
-        if isinstance(v, nn.meta.AxisMetadata):
-            v = v.unbox()
-        if isinstance(v, QuantizedTensor4):
-            return v
-    return module.param(name, init_box, shape, dtype)
-
-
-class LoRADense(nn.Module):
-    """Dense with optional additive low-rank adapter: y = xW + (x A) B * s.
-
-    The base kernel is a normal flax param (frozen by the LLM optimizer
-    mask); ``lora_a/lora_b`` live under the same params tree with a
-    ``lora_`` name prefix, which is what the trainable/exchange filters key
-    on (``fedml_tpu/train/llm/federated.py``).
-    """
-
-    features: int
-    rank: int = 0
-    alpha: float = 16.0
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32  # base kernel storage; lora_a/b stay fp32
-    kernel_axes: Tuple[str, ...] = ()
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = _maybe_packed_param(
-            self,
-            "kernel",
-            nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), self.kernel_axes
-            ),
-            (x.shape[-1], self.features),
-            self.param_dtype,
-        )
-        from fedml_tpu.ops.quant import matmul_maybe_quantized
-
-        y = matmul_maybe_quantized(x, kernel, self.dtype)
-        if self.rank > 0:
-            a = self.param(
-                "lora_a",
-                nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(),
-                    (self.kernel_axes[0] if self.kernel_axes else None, None),
-                ),
-                (x.shape[-1], self.rank),
-                jnp.float32,
-            )
-            b = self.param(
-                "lora_b",
-                nn.with_logical_partitioning(
-                    nn.initializers.zeros,
-                    (None, self.kernel_axes[1] if len(self.kernel_axes) > 1 else None),
-                ),
-                (self.rank, self.features),
-                jnp.float32,
-            )
-            scaling = self.alpha / self.rank
-            y = y + (x @ a.astype(self.dtype)) @ b.astype(self.dtype) * scaling
-        return y
-
-
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
 
@@ -294,15 +161,9 @@ class LlamaAttention(nn.Module):
         cfg = self.cfg
         b, t, _ = x.shape
         h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        dense = lambda feats, name, axes: LoRADense(
-            feats, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, kernel_axes=axes, name=name,
-        )
-        q = dense(h * d, "q_proj", ("embed", "heads"))(x)
-        k = dense(hkv * d, "k_proj", ("embed", "heads"))(x)
-        v = dense(hkv * d, "v_proj", ("embed", "heads"))(x)
-        # flax names the projections; what is not a module gets a scope of
-        # its own, so a device trace can tell the glue from the matmuls
+        q = lora_dense(cfg, h * d, "q_proj", ("embed", "heads"))(x)
+        k = lora_dense(cfg, hkv * d, "k_proj", ("embed", "heads"))(x)
+        v = lora_dense(cfg, hkv * d, "v_proj", ("embed", "heads"))(x)
         with jax.named_scope("attn_layout"):
             q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
             k = k.reshape(b, t, hkv, d).transpose(0, 2, 1, 3)
@@ -343,19 +204,9 @@ class LlamaAttention(nn.Module):
             out = jnp.einsum("bhts,bhsd->bhtd", probs, vv.astype(jnp.float32))
             out = out.astype(cfg.dtype)
         else:
-            if attention_fn is not None:
-                out = attention_fn(q, k, v)
-            elif cfg.use_flash:
-                from fedml_tpu.ops.flash_attention import flash_attention
-
-                out = flash_attention(q, k, v, causal=True)
-            else:
-                from fedml_tpu.ops.flash_attention import reference_attention
-
-                out = reference_attention(q, k, v, causal=True)
-        with jax.named_scope("attn_layout"):
-            out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-        out = dense(cfg.hidden_size, "o_proj", ("heads", "embed"))(out)
+            out = causal_attention(q, k, v, cfg, attention_fn)
+        out = lora_dense(cfg, cfg.hidden_size, "o_proj", ("heads", "embed"))(
+            merge_heads(out))
         return out, new_cache
 
 
@@ -365,15 +216,12 @@ class LlamaMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        dense = lambda feats, name, axes: LoRADense(
-            feats, rank=0, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            kernel_axes=axes, name=name
-        )
-        gate = dense(cfg.intermediate_size, "gate_proj", ("embed", "mlp"))(x)
-        up = dense(cfg.intermediate_size, "up_proj", ("embed", "mlp"))(x)
-        return dense(cfg.hidden_size, "down_proj", ("mlp", "embed"))(
-            nn.silu(gate) * up
-        )
+        mid, up_axes = cfg.intermediate_size, ("embed", "mlp")
+        # frozen under LoRA: the adapters sit on the attention projections
+        gate = lora_dense(cfg, mid, "gate_proj", up_axes, adapters=False)(x)
+        up = lora_dense(cfg, mid, "up_proj", up_axes, adapters=False)(x)
+        return lora_dense(cfg, cfg.hidden_size, "down_proj", ("mlp", "embed"),
+                          adapters=False)(nn.silu(gate) * up)
 
 
 class LlamaMoE(nn.Module):
@@ -474,10 +322,13 @@ class LlamaMoE(nn.Module):
 
 
 class LlamaBlock(nn.Module):
+    """A layer under ``causal_lm.py``'s block protocol: nothing carried
+    beside ``x``, nothing counted, a key-value cache taken."""
+
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, cos, sin, kv_cache=None, attention_fn=None):
+    def __call__(self, x, carry, cos, sin, kv_cache=None, attention_fn=None):
         cfg = self.cfg
         # pin the residual stream to (batch, seq, embed) so SPMD never
         # round-trips activations through a tp-sharded layout in the bwd pass
@@ -493,78 +344,15 @@ class LlamaBlock(nn.Module):
         x = x + ffn(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="post_attn_norm")(x)
         )
-        return x, new_cache
+        return x, carry, new_cache, None
 
 
-class LlamaForCausalLM(nn.Module):
-    """Token ids [B, T] → logits [B, T, V].
+class LlamaForCausalLM(CausalLM):
+    """:class:`CausalLM` over :class:`LlamaBlock`, and the caches its
+    attention takes (``fedml_tpu/serving``)."""
 
-    ``__call__(tokens)`` is the forward that serving, conversion and the
-    parity tests read; ``head_inputs=True`` stops before the head's product
-    and returns :class:`HeadInputs` (the final hidden state and the head's
-    matrix), which is what the training loss takes; ``decode_step`` threads
-    an explicit KV cache for serving (``fedml_tpu/serving``).
-    """
+    block = LlamaBlock
 
-    cfg: LlamaConfig
-
-    @nn.compact
-    def __call__(self, tokens, positions=None, kv_caches=None, attention_fn=None,
-                 head_inputs=False):
-        cfg = self.cfg
-        emb = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("vocab", "embed")
-            ),
-            (cfg.vocab_size, cfg.hidden_size),
-            cfg.param_dtype,
-        )
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens]
-        if positions is None:
-            positions = jnp.arange(tokens.shape[1])
-        with jax.named_scope("rope"):
-            cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-
-        block = LlamaBlock
-        if cfg.remat and cfg.remat_policy != "none" and kv_caches is None:
-            policy = None  # "full": save only block inputs
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            block = nn.remat(LlamaBlock, static_argnums=(5,), policy=policy)
-        new_caches = []
-        for i in range(cfg.num_hidden_layers):
-            cache_i = kv_caches[i] if kv_caches is not None else None
-            x, new_cache = block(cfg, name=f"layer_{i}")(
-                x, cos, sin, cache_i, attention_fn
-            )
-            new_caches.append(new_cache)
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        head = emb if cfg.tie_word_embeddings else _maybe_packed_param(
-            self,
-            "lm_head",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("embed", "vocab")
-            ),
-            (cfg.hidden_size, cfg.vocab_size),
-            cfg.param_dtype,
-        )
-        if head_inputs:
-            return HeadInputs(x, head, cfg.tie_word_embeddings)
-        with jax.named_scope("lm_head"):
-            if cfg.tie_word_embeddings:
-                logits = x @ emb.astype(cfg.dtype).T
-            else:
-                from fedml_tpu.ops.quant import matmul_maybe_quantized
-
-                logits = matmul_maybe_quantized(x, head, cfg.dtype)
-            logits = logits.astype(jnp.float32)
-        if kv_caches is not None:
-            return logits, new_caches
-        return logits
-
-    # -- serving helpers --------------------------------------------------
     def init_kv_caches(self, batch: int, max_len: int):
         cfg = self.cfg
         shape = (batch, cfg.num_key_value_heads, max_len, cfg.head_dim)
@@ -572,30 +360,3 @@ class LlamaForCausalLM(nn.Module):
             (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype), 0)
             for _ in range(cfg.num_hidden_layers)
         ]
-
-
-def causal_lm_loss(apply_fn):
-    """Next-token CE over a [B, T] token batch; mask is [B] sample validity.
-
-    Matches the trainer contract in ``ml/trainer/local_sgd.py`` so the LLM
-    drops into every federated engine unchanged: ``loss, (correct, denom,
-    *stats)``, where ``correct`` counts the valid rows whose target's logit
-    is the row's maximum (a target that ties it exactly counts; see
-    ``head_loss``).
-    """
-
-    def loss_fn(params, x, y, mask):
-        out = apply_fn(params, x)  # y: next tokens [B, T]
-        # apply_fns return the model's HeadInputs (``head_inputs=True``);
-        # MoE ones (HeadInputs, aux_loss) and, where the model counts
-        # something a round, a dict of those counts
-        head, aux, *stats = out if isinstance(out, tuple) else (out, 0.0)
-        with jax.named_scope("loss"):
-            valid = (y >= 0).astype(jnp.float32) * mask[:, None]
-            denom = jnp.maximum(jnp.sum(valid), 1.0)
-        # the product, the cross-entropy and their gradient in one
-        total, correct = head_loss(head, y, valid)
-        with jax.named_scope("loss"):
-            return total / denom + aux, (correct, denom, *stats)
-
-    return loss_fn

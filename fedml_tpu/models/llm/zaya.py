@@ -29,8 +29,7 @@ Attention, ``Hq`` query / ``Hk`` key-value heads of size ``D``, ``g = Hq/Hk``:
 6. rope (halves convention) on the first ``partial_rotary_factor * D``
    dimensions of each head.
 7. causal softmax attention at scale ``1/sqrt(D)`` through the repo's
-   flash kernels (the ``attention_fn`` protocol of ``llama.py``), then
-   ``o_proj``.
+   flash kernels (``layers.causal_attention``), then ``o_proj``.
 
 Experts:
 
@@ -46,7 +45,7 @@ What a federated round trains: LoRA adapters on the five attention
 projections (``attn/{q,k,v,v_prev,o}_proj``), nothing else — router and
 experts are frozen and there is no auxiliary loss. Serving (a latent
 cache, the previous token's ``h`` and the router's state per slot) is not
-implemented: ``kv_caches`` raises.
+implemented: the block raises on a cache.
 """
 from __future__ import annotations
 
@@ -58,9 +57,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.llm.head_loss import HeadInputs
-from fedml_tpu.models.llm.llama import (LoRADense, RMSNorm, apply_rope,
-                                        rope_tables)
+from fedml_tpu.models.llm import preset_from_args
+from fedml_tpu.models.llm.causal_lm import CausalLM
+from fedml_tpu.models.llm.layers import (RMSNorm, apply_rope,
+                                         causal_attention, lora_dense,
+                                         merge_heads)
 from fedml_tpu.ops import grouped_matmul as gmm
 
 L2_EPS = 1e-6  # under the root of a head's squared norm (step 5)
@@ -148,31 +149,17 @@ class ZayaConfig:
             kw.setdefault(k, v)
         return ZayaConfig(**kw)
 
-    @staticmethod
-    def from_args(args: Any, vocab_size: Optional[int] = None) -> "ZayaConfig":
+    # what ``model_size`` may say, and the preset it means
+    PRESETS = {"tiny": "tiny", "zaya1_8b": "zaya1_8b", "8b": "zaya1_8b"}
+    # the fields a user's yaml may override by name
+    YAML_FIELDS = ("lora_rank", "lora_alpha", "num_hidden_layers",
+                   "max_position_embeddings", "moe_block_rows")
+
+    @classmethod
+    def from_args(cls, args: Any, vocab_size: Optional[int] = None) -> "ZayaConfig":
         """``model: zaya`` in a user's yaml; ``model_size`` names a preset
         and the listed keys override it."""
-        preset = str(getattr(args, "model_size", None)
-                     or getattr(args, "model_name", "tiny")
-                     ).lower().replace("-", "_")
-        kw = {}
-        for field in ("lora_rank", "lora_alpha", "num_hidden_layers",
-                      "max_position_embeddings", "moe_block_rows"):
-            if getattr(args, field, None) is not None:
-                kw[field] = type(
-                    ZayaConfig.__dataclass_fields__[field].default)(
-                        getattr(args, field))
-        if getattr(args, "use_flash_attention", None) is not None:
-            kw["use_flash"] = bool(args.use_flash_attention)
-        if getattr(args, "remat_policy", None) is not None:
-            kw["remat_policy"] = str(args.remat_policy)
-        if bool(getattr(args, "base_params_bf16", False)):
-            kw["param_dtype"] = jnp.bfloat16
-        if preset in ("zaya1_8b", "8b"):
-            return ZayaConfig.zaya1_8b(**kw)
-        if vocab_size is not None:
-            kw["vocab_size"] = max(vocab_size, 32)
-        return ZayaConfig.tiny(**kw)
+        return preset_from_args(cls, args, vocab_size)
 
 
 def shift_tokens(x: jax.Array, by: int = 1) -> jax.Array:
@@ -199,14 +186,11 @@ class ZayaAttention(nn.Module):
         h, hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
         heads, group = h + hkv, h // hkv
-        dense = lambda feats, name, axes: LoRADense(
-            feats, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, kernel_axes=axes, name=name)
-        q_lat = dense(h * d, "q_proj", ("embed", "heads"))(x)
-        k_lat = dense(hkv * d, "k_proj", ("embed", "heads"))(x)
-        v_now = dense(hkv // 2 * d, "v_proj", ("embed", "heads"))(x)
-        v_prev = dense(hkv // 2 * d, "v_prev_proj", ("embed", "heads"))(
-            shift_tokens(x))
+        q_lat = lora_dense(cfg, h * d, "q_proj", ("embed", "heads"))(x)
+        k_lat = lora_dense(cfg, hkv * d, "k_proj", ("embed", "heads"))(x)
+        v_now = lora_dense(cfg, hkv // 2 * d, "v_proj", ("embed", "heads"))(x)
+        v_prev = lora_dense(cfg, hkv // 2 * d, "v_prev_proj",
+                            ("embed", "heads"))(shift_tokens(x))
 
         taps = nn.initializers.normal(1.0 / cfg.cca_time0)
         conv0 = self.param("conv0_kernel", taps, (cfg.cca_time0, heads * d),
@@ -254,19 +238,9 @@ class ZayaAttention(nn.Module):
         k = jnp.concatenate(
             [apply_rope(k[..., :rot], cos, sin), k[..., rot:]], -1)
 
-        if attention_fn is not None:
-            out = attention_fn(q, k, v)
-        elif cfg.use_flash:
-            from fedml_tpu.ops.flash_attention import flash_attention
-
-            out = flash_attention(q, k, v, causal=True)
-        else:
-            from fedml_tpu.ops.flash_attention import reference_attention
-
-            out = reference_attention(q, k, v, causal=True)
-        with jax.named_scope("attn_layout"):
-            out = out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-        return dense(cfg.hidden_size, "o_proj", ("heads", "embed"))(out)
+        out = merge_heads(causal_attention(q, k, v, cfg, attention_fn))
+        return lora_dense(cfg, cfg.hidden_size, "o_proj", ("heads", "embed"))(
+            out)
 
 
 class ZayaRouter(nn.Module):
@@ -347,11 +321,20 @@ class ZayaMoE(nn.Module):
 
 
 class ZayaBlock(nn.Module):
+    """A layer under ``causal_lm.py``'s block protocol: the router's state
+    is carried down the stack, the tokens each expert was sent are
+    counted, and no cache is taken."""
+
     cfg: ZayaConfig
 
     @nn.compact
-    def __call__(self, x, state, cos, sin, attention_fn=None):
+    def __call__(self, x, state, cos, sin, cache=None, attention_fn=None):
         cfg = self.cfg
+        if cache is not None:
+            raise NotImplementedError(
+                "zaya: serving is not implemented (a slot would hold a "
+                "latent cache, the previous token's h and the router's "
+                "state); training only")
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         x = x + ZayaAttention(cfg, name="attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_norm")(x),
@@ -360,13 +343,11 @@ class ZayaBlock(nn.Module):
         y, state, counts = ZayaMoE(cfg, name="moe")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="post_attn_norm")(x),
             state)
-        return x + y, state, counts
+        return x + y, state, None, counts
 
 
-class ZayaForCausalLM(nn.Module):
-    """Token ids [B, T] → logits [B, T, V] in float32, or with
-    ``head_inputs=True`` the :class:`HeadInputs` the training loss takes
-    (the final hidden state and the tied embedding, no product made).
+class ZayaForCausalLM(CausalLM):
+    """:class:`CausalLM` over :class:`ZayaBlock`.
 
     What flows from layer to layer is the pair ``(x, s)``: the residual
     stream and the router's state. Every call sows ``moe_tokens``, the
@@ -375,50 +356,15 @@ class ZayaForCausalLM(nn.Module):
     per layer the number of experts that were sent any.
     """
 
-    cfg: ZayaConfig
+    block = ZayaBlock
 
-    @nn.compact
-    def __call__(self, tokens, positions=None, kv_caches=None,
-                 attention_fn=None, head_inputs=False):
-        cfg = self.cfg
-        if kv_caches is not None:
-            raise NotImplementedError(
-                "zaya: serving is not implemented (a slot would hold a "
-                "latent cache, the previous token's h and the router's "
-                "state); training only")
-        emb = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("vocab", "embed")),
-            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        with jax.named_scope("embed"):
-            x = emb.astype(cfg.dtype)[tokens]
-        if positions is None:
-            positions = jnp.arange(tokens.shape[1])
-        with jax.named_scope("rope"):
-            cos, sin = rope_tables(positions, cfg.rotary_dim, cfg.rope_theta)
-        state = jnp.zeros((*tokens.shape, cfg.router_hidden_size),
-                          jnp.float32)
+    @nn.nowrap
+    def init_carry(self, tokens):
+        return jnp.zeros((*tokens.shape, self.cfg.router_hidden_size),
+                         jnp.float32)
 
-        block = ZayaBlock
-        if cfg.remat and cfg.remat_policy != "none":
-            policy = None
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            block = nn.remat(ZayaBlock, static_argnums=(5,), policy=policy)
-        counts = []
-        for i in range(cfg.num_hidden_layers):
-            x, state, n = block(cfg, name=f"layer_{i}")(
-                x, state, cos, sin, attention_fn)
-            counts.append(n)
-        counts = jnp.stack(counts)
-        self.sow("intermediates", "moe_tokens", counts)
-        self.sow("intermediates", "moe_live",
-                 jnp.sum(counts > 0, axis=1, dtype=jnp.int32))
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="final_norm")(x)
-        if head_inputs:
-            return HeadInputs(x, emb, True)
-        with jax.named_scope("lm_head"):
-            # tied, and straight to float32: no bfloat16 copy of [T, V]
-            return jnp.einsum("bth,vh->btv", x, emb.astype(cfg.dtype),
-                              preferred_element_type=jnp.float32)
+    @nn.nowrap
+    def layer_stats(self, stats):
+        counts = jnp.stack(stats)
+        return {"moe_tokens": counts,
+                "moe_live": jnp.sum(counts > 0, axis=1, dtype=jnp.int32)}

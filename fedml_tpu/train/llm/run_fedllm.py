@@ -39,8 +39,9 @@ class FedLLMAPI:
 
     def __init__(self, args: Any, device: Any, dataset: FederatedDataset,
                  cfg: Any = None, mesh=None):
-        """``cfg``: a model configuration object (``LlamaConfig``,
-        ``ZayaConfig``); without one it is built from ``args`` by the
+        """``cfg``: a model family's configuration object, whose
+        ``module()`` is a ``models/llm/causal_lm.py::CausalLM`` bound to
+        the family's block; without one it is built from ``args`` by the
         ``model`` they name (``models.llm.config_from_args``)."""
         self.args = args
         self.dataset = dataset

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from fedml_tpu.models.llm.llama import causal_lm_loss
+from fedml_tpu.models.llm.head_loss import causal_lm_loss
 from fedml_tpu.train.llm.sharding import (
     batch_sharding,
     data_parallel_size,
@@ -95,11 +95,11 @@ def merge_lora(params: Pytree, lora: dict) -> Pytree:
 class LLMTrainer:
     """Compiled causal-LM fine-tuning over a named mesh.
 
-    ``cfg`` is a model configuration object (``LlamaConfig``,
-    ``ZayaConfig``): the trainer asks it for its flax module
-    (``cfg.module()``), the weight of sown auxiliary losses
-    (``cfg.aux_loss_weight``) and the names of the counts its module sows
-    for a round (``cfg.round_stats``), and knows no model by name."""
+    ``cfg`` is a model family's configuration object: the trainer asks it
+    for its flax module (``cfg.module()``, a ``models/llm/causal_lm.py::
+    CausalLM`` bound to the family's block), the weight of sown auxiliary
+    losses (``cfg.aux_loss_weight``) and the names of the counts its module
+    sows for a round (``cfg.round_stats``), and knows no model by name."""
 
     def __init__(self, cfg: Any, args: Any, mesh=None):
         self.cfg = cfg
@@ -131,16 +131,13 @@ class LLMTrainer:
             optax.clip_by_global_norm(float(getattr(args, "max_grad_norm", 1.0))),
             optax.adamw(sched, weight_decay=wd),
         )
-        if self.lora_only:
-            # the train step differentiates ONLY the trainable flat dict
-            # (extract_trainable) and the optimizer runs on that dict —
-            # frozen base weights never see a gradient, which both drops
-            # the reliance on XLA DCE'ing 13.5 GB of dead wgrads and is
-            # what makes an int8-quantized base (QLoRA) differentiable
-            # at all (jax.grad refuses int8 inputs).
-            self.tx = base_tx
-        else:
-            self.tx = base_tx
+        # one optimizer for both modes. Under LoRA the train step
+        # differentiates ONLY the trainable flat dict (extract_trainable)
+        # and the optimizer runs on that dict — frozen base weights never
+        # see a gradient, which both drops the reliance on XLA DCE'ing
+        # 13.5 GB of dead wgrads and is what makes an int8-quantized base
+        # (QLoRA) differentiable at all (jax.grad refuses int8 inputs).
+        self.tx = base_tx
         # QLoRA: store the frozen base quantized — per-channel int8
         # (ops/quant.quantize_int8, 6.9 GB instead of 13.5 at 7B) or
         # blockwise 4-bit int4/nf4 (ops/quant.quantize_int4, ~3.6 GB) —

@@ -83,53 +83,6 @@ def test_llama_forward_and_decode_parity():
     assert float(jnp.abs(stitched - full).max()) < 1e-4
 
 
-@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
-def test_head_inputs_give_the_loss_the_default_calls_logits_give(tied):
-    """The default call returns what it returned before the loss took the
-    head over (the head's product in the compute type, then float32);
-    ``head_inputs=True`` stops before that product, and the loss made from
-    it is ``optax``'s over those logits — with every leaf differentiated,
-    the head's own among them (full fine-tuning)."""
-    import optax
-
-    from fedml_tpu.models.llm.head_loss import head_loss
-    from fedml_tpu.train.llm.sharding import unbox
-
-    cfg = LlamaConfig.tiny(use_flash=False, tie_word_embeddings=tied,
-                           dtype=jnp.float32)
-    model = LlamaForCausalLM(cfg)
-    toks = jax.random.randint(jax.random.key(0), (2, 16), 0, cfg.vocab_size)
-    y = jnp.roll(toks, -1, axis=1).at[:, -1].set(-1)
-    params = unbox(model.init(jax.random.key(0), toks))
-    w = (y >= 0).astype(jnp.float32)
-
-    out = model.apply(params, toks, head_inputs=True)
-    head = params["params"]["embed_tokens" if tied else "lm_head"]
-    assert out.tied == tied and out.head is head
-    logits = model.apply(params, toks)
-    before = out.hidden @ (head.T if tied else head)
-    np.testing.assert_array_equal(logits, before.astype(jnp.float32))
-
-    def ours(p):
-        return head_loss(model.apply(p, toks, head_inputs=True), y, w)[0]
-
-    def theirs(p):
-        ce = optax.softmax_cross_entropy_with_integer_labels(
-            model.apply(p, toks), jnp.maximum(y, 0))
-        return jnp.sum(ce * w)
-
-    (loss, grads), (want, want_grads) = (
-        jax.value_and_grad(f)(params) for f in (ours, theirs))
-    np.testing.assert_allclose(loss, want, rtol=1e-6)
-    flat, want_flat = (dict(jax.tree_util.tree_flatten_with_path(g)[0])
-                       for g in (grads, want_grads))
-    assert flat.keys() == want_flat.keys()
-    for path, g in flat.items():
-        np.testing.assert_allclose(g, want_flat[path], atol=2e-5, rtol=0,
-                                   err_msg=str(path))
-    assert float(jnp.abs(grads["params"]["embed_tokens"]).max()) > 1e-3
-
-
 @pytest.mark.slow
 def test_llm_trainer_converges_full_ft():
     from fedml_tpu.train.llm.trainer import LLMTrainer

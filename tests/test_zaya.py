@@ -92,33 +92,6 @@ def test_logits_and_loss_are_the_references(f32):
     np.testing.assert_array_equal(counts.sum(1), B * T)
 
 
-def test_head_inputs_give_the_loss_the_default_calls_logits_give(f32):
-    """The default call returns what it returned before the loss took the
-    head over (the tied product straight to float32);
-    ``head_inputs=True`` stops before it, and the loss made from that is
-    ``optax``'s over those logits, targets of -1 left out."""
-    import optax
-
-    from fedml_tpu.models.llm.head_loss import head_loss
-
-    cfg, params, tokens = f32
-    y = jnp.roll(tokens, -1, axis=1).at[:, -1].set(-1)
-    w = (y >= 0).astype(jnp.float32)
-    model = cfg.module()
-    out = model.apply(params, tokens, head_inputs=True)
-    assert out.tied and out.head is params["params"]["embed_tokens"]
-    logits = model.apply(params, tokens)
-    np.testing.assert_array_equal(logits, jnp.einsum(
-        "bth,vh->btv", out.hidden, out.head,
-        preferred_element_type=jnp.float32))
-    total, correct = head_loss(out, y, w)
-    ce = optax.softmax_cross_entropy_with_integer_labels(
-        logits, jnp.maximum(y, 0))
-    np.testing.assert_allclose(total, jnp.sum(ce * w), rtol=1e-6)
-    assert float(correct) == float(
-        jnp.sum((jnp.argmax(logits, -1) == y) * w))
-
-
 def test_every_adapter_leafs_gradient_is_the_references(f32):
     """``jax.grad`` of the module's loss against ``jax.grad`` of the plain
     reference's, for all 20 adapter leaves of both layers; float32 both,
