@@ -10,6 +10,9 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from fedml_tpu.telemetry import get_tracer
 
 
 class RMSNorm(nn.Module):
@@ -33,17 +36,99 @@ def rope_tables(positions: jax.Array, head_dim: int, theta: float):
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array):
-    """x: [B, H, T, D]; cos/sin: [B, T, D/2] or [T, D/2]."""
+def _half_swap(head_dim: int, rotary_dim: int) -> np.ndarray:
+    """The rotate-half convention as a signed permutation ``P[D, D]``:
+    ``(x @ P)[j] = -x[j + r]`` and ``(x @ P)[j + r] = x[j]`` for ``j < r``,
+    ``r`` half of the rotary width; lanes past it get zero columns."""
+    r = rotary_dim // 2
+    j = np.arange(r)
+    p = np.zeros((head_dim, head_dim), np.float32)
+    p[j + r, j] = -1.0
+    p[j, j + r] = 1.0
+    return p
+
+
+def _lanes(table: jax.Array, head_dim: int, rest: float) -> jax.Array:
+    """``[..., T, r]`` -> ``[..., 1, T, D]``: the table over both halves,
+    ``rest`` on the lanes past the rotary width, broadcast over heads."""
+    pad = jnp.full(table.shape[:-1] + (head_dim - 2 * table.shape[-1],),
+                   rest, table.dtype)
+    return jnp.concatenate([table, table, pad], -1)[..., None, :, :]
+
+
+def _swapped(x: jax.Array, rotary_dim: int, back: bool = False) -> jax.Array:
+    """``x @ P`` in float32, or ``x @ P.T`` (``= -P``: the swap ``back``).
+    One non-zero term a column, so the product is exact — given that an
+    operand wider than bfloat16 is not rounded to it, which the MXU's
+    default precision would do."""
+    p = _half_swap(x.shape[-1], rotary_dim)
+    p = jnp.asarray(p.T if back else p, x.dtype)
+    exact = None if x.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    return jnp.einsum("bhtd,de->bhte", x, p, precision=exact,
+                      preferred_element_type=jnp.float32)
+
+
+def _rotate(x, cos, sin, back: bool = False):
+    """``x * [cos, cos, 1...] + (x @ P) * [sin, sin, 0...]`` in float32,
+    rounded once to ``x.dtype``; ``back`` turns by the opposite angle. No
+    split and no concatenation of ``x``'s minor dimension: those XLA does
+    not fuse, and it keeps float32 copies of ``x`` and half-width arrays in
+    HBM to feed them."""
+    d = x.shape[-1]
     with jax.named_scope("rope"):
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        if cos.ndim == 2:
-            cos, sin = cos[None, None], sin[None, None]
-        else:
-            cos, sin = cos[:, None], sin[:, None]
-        return jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-        ).astype(x.dtype)
+        y = (x.astype(jnp.float32) * _lanes(cos, d, 1.0)
+             + _swapped(x, 2 * cos.shape[-1], back) * _lanes(sin, d, 0.0))
+        return y.astype(x.dtype)
+
+
+@jax.custom_vjp
+def _rope(x, cos, sin):
+    return _rotate(x, cos, sin)
+
+
+def _rope_fwd(x, cos, sin):
+    return _rotate(x, cos, sin), (x, cos, sin)
+
+
+def _rope_bwd(res, g):
+    """The rotation back, the same one pass over the same tables: the
+    cotangent in, float32 multiply-adds, one rounding out. Autodiff of
+    :func:`_rotate` would round the cotangent once a consumer of ``x`` and
+    add the two in ``x.dtype``. The tables' cotangents are stated for
+    whoever differentiates them; a training step does not, and they are
+    dead code there."""
+    x, cos, sin = res
+    r = cos.shape[-1]
+    with jax.named_scope("rope"):
+        g32 = g.astype(jnp.float32)
+
+        def table_grad(lanes):
+            over = (0, 1) if cos.ndim == 2 else (1,)
+            full = jnp.sum(g32 * lanes, axis=over)
+            return (full[..., :r] + full[..., r:2 * r]).astype(cos.dtype)
+
+        d_cos = table_grad(x.astype(jnp.float32))
+        d_sin = table_grad(_swapped(x, 2 * r))
+    return _rotate(g, cos, sin, back=True), d_cos, d_sin
+
+
+# ``optimize_remat``: under ``nn.remat`` the forward rule stays one opaque
+# call. Inlined, a policy that keeps products (``remat_policy: dots``) would
+# keep this one's float32 output, twice ``x``'s bytes, to save a pass of
+# elementwise work.
+_rope.defvjp(_rope_fwd, _rope_bwd, optimize_remat=True)
+
+
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array):
+    """x: ``[B, H, T, D]``, whole heads; cos/sin: ``[B, T, r]`` or
+    ``[T, r]``. The rotary width is the tables' (``2 r``): lanes of a head
+    past it pass through unchanged."""
+    b, h, t, d = x.shape
+    get_tracer().event(
+        "rope/plan", rows=b * t, heads=h, head_dim=d,
+        rotary_dim=2 * cos.shape[-1], dtype=jnp.dtype(x.dtype).name,
+        form="product")
+    return _rope(x, cos, sin)
 
 
 def _maybe_packed_param(module, name, init_box, shape, dtype):

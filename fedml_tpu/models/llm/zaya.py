@@ -232,11 +232,9 @@ class ZayaAttention(nn.Module):
                 [v_now.reshape(b, t, hkv // 2, d),
                  v_prev.reshape(b, t, hkv // 2, d)], axis=2,
             ).transpose(0, 2, 1, 3)
-        rot = cfg.rotary_dim
-        q = jnp.concatenate(
-            [apply_rope(q[..., :rot], cos, sin), q[..., rot:]], -1)
-        k = jnp.concatenate(
-            [apply_rope(k[..., :rot], cos, sin), k[..., rot:]], -1)
+        # whole heads: the tables carry the rotary width (half of a head)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
         out = merge_heads(causal_attention(q, k, v, cfg, attention_fn))
         return lora_dense(cfg, cfg.hidden_size, "o_proj", ("heads", "embed"))(

@@ -8,6 +8,7 @@ Everything that touches the TPU library lives in fixtures of THIS file:
 one process at a time may load libtpu, so only the xdist worker that is
 handed this file does (see /opt/skills/guides/on-chip-measurement §2).
 """
+import math
 import os
 import re
 
@@ -200,3 +201,77 @@ def test_fused_round_7b_widths_compiles_for_v5e(topo, monkeypatch, fsdp):
         # weights are gathered per layer
         assert mem.argument_size_in_bytes < 0.3 * 1.36e9
         assert "all-gather" in text
+
+
+def _unfused(text):
+    """``(shapes, op_name)`` of every instruction the compiled module runs
+    as one of its own (not inside a fused computation): what it writes is
+    an array in memory."""
+    fused = False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            fused = line.startswith("%fused_computation")
+            continue
+        found = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if found and op_name and not fused:
+            yield re.findall(r"(\w+)\[([\d,]+)\]", found.group(1)), \
+                op_name.group(1)
+
+
+@pytest.mark.parametrize("b,t,h,hkv,d", [(1, 4096, 32, 32, 64),
+                                         (1, 512, 32, 4, 128)],
+                         ids=["round_long", "round_short"])  # dense cells'
+def test_rope_is_one_pass_each_way_for_v5e(one_chip, monkeypatch,
+                                           b, t, h, hkv, d):
+    """The attention sandwich (projections with adapters, ``attn_layout``,
+    rope on q and k, the flash kernels, ``o_proj``) and its gradient:
+    under ``rope`` there is one fusion a roped tensor a direction, and no
+    array of a tensor's size but those fusions' bfloat16 outputs — no
+    float32 copy of q or k, no half-width array, no concatenation but the
+    tables' ``[T, D]``."""
+    from fedml_tpu.models.llm.layers import rope_tables
+    from fedml_tpu.models.llm.llama import LlamaAttention, LlamaConfig
+    from fedml_tpu.ops import dispatch
+    from fedml_tpu.train.llm.sharding import unbox
+
+    monkeypatch.setattr(dispatch, "default_platform", lambda: "tpu")
+    cfg = LlamaConfig(
+        hidden_size=h * d, num_attention_heads=h, num_key_value_heads=hkv,
+        lora_rank=16, param_dtype=jnp.bfloat16, use_flash=True)
+    attn = LlamaAttention(cfg)
+
+    def tables():
+        with jax.named_scope("rope"):  # as the shell states them
+            return rope_tables(jnp.arange(t), d, cfg.rope_theta)
+
+    def loss(params, x):
+        out, _ = attn.apply(params, x, *tables())
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        unbox(jax.eval_shape(
+            lambda x: attn.init(jax.random.key(0), x, *tables()), x)))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    assert _kernels(compiled) == 3
+
+    roped = {"jvp": [], "transpose": []}
+    for shapes, op_name in _unfused(compiled.as_text()):
+        if not re.search(r"(^|/)rope(/|$)", op_name):
+            continue
+        for dtype, dims in shapes:
+            dims = [int(n) for n in dims.split(",")]
+            if math.prod(dims) <= t * d:
+                continue  # the tables and what they are made of
+            # all that is left is a roped tensor in the compute type
+            assert "dot_general" in op_name, (shapes, op_name)
+            assert dtype == "bf16" and dims[-1] == d, (shapes, op_name)
+            assert math.prod(dims) in (b * t * h * d, b * t * hkv * d)
+            roped["transpose" if "transpose(" in op_name else "jvp"].append(
+                math.prod(dims))
+    want = sorted([b * t * h * d, b * t * hkv * d])
+    assert sorted(roped["jvp"]) == want  # q and k, forward
+    assert sorted(roped["transpose"]) == want  # dq and dk, backward
