@@ -3,7 +3,8 @@ object answers ``cfg.module()`` with its flax module, and
 :func:`config_from_args` picks the configuration class from the ``model``
 a user's yaml names.
 
-A family is added as a file beside ``llama.py`` and ``zaya.py`` that edits
+A family is added as a file beside ``llama.py``, ``zaya.py`` and
+``nemotron_h.py`` that edits
 no shared file: a configuration (``PRESETS``, ``YAML_FIELDS`` and a
 ``from_args`` over :func:`preset_from_args`), a block under the protocol
 at the top of ``causal_lm.py`` built from ``layers.py``, a
@@ -17,11 +18,17 @@ from typing import Any, Optional
 
 
 def config_from_args(args: Any, vocab_size: Optional[int] = None):
-    """``ZayaConfig`` for ``model: zaya``, else ``LlamaConfig``."""
-    if str(getattr(args, "model", "")).lower() == "zaya":
+    """``ZayaConfig`` for ``model: zaya``, ``NemotronHConfig`` for ``model:
+    nemotron_h``, else ``LlamaConfig``."""
+    model = str(getattr(args, "model", "")).lower()
+    if model == "zaya":
         from fedml_tpu.models.llm.zaya import ZayaConfig
 
         return ZayaConfig.from_args(args, vocab_size=vocab_size)
+    if model == "nemotron_h":
+        from fedml_tpu.models.llm.nemotron_h import NemotronHConfig
+
+        return NemotronHConfig.from_args(args, vocab_size=vocab_size)
     from fedml_tpu.models.llm.llama import LlamaConfig
 
     return LlamaConfig.from_args(args, vocab_size=vocab_size)

@@ -2,7 +2,8 @@
 a stack of the family's blocks (recomputed in the backward pass where the
 configuration says so), final norm, head. A family is a configuration, a
 block and the few lines that bind them to :class:`CausalLM` (``llama.py``,
-``zaya.py``).
+``zaya.py``); one whose layers are of several kinds says which block layer
+``i`` is (:meth:`CausalLM.layer_block`; ``nemotron_h.py``).
 
 The block protocol::
 
@@ -50,6 +51,12 @@ class CausalLM(nn.Module):
     block = None  # the family's block class (the protocol above)
 
     @nn.nowrap
+    def layer_block(self, i: int):
+        """The block class of layer ``i``: ``block``, unless the family's
+        layers are of several kinds and it says which one ``i`` is."""
+        return self.block
+
+    @nn.nowrap
     def init_carry(self, tokens):
         """What the first block is handed beside ``x``."""
         return None
@@ -81,17 +88,26 @@ class CausalLM(nn.Module):
             cos, sin = rope_tables(positions, cfg.rotary_dim, cfg.rope_theta)
         carry = self.init_carry(tokens)
 
-        block = self.block
-        if cfg.remat and cfg.remat_policy != "none" and kv_caches is None:
-            policy = None  # "full": save only block inputs
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            # argument 6 is ``attention_fn`` (0 is the module)
-            block = nn.remat(block, static_argnums=(6,), policy=policy)
+        blocks = {}  # a block class -> the class the layers are built from
+
+        def built_from(block):
+            if cfg.remat and cfg.remat_policy != "none" and kv_caches is None:
+                policy = None  # "full": save only block inputs
+                if cfg.remat_policy == "dots":
+                    policy = (jax.checkpoint_policies
+                              .dots_with_no_batch_dims_saveable)
+                # argument 6 is ``attention_fn`` (0 is the module)
+                return nn.remat(block, static_argnums=(6,), policy=policy)
+            return block
+
         new_caches, stats = [], []
         for i in range(cfg.num_hidden_layers):
+            block = self.layer_block(i)
+            if block not in blocks:
+                blocks[block] = built_from(block)
             cache_i = kv_caches[i] if kv_caches is not None else None
-            x, carry, new_cache, layer = block(cfg, name=f"layer_{i}")(
+            x, carry, new_cache, layer = blocks[block](
+                cfg, name=f"layer_{i}")(
                 x, carry, cos, sin, cache_i, attention_fn
             )
             new_caches.append(new_cache)

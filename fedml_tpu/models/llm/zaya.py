@@ -128,6 +128,16 @@ class ZayaConfig:
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.partial_rotary_factor)
 
+    def moe_capacity_rows(self, tokens: int) -> int:
+        """Rows of a layer's sorted buffer for a step of ``tokens``."""
+        return gmm.padded_rows(tokens, self.num_experts, self.moe_block_rows)
+
+    @property
+    def moe_static(self) -> dict:
+        """What the ``round/<n>/moe`` event says that no count carries."""
+        return {"experts": self.num_experts,
+                "top_k": self.num_experts_per_tok}
+
     def module(self) -> nn.Module:
         return ZayaForCausalLM(self)
 

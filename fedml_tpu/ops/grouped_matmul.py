@@ -1,15 +1,19 @@
 """Pallas TPU grouped matrix product — the dropless expert layer's hot op.
 
-A top-1 router over ``E`` frozen experts sends each of a step's tokens to
-one expert. Sorted by expert the tokens are ``E`` runs of rows, and each
-run is multiplied by its own expert's matrix: ``out[r] = x[r] @ w[e(r)]``.
-No capacity, no token dropped, no one-hot dispatch tensor.
+A router over frozen experts sends each of a step's tokens to ``k`` of
+them, of which this layer HOLDS a range (all of them, or the chip's share
+of an expert-parallel deployment). Sorted by expert the held assignments
+are runs of rows, and each run is multiplied by its own expert's matrix:
+``out[r] = x[r] @ w[e(r)]``. No capacity factor, no assignment dropped, no
+one-hot dispatch tensor; an assignment to an expert that is not held is
+skipped (another chip's work).
 
 Layout (:func:`group_layout`): every run is padded to a whole number of
 ``block_m``-row tiles, so a row tile belongs to exactly ONE expert and the
 kernel is a plain tiled product whose weight block is chosen per row tile
-by a scalar-prefetched table. The padded buffer has ``padded_rows(m, E,
-block_m)`` rows whatever the routing is (shapes stay static); the tiles
+by a scalar-prefetched table. The padded buffer has ``padded_rows(a, E,
+block_m)`` rows whatever the routing is (shapes stay static; ``a`` the most
+assignments that can be held, every one of every token's choices); the tiles
 past the last run are neither computed nor fetched (their block indices
 repeat the last live tile's, so the pipeline issues no copy) and their
 rows of the output stay unwritten — nothing reads them, since the way back
@@ -51,19 +55,23 @@ from fedml_tpu.ops.dispatch import INTERPRET, REFERENCE, kernel_mode
 # mostly padding and a smaller one pays more grid steps; 16 is the least a
 # bfloat16 tile can hold (chip readings of 16 to 256 rows in PERF.md)
 BLOCK_M = 64
+# the widest column tile; the tile itself is chosen from N (column_tile)
 BLOCK_N = 1024
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 class GroupLayout(NamedTuple):
-    """Where each token's row lives in the padded, expert-sorted buffer."""
+    """Where each held assignment's row lives in the padded, expert-sorted
+    buffer. An assignment is ``(token t, choice j)``, numbered ``t * k + j``;
+    with one choice a token (``expert`` given as ``[m]``) it is the token."""
 
-    src: jax.Array         # [P] token a padded row is read from (any, if pad)
-    valid: jax.Array       # [P] bool: the row holds a token
-    pos: jax.Array         # [m] row of the padded buffer holding token t
-    tile_group: jax.Array  # [P / block_m] expert of each row tile
-    live_tiles: jax.Array  # [1] row tiles that hold any token
-    counts: jax.Array      # [E] tokens of each expert
+    src: jax.Array         # [P] assignment a padded row is read from (any, if pad)
+    valid: jax.Array       # [P] bool: the row holds an assignment
+    pos: jax.Array         # [m] or [m, k] row holding the assignment (any, if not held)
+    held: jax.Array        # [m] or [m, k] bool: the assignment's expert is held here
+    tile_group: jax.Array  # [P / block_m] held expert (0-based) of each row tile
+    live_tiles: jax.Array  # [1] row tiles that hold any assignment
+    counts: jax.Array      # [E] assignments of each held expert
 
 
 def padded_rows(m: int, groups: int, block_m: int) -> int:
@@ -72,37 +80,59 @@ def padded_rows(m: int, groups: int, block_m: int) -> int:
     return -(-worst // block_m) * block_m
 
 
-def group_layout(expert: jax.Array, groups: int,
-                 block_m: int = BLOCK_M) -> GroupLayout:
-    """The layout for ``expert`` ``[m]`` (each token's expert index).
+def group_layout(expert: jax.Array, groups: int, block_m: int = BLOCK_M,
+                 first: int = 0) -> GroupLayout:
+    """The layout for ``expert``: each token's expert index ``[m]``, or its
+    ``k`` DISTINCT choices ``[m, k]``, of which the ``groups`` experts from
+    ``first`` on are held here and placed; the others are skipped.
 
     No sort and no scatter (both serialise on the chip: 0.45 ms a layer at
-    1,024 tokens, a fifth of a step; PERF.md): a token's rank within its
-    expert's run, and the token of each padded row, are counted by
-    comparisons over ``[m, m]`` and ``[P, m]`` index grids that fuse into
-    one reduction each."""
-    m = expert.shape[0]
-    rows = padded_rows(m, groups, block_m)
+    1,024 tokens, a fifth of a step; PERF.md). A token chooses an expert at
+    most once, so an assignment's rank within its expert's run is the
+    number of earlier tokens that chose the expert: a product of the
+    strictly lower triangle with the ``[m, E]`` table of who chose whom
+    (0 / 1 operands, float32 sums: exact; linear in held experts whatever
+    ``k``), and the assignment of each padded row is found by comparing
+    the row's number with the positions of its own expert's column, a
+    ``[P, m]`` grid that fuses into one reduction."""
+    flat = expert.ndim == 1
+    chosen = (expert[:, None] if flat else expert) - first          # [m, k]
+    m, k = chosen.shape
+    rows = padded_rows(m * min(k, groups), groups, block_m)
     tiles = rows // block_m
     token = jnp.arange(m, dtype=jnp.int32)
-    mine = expert[:, None] == jnp.arange(groups, dtype=jnp.int32)   # [m, E]
+    picks = chosen[:, :, None] == jnp.arange(groups, dtype=jnp.int32)  # [m, k, E]
+    mine = jnp.any(picks, axis=1)                                   # [m, E]
     counts = jnp.sum(mine, axis=0, dtype=jnp.int32)
     run_tiles = -(-counts // block_m)
     tile_end = jnp.cumsum(run_tiles)                    # [E] in tiles
     run_start = (tile_end - run_tiles) * block_m        # [E] in rows
-    # tokens before t that go to t's expert
-    rank = jnp.sum((expert[None, :] == expert[:, None])
-                   & (token[None, :] < token[:, None]), axis=1,
-                   dtype=jnp.int32)
-    pos = jnp.sum(jnp.where(mine, run_start, 0), axis=1) + rank
-    hit = pos[None, :] == jnp.arange(rows, dtype=jnp.int32)[:, None]  # [P, m]
-    src = jnp.sum(jnp.where(hit, token, 0), axis=1)
-    valid = jnp.any(hit, axis=1)
+    earlier = (token[:, None] > token[None, :]).astype(jnp.bfloat16)
+    rank = jnp.dot(earlier, mine.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    # row of (token, held expert); -1 where the token did not choose it
+    place = jnp.where(mine, run_start + rank, -1)
+    held = jnp.any(picks, axis=2)                                   # [m, k]
+    pos = jnp.sum(jnp.where(picks, place[:, None, :], 0), axis=2)   # [m, k]
     live = tile_end[-1]
-    tile = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32), live - 1)
-    tile_group = jnp.sum(tile_end[None, :] <= tile[:, None], axis=1,
-                         dtype=jnp.int32)
-    return GroupLayout(src, valid, pos, tile_group, live[None], counts)
+    tile = jnp.clip(jnp.arange(tiles, dtype=jnp.int32), 0,
+                    jnp.maximum(live, 1) - 1)
+    tile_group = jnp.minimum(
+        jnp.sum(tile_end[None, :] <= tile[:, None], axis=1, dtype=jnp.int32),
+        groups - 1)
+    row = jnp.arange(rows, dtype=jnp.int32).reshape(tiles, block_m, 1)
+    # the assignment (t * k + j) of each (token, held expert), and each row
+    # tile's own expert's column of both tables
+    slot = jnp.sum(jnp.where(
+        picks, jnp.arange(k, dtype=jnp.int32)[:, None], 0), axis=1)
+    number = token[:, None] * k + slot                              # [m, E]
+    hit = place.T[tile_group][:, None, :] == row               # [P/bm, bm, m]
+    src = jnp.sum(jnp.where(hit, number.T[tile_group][:, None, :], 0), axis=2)
+    valid = jnp.any(hit, axis=2)
+    if flat:
+        pos, held = pos[:, 0], held[:, 0]
+    return GroupLayout(src.reshape(rows), valid.reshape(rows), pos, held,
+                       tile_group, live[None], counts)
 
 
 # -- the way in and the way back: permutations, so their transposes are
@@ -110,7 +140,14 @@ def group_layout(expert: jax.Array, groups: int,
 @jax.custom_vjp
 def dispatch(x, layout: GroupLayout):
     """``x`` ``[m, K]`` into the padded buffer ``[P, K]``."""
-    return x[layout.src]
+    return x[_token(layout)]
+
+
+def _token(layout: GroupLayout):
+    """The token of each padded row (``src`` numbers assignments)."""
+    if layout.pos.ndim == 1:
+        return layout.src
+    return layout.src // layout.pos.shape[1]
 
 
 def _dispatch_fwd(x, layout):
@@ -118,7 +155,10 @@ def _dispatch_fwd(x, layout):
 
 
 def _dispatch_bwd(layout, g):
-    return g[layout.pos], None
+    # a row that was never written (not held: no row is its own) may hold
+    # anything, so it is selected away, not multiplied away
+    held = jnp.where(layout.held[..., None], g[layout.pos], 0)
+    return (held if held.ndim == 2 else jnp.sum(held, axis=1)), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -126,8 +166,9 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 @jax.custom_vjp
 def combine(y, layout: GroupLayout):
-    """Each token's own row of the padded buffer: ``[P, N] -> [m, N]``."""
-    return y[layout.pos]
+    """Each assignment's own row of the padded buffer: ``[P, N] -> [m, N]``
+    or ``[m, k, N]``, zeros for an assignment that is not held here."""
+    return jnp.where(layout.held[..., None], y[layout.pos], 0)
 
 
 def _combine_fwd(y, layout):
@@ -136,6 +177,7 @@ def _combine_fwd(y, layout):
 
 def _combine_bwd(layout, g):
     # padding rows get an exact zero: the backward product reads them
+    g = g.reshape(-1, g.shape[-1])
     return jnp.where(layout.valid[:, None], g[layout.src], 0), None
 
 
@@ -154,19 +196,35 @@ def _kernel(tile_group, live_tiles, x_ref, w_ref, o_ref, *, transpose_rhs):
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
+def column_tile(n: int, at_most: int = BLOCK_N) -> int:
+    """The column tile for ``n`` columns: ``n`` itself if it fits, else the
+    widest whole number of 128 lanes that divides ``n`` and is ``at_most``
+    (2688 = 21 x 128 gets 896, 2048 gets 1024)."""
+    if n <= at_most:
+        return n
+    fits = [c for c in range(128, at_most + 1, 128) if n % c == 0]
+    if n % at_most == 0:
+        fits.append(at_most)
+    if not fits:
+        raise ValueError(
+            f"moe_gmm: {n} columns have no tile of whole 128-lane groups "
+            f"up to {at_most}")
+    return max(fits)
+
+
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _gmm(x, w, tile_group, live_tiles, transpose_rhs, block_m, block_n,
          interpret):
     rows, k = x.shape
     n = w.shape[1] if transpose_rhs else w.shape[2]
-    block_n = min(block_n, n)
-    if rows % block_m or n % block_n:
-        raise ValueError(
-            f"moe_gmm: rows {rows} and columns {n} must be whole multiples "
-            f"of the blocks ({block_m}, {block_n})")
+    block_n = column_tile(n, block_n)
+    if rows % block_m:
+        raise ValueError(f"moe_gmm: rows {rows} must be a whole multiple "
+                         f"of the block ({block_m})")
 
     def row_tile(j, i, tile_group, live_tiles):
-        return jnp.minimum(i, live_tiles[0] - 1)
+        # no live tile at all (nothing routed to an expert held here): 0
+        return jnp.maximum(jnp.minimum(i, live_tiles[0] - 1), 0)
 
     x_spec = pl.BlockSpec(
         (block_m, k), lambda j, i, tg, lt: (row_tile(j, i, tg, lt), 0))

@@ -145,33 +145,52 @@ class FedLLMAPI:
                 stats = {k: np.asarray(v) for s in stats for k, v in s.items()}
             dt = time.time() - t0
             if "moe_tokens" in stats:
-                self._moe_event(round_idx, stats, xs.size, ms.size // batch)
+                self._moe_event(round_idx, stats, xs.size, ms.size // batch,
+                                self.cfg)
             report = {"round": round_idx, "round_sec": dt, "train_loss": loss}
             self._maybe_test_and_checkpoint(round_idx, report)
         return report
 
     @staticmethod
     def _moe_event(round_idx: int, stats: Dict[str, np.ndarray],
-                   tokens: int, steps: int) -> Dict:
-        """``round/<n>/moe``: how the round's tokens spread over the
-        experts. ``moe_tokens`` ``[layers, experts]`` and ``moe_live``
-        ``[layers]`` are summed over the round's ``steps``.
-        ``max_over_mean`` is the busiest expert's load over the mean load
-        in the worst layer; ``dropped`` the tokens that reached no expert in
-        some layer (a dropless layer reads 0); ``live_share`` the share of
-        (step, layer, expert) triples in which the expert got a token — a
-        step's grouped products read only those experts' matrices."""
+                   tokens: int, steps: int, cfg: Any) -> Dict:
+        """``round/<n>/moe``: how the round's assignments spread over the
+        experts held here. ``moe_tokens`` ``[expert layers, held experts]``
+        and ``moe_live`` ``[expert layers]`` are summed over the round's
+        ``steps``; what no count carries is the configuration's to say
+        (``cfg.moe_static``: ``experts`` the router scores and ``top_k``;
+        ``cfg.moe_capacity_rows(tokens of a step)``: the sorted buffer's
+        static rows). A family that holds every expert need not count
+        ``moe_held`` / ``moe_placed``: every assignment is held, and the
+        counts say where it was placed.
+        ``max_over_mean`` is the busiest held expert's load over the held
+        experts' mean load in the worst layer; ``held_share`` the share of
+        all assignments whose expert is held here; ``dropped`` the held
+        assignments that reached no row in some layer (a dropless layer
+        reads 0); ``live_share`` the share of (step, layer, held expert)
+        triples in which the expert got a token — a step's grouped products
+        read only those experts' matrices."""
+        static = cfg.moe_static
         counts = stats["moe_tokens"]
-        layers, experts = counts.shape
+        layers, held = counts.shape
+        top_k = int(static["top_k"])
+        assignments = int(tokens) * top_k
         per_layer = counts.sum(axis=1)
+        here = stats.get("moe_held", np.full(layers, assignments))
+        placed = stats.get("moe_placed", per_layer)
+        step_tokens = int(tokens) // max(int(steps), 1)
         return get_tracer().event(
-            f"round/{round_idx}/moe", layers=int(layers),
-            experts=int(experts), tokens=int(tokens), steps=int(steps),
+            f"round/{round_idx}/moe",
+            layers=int(layers), experts=int(static["experts"]),
+            held=int(held), top_k=top_k, tokens=int(tokens),
+            steps=int(steps), assignments=assignments,
+            held_share=float(here.sum() / (assignments * layers)),
+            capacity_rows=int(cfg.moe_capacity_rows(step_tokens)),
             max_over_mean=float(
-                (counts.max(axis=1) * experts / per_layer).max()),
+                (counts.max(axis=1) * held / np.maximum(per_layer, 1)).max()),
             live_share=float(
-                stats["moe_live"].sum() / (steps * layers * experts)),
-            dropped=int((tokens - per_layer).max()))
+                stats["moe_live"].sum() / (steps * layers * held)),
+            dropped=int((here - placed).max()))
 
     def train_one_round(self, round_idx: int) -> Dict:
         if self.on_device:

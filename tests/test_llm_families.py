@@ -323,3 +323,41 @@ def test_from_args_overrides_a_field_and_no_other(model, key, said, want):
     other = "moe_block_rows" if model == "llama" else "hidden_size"
     assert config_from_args(_args(model, **{other: 16})) == \
         CLASSES[model](**TINY[model])
+
+
+# -- (iv) the seam by which a family says what layer i is -------------------
+@pytest.mark.parametrize("remat_policy", ["none", "full", "dots"])
+@pytest.mark.parametrize("family", ["llama", "zaya"])
+def test_a_family_of_one_kind_lowers_as_if_it_said_so_a_layer(family,
+                                                              remat_policy):
+    """``CausalLM.layer_block`` answers ``block`` for every layer unless a
+    family says otherwise; a family that says ``block`` for each ``i``
+    itself lowers to the same program text, with remat and without (the
+    shell builds each kind's remat class once, whatever the depth)."""
+    import re
+
+    from fedml_tpu.models.llm.llama import LlamaForCausalLM
+    from fedml_tpu.models.llm.zaya import ZayaForCausalLM
+
+    cfg = (LlamaConfig if family == "llama" else ZayaConfig).tiny(
+        lora_rank=4, use_flash=False, remat=True, remat_policy=remat_policy)
+    base = LlamaForCausalLM if family == "llama" else ZayaForCausalLM
+    asked = []
+
+    class Saying(base):
+        @nn.nowrap
+        def layer_block(self, i):
+            asked.append(i)
+            return type(self).block
+
+    Saying.__name__ = base.__name__   # the class's name is in every op_name
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    texts = []
+    for module in (base(cfg), Saying(cfg)):
+        assert module.layer_block(0) is base.block
+        params = jax.eval_shape(module.init, jax.random.key(0), tokens)
+        lowered = jax.jit(module.apply).lower(params, tokens).as_text()
+        texts.append(re.sub(r"loc\([^)]*\)|#loc.*", "", lowered))
+    assert asked.count(1) >= 1 and set(asked) == set(
+        range(cfg.num_hidden_layers))
+    assert texts[0] == texts[1]

@@ -126,6 +126,67 @@ def test_grouped_matmul_compiles_for_v5e(one_chip):
     assert " sort(" not in text and " scatter(" not in text
 
 
+def test_held_top_k_grouped_matmul_compiles_for_v5e(one_chip):
+    """The expert layer of ``nemotron-3-super-120b-a12b.round-2k``: 2,048
+    tokens, 22 choices a token of 512 experts, 128 held, in a 1024-wide
+    latent with 2688-wide experts — the layout with a held range, both
+    grouped products (column tiles of 896 and 1024) and their row
+    gradients, still without a sort or a scatter."""
+    from fedml_tpu.ops import grouped_matmul as gmm
+
+    m, k, lat, mid, held, bm = 2048, 22, 1024, 2688, 128, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, up, down, chosen, gate):
+        layout = gmm.group_layout(chosen, held, bm, first=0)
+        product = lambda a, w: gmm.grouped_matmul(
+            a, w, layout, block_m=bm, interpret=False)
+        hidden = product(gmm.dispatch(x, layout), up)
+        out = product(jnp.square(jax.nn.relu(hidden)), down)
+        mine = gmm.combine(out, layout).astype(jnp.float32)
+        return jnp.sum(mine * gate[..., None])
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        sds((m, lat), jnp.bfloat16), sds((held, lat, mid), jnp.bfloat16),
+        sds((held, mid, lat), jnp.bfloat16), sds((m, k), jnp.int32),
+        sds((m, k), jnp.float32)).compile()
+    assert _kernels(compiled) == 4
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(c.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                  for c in calls) == ["moe_gmm"] * 2 + ["moe_gmm_t"] * 2
+    rows = gmm.padded_rows(m * k, held, bm)
+    assert rows == 61312 and f"bf16[{rows},{mid}]" in text
+    assert " sort(" not in text and " scatter(" not in text
+    # no [assignments, assignments] grid: the largest integer or boolean
+    # array is the [row tiles, rows a tile, tokens] one, as pred
+    assert f"[{m * k},{m * k}]" not in text
+
+
+def test_chunked_scan_compiles_for_v5e(one_chip):
+    """``ops/ssd.py`` at the Mamba-2 widths of the same cell (128 heads x
+    64, 8 groups, state 128, chunks of 128 at T2048), forward and backward
+    with its inside recomputed: it fits beside nothing else in well under
+    a gigabyte and keeps no ``[H, Q, Q]`` array between the passes."""
+    from fedml_tpu.ops.ssd import ssd
+
+    t, h, p, g, n = 2048, 128, 64, 8, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        return ssd(x, dt, a, b, c, chunk=128).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))).lower(
+        sds((1, t, h, p), jnp.bfloat16), sds((1, t, h), jnp.float32),
+        sds((h,), jnp.float32), sds((1, t, g, n), jnp.bfloat16),
+        sds((1, t, g, n), jnp.bfloat16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2):
     """``llm/fused_round`` at the 7B widths, lowered from shapes alone.
 

@@ -271,6 +271,14 @@ def test_the_fused_round_of_a_tiny_zaya_is_the_host_loops():
     assert moe["attrs"]["experts"] == cfg.num_experts
     assert moe["attrs"]["tokens"] == tokens
     assert moe["attrs"]["steps"] == 2 * 2
+    # the one protocol of the event (every family's configuration states
+    # ``moe_static`` and ``moe_capacity_rows``): here every expert is held
+    assert moe["attrs"]["held"] == cfg.num_experts
+    assert moe["attrs"]["top_k"] == 1
+    assert moe["attrs"]["assignments"] == tokens
+    assert moe["attrs"]["held_share"] == 1.0
+    assert moe["attrs"]["capacity_rows"] == cfg.moe_capacity_rows(
+        engine.batch_size * engine.seq_len)
     assert 1.0 <= moe["attrs"]["max_over_mean"] <= cfg.num_experts
     assert 1 / cfg.num_experts <= moe["attrs"]["live_share"] <= 1.0
     names = [r["name"] for r in records]
