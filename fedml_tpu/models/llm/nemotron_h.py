@@ -36,8 +36,9 @@ trainer's attention product (the flash kernels), then ``W_o``.
    ``w = s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling_factor``;
 8. ``l = u W_fc1`` (hidden -> latent); ``r = sum_{e chosen AND held} w_e
    relu(l U_e)^2 V_e``: the held assignments sorted by expert, one grouped
-   product a matrix (``ops/grouped_matmul.py``), none dropped whatever the
-   routing; what the experts held elsewhere would add is left out — on one
+   product a matrix (``ops/grouped_matmul.py``; relu^2 is handed to the
+   second product, which applies it on its live tiles), none dropped
+   whatever the routing; what the experts held elsewhere would add is left out — on one
    chip there is no exchange and nothing stands in for one;
 9. ``r W_fc2 + relu(u S_up)^2 S_down`` (latent -> hidden; the shared
    expert sees every token).
@@ -254,6 +255,12 @@ def _relu2(x):
     return jnp.square(nn.relu(x))
 
 
+# relu^2 as the grouped product takes it (ops/grouped_matmul.py): applied,
+# and differentiated, inside the kernels on the tiles that hold rows
+RELU2 = gmm.Activation(
+    "relu2", value=_relu2, derivative=lambda x: 2.0 * nn.relu(x))
+
+
 class NemotronHMamba(nn.Module):
     """Steps 1-6."""
 
@@ -345,11 +352,12 @@ class NemotronHExperts(nn.Module):
                     ("expert", in_axis, out_axis)),
                 (e, *shape), cfg.param_dtype).astype(cfg.dtype)
 
-        product = lambda a, w: gmm.grouped_matmul(
-            a, w, layout, block_m=cfg.moe_block_rows)
+        product = lambda a, w, **kw: gmm.grouped_matmul(
+            a, w, layout, block_m=cfg.moe_block_rows, **kw)
         up = product(xs, experts("up_proj", (lat, mid), "embed", "mlp"))
-        return product(_relu2(up),
-                       experts("down_proj", (mid, lat), "mlp", "embed"))
+        # relu^2 is the second product's: no pass over the padded buffer
+        return product(up, experts("down_proj", (mid, lat), "mlp", "embed"),
+                       activation=RELU2)
 
 
 class NemotronHShared(nn.Module):

@@ -30,7 +30,26 @@ accumulator and no revisit.
 The experts are frozen: the custom VJP gives the gradient with respect to
 the ROWS only (``dx = dy @ w[e]^T``, the same kernel contracting over the
 matrix's last axis, so no transposed copy of the weights is ever made) and
-none for the weights. Which form a call gets is decided in
+none for the weights.
+
+The elementwise function that stands BEFORE a product is the product's to
+apply (``activation=``, an :class:`Activation` the calling family states:
+its value and its derivative): ``out[r] = f(x[r]) @ w[e(r)]``. Forward,
+``f`` is the kernel's prologue on the x tile it has just fetched (from the
+tile's own type through float32, rounded once: what ``f(x)`` written
+outside would have fed the product); backward, ``f'`` of the saved ``x``
+is the epilogue of ``moe_gmm_t`` on the float32 product before its one
+cast (``dx = (dy @ w[e]^T) * f'(x)``, ``x`` a third operand tiled like the
+output). Neither ``f(x)`` nor its cotangent is ever an array: a fusion
+outside the kernels walks the whole padded buffer because its shape is
+static, the kernels walk the live tiles. The dead tiles' rows of ``dx``
+stay unwritten like those of ``out``: :func:`combine`'s transpose hands
+exact zeros to every padding row and :func:`dispatch`'s selects by
+``held``, so nothing reads them. Without an activation the call is the
+plain product, kernel body and operands unchanged. Every product traced
+leaves a ``moe_gmm/plan`` point event (:func:`_plan`).
+
+Which form a call gets is decided in
 ``ops/dispatch.py``: compiled on a TPU or an exception; off-TPU the plain
 XLA reference (:func:`reference_grouped_matmul`), or the interpreter with
 ``interpret=True``. (``jax.lax.ragged_dot`` was tried first: the chip's
@@ -40,15 +59,22 @@ copy of every expert's matrix; 0.51 + 0.90 ms a product pair against
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fedml_tpu.ops.dispatch import INTERPRET, REFERENCE, kernel_mode
+from fedml_tpu.ops.dispatch import (
+    COMPILED,
+    INTERPRET,
+    REFERENCE,
+    kernel_mode,
+)
+from fedml_tpu.telemetry import get_tracer
 
 # rows of a tile: an expert at B1 T1024 sees 64 rows on average, and every
 # run is padded to whole tiles, so a tile much larger than a run multiplies
@@ -72,6 +98,18 @@ class GroupLayout(NamedTuple):
     tile_group: jax.Array  # [P / block_m] held expert (0-based) of each row tile
     live_tiles: jax.Array  # [1] row tiles that hold any assignment
     counts: jax.Array      # [E] assignments of each held expert
+
+
+@dataclasses.dataclass(frozen=True)
+class Activation:
+    """An elementwise function a family puts before a grouped product:
+    ``value`` and ``derivative`` are ``jax.numpy`` on float32 arrays of any
+    shape (they run on a tile inside the kernels), ``name`` is what the
+    ``moe_gmm/plan`` event says."""
+
+    name: str
+    value: Callable[[jax.Array], jax.Array]
+    derivative: Callable[[jax.Array], jax.Array]
 
 
 def padded_rows(m: int, groups: int, block_m: int) -> int:
@@ -185,15 +223,27 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # -- the kernel ------------------------------------------------------------
-def _kernel(tile_group, live_tiles, x_ref, w_ref, o_ref, *, transpose_rhs):
+def _kernel(tile_group, live_tiles, x_ref, w_ref, *refs, transpose_rhs,
+            activation):
+    """One live tile's product. With an ``activation``: its value on the x
+    tile before the product, or (``transpose_rhs``, the row gradient) its
+    derivative of the saved tile ``refs[0]`` on the product after it."""
     del tile_group
+    *saved, o_ref = refs
 
     @pl.when(pl.program_id(1) < live_tiles[0])
     def _():
+        x = x_ref[...]
+        if activation is not None and not transpose_rhs:
+            x = activation.value(x.astype(jnp.float32)).astype(x.dtype)
         contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
-        o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[...], (contract, ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        out = jax.lax.dot_general(
+            x, w_ref[...], (contract, ((), ())),
+            preferred_element_type=jnp.float32)
+        if saved:
+            out = out * activation.derivative(
+                saved[0][...].astype(jnp.float32))
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def column_tile(n: int, at_most: int = BLOCK_N) -> int:
@@ -212,9 +262,25 @@ def column_tile(n: int, at_most: int = BLOCK_N) -> int:
     return max(fits)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _gmm(x, w, tile_group, live_tiles, transpose_rhs, block_m, block_n,
-         interpret):
+def _plan(x, n, transpose_rhs, block_m, block_n, activation, form):
+    """One ``moe_gmm/plan`` point event: a grouped product was traced (the
+    forward by :func:`grouped_matmul`, the row gradient by the custom
+    VJP's rule, which the plain-XLA reference form does not have)."""
+    rows, k = x.shape
+    block_n = column_tile(n, block_n)
+    get_tracer().event(
+        "moe_gmm/plan", rows=rows, k=k, n=n, block_m=block_m,
+        block_n=block_n, row_tiles=rows // block_m, column_tiles=n // block_n,
+        transpose=transpose_rhs,
+        activation=activation.name if activation else None,
+        dtype=jnp.dtype(x.dtype).name, form=form)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _gmm(x, w, tile_group, live_tiles, saved, transpose_rhs, block_m, block_n,
+         interpret, activation):
+    """``saved``: the activation's input where this is the row gradient of
+    a product that took one (its derivative is the epilogue), else None."""
     rows, k = x.shape
     n = w.shape[1] if transpose_rhs else w.shape[2]
     block_n = column_tile(n, block_n)
@@ -236,35 +302,47 @@ def _gmm(x, w, tile_group, live_tiles, transpose_rhs, block_m, block_n,
                               lambda j, i, tg, lt: (tg[i], 0, j))
     o_spec = pl.BlockSpec(
         (block_m, block_n), lambda j, i, tg, lt: (row_tile(j, i, tg, lt), j))
+    operands, in_specs = [x, w], [x_spec, w_spec]
+    if saved is not None:   # tiled like the output it scales
+        operands, in_specs = operands + [saved], in_specs + [o_spec]
     return pl.pallas_call(
-        functools.partial(_kernel, transpose_rhs=transpose_rhs),
+        functools.partial(_kernel, transpose_rhs=transpose_rhs,
+                          activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(n // block_n, rows // block_m),
-            in_specs=[x_spec, w_spec], out_specs=o_spec),
+            in_specs=in_specs, out_specs=o_spec),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="moe_gmm_t" if transpose_rhs else "moe_gmm",
-    )(tile_group, live_tiles, x, w)
+    )(tile_group, live_tiles, *operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _grouped(x, w, tile_group, live_tiles, block_m, block_n, interpret):
-    return _gmm(x, w, tile_group, live_tiles, False, block_m, block_n,
-                interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _grouped(x, w, tile_group, live_tiles, block_m, block_n, interpret,
+             activation):
+    return _gmm(x, w, tile_group, live_tiles, None, False, block_m, block_n,
+                interpret, activation)
 
 
-def _grouped_fwd(x, w, tile_group, live_tiles, block_m, block_n, interpret):
-    out = _grouped(x, w, tile_group, live_tiles, block_m, block_n, interpret)
-    return out, (w, tile_group, live_tiles)
+def _grouped_fwd(x, w, tile_group, live_tiles, block_m, block_n, interpret,
+                 activation):
+    out = _grouped(x, w, tile_group, live_tiles, block_m, block_n, interpret,
+                   activation)
+    # an activation's derivative wants its input; the plain product keeps
+    # no rows at all
+    saved = None if activation is None else x
+    return out, (saved, w, tile_group, live_tiles)
 
 
-def _grouped_bwd(block_m, block_n, interpret, res, dy):
-    w, tile_group, live_tiles = res
-    dx = _gmm(dy, w, tile_group, live_tiles, True, block_m, block_n,
-              interpret)
+def _grouped_bwd(block_m, block_n, interpret, activation, res, dy):
+    saved, w, tile_group, live_tiles = res
+    _plan(dy, w.shape[1], True, block_m, block_n, activation,
+          INTERPRET if interpret else COMPILED)
+    dx = _gmm(dy, w, tile_group, live_tiles, saved, True, block_m, block_n,
+              interpret, activation)
     # the experts are frozen: no product for their gradient is ever built
     return dx, None, None, None
 
@@ -272,8 +350,32 @@ def _grouped_bwd(block_m, block_n, interpret, res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def reference_grouped_matmul(x, w, layout: GroupLayout, block_m: int):
-    """Plain XLA: each row tile times its own expert's matrix, gathered."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _activate(activation: Activation, x):
+    """``f(x)`` as the kernels compute it, and THEIR derivative (the
+    family's own, not JAX's of ``value``) for the reference form."""
+    return activation.value(x.astype(jnp.float32)).astype(x.dtype)
+
+
+def _activate_fwd(activation, x):
+    return _activate(activation, x), x
+
+
+def _activate_bwd(activation, x, g):
+    scaled = g.astype(jnp.float32) * activation.derivative(
+        x.astype(jnp.float32))
+    return (scaled.astype(x.dtype),)
+
+
+_activate.defvjp(_activate_fwd, _activate_bwd)
+
+
+def reference_grouped_matmul(x, w, layout: GroupLayout, block_m: int,
+                             activation: Optional[Activation] = None):
+    """Plain XLA: each row tile times its own expert's matrix, gathered
+    (every tile, the dead ones too)."""
+    if activation is not None:
+        x = _activate(activation, x)
     tiles = x.shape[0] // block_m
     xt = x.reshape(tiles, block_m, x.shape[1])
     out = jnp.einsum("tmk,tkn->tmn", xt, w[layout.tile_group],
@@ -283,16 +385,22 @@ def reference_grouped_matmul(x, w, layout: GroupLayout, block_m: int):
 
 def grouped_matmul(x: jax.Array, w: jax.Array, layout: GroupLayout,
                    block_m: int = BLOCK_M, block_n: int = BLOCK_N,
-                   interpret: Optional[bool] = None) -> jax.Array:
-    """``out[r] = x[r] @ w[expert of r's tile]``; x ``[P, K]`` in the
-    layout's padded order, w ``[E, K, N]`` (frozen: it gets no gradient).
+                   interpret: Optional[bool] = None,
+                   activation: Optional[Activation] = None) -> jax.Array:
+    """``out[r] = f(x[r]) @ w[expert of r's tile]``; x ``[P, K]`` in the
+    layout's padded order, w ``[E, K, N]`` (frozen: it gets no gradient),
+    ``f`` the ``activation`` (none: the plain product), applied and
+    differentiated inside the kernels on live tiles only.
 
+    The rows of dead tiles are unwritten in ``out`` and in the row
+    gradient; see the module's docstring for why nothing reads them.
     ``block_m`` must be the layout's. ``interpret`` as in
     :func:`fedml_tpu.ops.flash_attention.flash_attention`.
     """
     mode = kernel_mode(interpret, off_tpu=REFERENCE)
+    _plan(x, w.shape[2], False, block_m, block_n, activation, mode)
     if mode == REFERENCE:
         return reference_grouped_matmul(
-            x, jax.lax.stop_gradient(w), layout, block_m)
+            x, jax.lax.stop_gradient(w), layout, block_m, activation)
     return _grouped(x, w, layout.tile_group, layout.live_tiles, block_m,
-                    block_n, mode == INTERPRET)
+                    block_n, mode == INTERPRET, activation)

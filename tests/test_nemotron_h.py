@@ -12,7 +12,11 @@ import pytest
 
 from fedml_tpu import telemetry
 from fedml_tpu.models.llm import config_from_args, nemotron_h_reference as ref
-from fedml_tpu.models.llm.nemotron_h import NemotronHConfig, NemotronHMoE
+from fedml_tpu.models.llm.nemotron_h import (
+    RELU2,
+    NemotronHConfig,
+    NemotronHMoE,
+)
 from fedml_tpu.ops import grouped_matmul as gmm
 from fedml_tpu.ops.ssd import ssd
 from fedml_tpu.train.llm.sharding import unbox
@@ -356,12 +360,17 @@ def test_the_layout_with_top_k_and_a_held_range_is_a_sorts(case):
                          ids=["reference", "interpreter"])
 @pytest.mark.parametrize("force", [None, (2, 6), (6, 9)],
                          ids=["some_held", "all_held", "none_held"])
-def test_grouped_product_over_a_tokens_choices_and_its_gradient(force,
-                                                                 interpret):
+@pytest.mark.parametrize("activation", [None, RELU2],
+                         ids=["plain", "relu2"])
+def test_grouped_product_over_a_tokens_choices_and_its_gradient(
+        activation, force, interpret):
     """dispatch -> ``moe_gmm`` -> combine with three choices a token and
     experts 2..5 of 9 held, against a gather of each held assignment's own
     matrix: values (zero for an assignment that is not held), and the
-    gradient with respect to the tokens' rows."""
+    gradient with respect to the tokens' rows. With an activation the
+    product applies it to its rows and its row gradient carries the
+    derivative: both equal ``f`` written outside and differentiated by
+    JAX."""
     rng = np.random.default_rng(2)
     m, k, total, first, held, kk, n, bm = 11, 3, 9, 2, 4, 32, 48, 8
     chosen = _choices(m, k, total, rng, force)
@@ -369,24 +378,125 @@ def test_grouped_product_over_a_tokens_choices_and_its_gradient(force,
     w = jnp.asarray(rng.normal(size=(held, kk, n)), jnp.float32)
     gate = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
     here = (chosen >= first) & (chosen < first + held)
+    f = activation.value if activation else (lambda x: x)
 
     def routed(x):
         layout = gmm.group_layout(chosen, held, bm, first)
         out = gmm.grouped_matmul(gmm.dispatch(x, layout), w, layout, bm, 16,
-                                 interpret=interpret)
+                                 interpret=interpret, activation=activation)
         return jnp.sum(gmm.combine(out, layout) * gate[..., None], axis=1)
 
     def plain(x):
         own = w[jnp.clip(chosen - first, 0, held - 1)]          # [m, k, K, N]
-        out = jnp.einsum("mk,mjkn->mjn", x, own)
+        out = jnp.einsum("mk,mjkn->mjn", f(x), own)
         return jnp.sum(jnp.where(here[..., None], out, 0) * gate[..., None],
                        axis=1)
 
-    np.testing.assert_allclose(routed(x), plain(x), atol=1e-4)
+    np.testing.assert_allclose(routed(x), plain(x), atol=1e-4, rtol=1e-5)
     got = jax.grad(lambda x: jnp.sum(jnp.sin(routed(x))))(x)
     want = jax.grad(lambda x: jnp.sum(jnp.sin(plain(x))))(x)
-    np.testing.assert_allclose(got, want, atol=1e-4)
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
     assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["reference", "interpreter"])
+@pytest.mark.parametrize("top_1", [True, False], ids=["top_1", "top_k"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_product_applies_what_stands_before_it_on_every_held_row(
+        dtype, top_1, interpret):
+    """``grouped_matmul(x, w, layout, activation=f)`` (``f`` = relu^2) against
+    ``grouped_matmul(f(x), w, layout)`` differentiated by JAX, row by row
+    of the padded buffer: value and row gradient agree on every row that
+    holds an assignment (top-1 with all held: ``zaya``'s layout; top-k
+    with a held range: ``nemotron_h``'s). In bfloat16 the value is the
+    same to the BIT (``f`` through float32, rounded once, is what ``f``
+    outside feeds the product) and the gradient within one rounding (the
+    kernel scales its float32 product and rounds once; outside, the
+    product is rounded first)."""
+    rng = np.random.default_rng(5)
+    m, kk, n, bm = 19, 32, 48, 8
+    if top_1:
+        held, first = 5, 0
+        chosen = jnp.asarray(rng.integers(0, held, size=m), jnp.int32)
+    else:
+        held, first = 4, 2
+        chosen = _choices(m, 3, 9, rng)
+    layout = gmm.group_layout(chosen, held, bm, first)
+    rows = layout.valid.shape[0]
+    x = jnp.asarray(rng.normal(size=(rows, kk)), dtype)
+    w = jnp.asarray(rng.normal(size=(held, kk, n)), dtype)
+    dy = jnp.asarray(rng.normal(size=(rows, n)), dtype)
+    product = lambda x, **kw: gmm.grouped_matmul(
+        x, w, layout, bm, 16, interpret=interpret, **kw)
+
+    got, pull = jax.vjp(lambda x: product(x, activation=RELU2), x)
+    want, pull_plain = jax.vjp(lambda x: product(RELU2.value(x)), x)
+    valid = np.asarray(layout.valid)
+    assert valid.any() and not valid.all()
+    held_rows = lambda y: np.asarray(y.astype(jnp.float32))[valid]
+    exact = dict(atol=0, rtol=0)
+    near = dict(atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(
+        held_rows(got), held_rows(want),
+        **(exact if dtype == jnp.bfloat16 else near))
+    np.testing.assert_allclose(
+        held_rows(pull(dy)[0]), held_rows(pull_plain(dy)[0]),
+        **(dict(atol=2.0 ** -6, rtol=2.0 ** -6) if dtype == jnp.bfloat16
+           else near))
+
+
+@jax.custom_vjp
+def _poison_dead_rows(y, dead):
+    """NaN in every row of a dead tile, on the way there and on the way
+    back: whatever a kernel leaves unwritten there."""
+    return jnp.where(dead[:, None], jnp.nan, y)
+
+
+_poison_dead_rows.defvjp(
+    lambda y, dead: (_poison_dead_rows(y, dead), dead),
+    lambda dead, g: (jnp.where(dead[:, None], jnp.nan, g), None))
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["reference", "interpreter"])
+@pytest.mark.parametrize("force", [None, (2, 6), (6, 9)],
+                         ids=["some_held", "all_held", "none_held"])
+@pytest.mark.parametrize("activation", [None, RELU2],
+                         ids=["plain", "relu2"])
+def test_nothing_reads_the_rows_of_dead_tiles(activation, force, interpret):
+    """dispatch -> product -> product (with the activation) -> combine
+    with NaN planted in the dead tiles' rows of every buffer, forward and
+    backward: the loss and the tokens' gradient stay finite and equal the
+    unpoisoned ones, because ``combine`` gathers each held assignment's
+    own row and ``dispatch``'s transpose selects by ``held``."""
+    rng = np.random.default_rng(7)
+    m, k, total, first, held, lat, mid, bm = 11, 3, 9, 2, 4, 16, 32, 8
+    chosen = _choices(m, k, total, rng, force)
+    x = jnp.asarray(rng.normal(size=(m, lat)), jnp.float32)
+    up = jnp.asarray(rng.normal(size=(held, lat, mid)), jnp.float32)
+    down = jnp.asarray(rng.normal(size=(held, mid, lat)), jnp.float32)
+    gate = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+
+    def loss(x, poison):
+        layout = gmm.group_layout(chosen, held, bm, first)
+        rows = layout.valid.shape[0]
+        dead = jnp.arange(rows) >= layout.live_tiles[0] * bm
+        assert rows > m * k   # the plan's rows exceed any routing's
+        spoil = (lambda y: _poison_dead_rows(y, dead)) if poison \
+            else (lambda y: y)
+        product = lambda a, w, **kw: gmm.grouped_matmul(
+            a, w, layout, bm, 16, interpret=interpret, **kw)
+        hidden = spoil(product(spoil(gmm.dispatch(x, layout)), up))
+        out = spoil(product(hidden, down, activation=activation))
+        return jnp.sum(gmm.combine(out, layout) * gate[..., None])
+
+    value, grad = jax.value_and_grad(loss)(x, True)
+    clean, clean_grad = jax.value_and_grad(loss)(x, False)
+    assert np.isfinite(float(value)) and np.isfinite(np.asarray(grad)).all()
+    np.testing.assert_allclose(value, clean, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(grad, clean_grad, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("n,tile", [(2688, 896), (2048, 1024), (1024, 1024),
@@ -425,7 +535,7 @@ def test_a_cache_is_refused(f32):
         cfg.module().apply(params, tokens, kv_caches=[()] * 11)
 
 
-def _api(on_device: bool):
+def _api(on_device: bool, model: str = "nemotron_h"):
     import fedml_tpu
     from fedml_tpu.arguments import load_arguments_from_dict
     from fedml_tpu.data import load_federated
@@ -436,7 +546,7 @@ def _api(on_device: bool):
         "common_args": {"training_type": "simulation", "random_seed": 0},
         "data_args": {"dataset": "synthetic_lm", "max_seq_length": 16,
                       "vocab_size": 64, "train_size": 64, "test_size": 16},
-        "model_args": {"model": "nemotron_h", "model_size": "tiny",
+        "model_args": {"model": model, "model_size": "tiny",
                        "lora_rank": 4, "use_flash_attention": False},
         "train_args": {"federated_optimizer": "FedAvg",
                        "client_num_in_total": 4, "client_num_per_round": 2,
@@ -446,6 +556,52 @@ def _api(on_device: bool):
                        "on_device_round": on_device},
     }))
     return FedLLMAPI(args, None, load_federated(args), mesh=None)
+
+
+@pytest.mark.parametrize("family", ["nemotron_h", "zaya"])
+def test_every_grouped_product_of_a_round_leaves_its_plan(family,
+                                                          monkeypatch):
+    """Tracing the tiny fused round with the grouped products as KERNELS
+    (the interpreter's form, steered here: off a TPU a family's own call
+    gets the reference, whose row gradient is XLA's and leaves nothing)
+    leaves one ``moe_gmm/plan`` a product and direction. ``nemotron_h``
+    hands relu^2 to its second product, so two of an expert layer's four
+    say so — that product and its row gradient; ``zaya``'s SwiGLU takes
+    two products' outputs and stays outside: none of its six a layer."""
+    monkeypatch.setattr(gmm, "kernel_mode", lambda *a, **kw: gmm.INTERPRET)
+    api = _api(on_device=True, model=family)
+    engine, cfg = api.client.engine, api.cfg
+    feed = jax.ShapeDtypeStruct((2, 2, engine.batch_size, engine.seq_len),
+                                np.int32)
+    telemetry.reset_tracer()
+    engine.compile_federated_round(2, 2).lower(
+        engine.params, engine.opt_state, api.global_exchange, feed, feed,
+        jax.ShapeDtypeStruct(feed.shape[:3], np.float32),
+        jax.ShapeDtypeStruct(feed.shape[:1], np.float32))
+    records = telemetry.get_tracer().records()
+    assert all(r["point"] for r in records if r["name"] == "moe_gmm/plan")
+    plans = [r["attrs"] for r in records if r["name"] == "moe_gmm/plan"]
+    rows = cfg.moe_capacity_rows(engine.batch_size * engine.seq_len)
+    assert plans and all(
+        p["rows"] == rows and p["block_m"] == cfg.moe_block_rows
+        and p["row_tiles"] * p["block_m"] == rows
+        and p["column_tiles"] * p["block_n"] == p["n"]
+        and p["form"] == "interpret" and p["dtype"] == "bfloat16"
+        for p in plans)
+    fused = [p for p in plans if p["activation"] is not None]
+    if family == "zaya":
+        assert len(plans) == 6 * cfg.num_hidden_layers and not fused
+        return
+    layers = cfg.hybrid_override_pattern.count("E")
+    lat, mid = cfg.moe_latent_size, cfg.moe_intermediate_size
+    assert len(plans) == 4 * layers and len(fused) == 2 * layers
+    assert {p["activation"] for p in fused} == {"relu2"}
+    # the second product [mid -> latent] and its row gradient
+    assert sorted((p["transpose"], p["k"], p["n"]) for p in fused) == (
+        [(False, mid, lat)] * layers + [(True, lat, mid)] * layers)
+    assert sorted((p["transpose"], p["k"], p["n"]) for p in plans
+                  if p not in fused) == (
+        [(False, lat, mid)] * layers + [(True, mid, lat)] * layers)
 
 
 def test_the_fused_round_of_a_tiny_nemotron_is_the_host_loops():

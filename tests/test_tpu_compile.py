@@ -131,7 +131,11 @@ def test_held_top_k_grouped_matmul_compiles_for_v5e(one_chip):
     tokens, 22 choices a token of 512 experts, 128 held, in a 1024-wide
     latent with 2688-wide experts — the layout with a held range, both
     grouped products (column tiles of 896 and 1024) and their row
-    gradients, still without a sort or a scatter."""
+    gradients, still without a sort or a scatter; relu^2 between the
+    products is the second one's own (its prologue, its row gradient's
+    epilogue), so no fusion walks the 61,312-row buffer between the
+    kernels, forward or backward."""
+    from fedml_tpu.models.llm.nemotron_h import RELU2
     from fedml_tpu.ops import grouped_matmul as gmm
 
     m, k, lat, mid, held, bm = 2048, 22, 1024, 2688, 128, 128
@@ -141,10 +145,10 @@ def test_held_top_k_grouped_matmul_compiles_for_v5e(one_chip):
 
     def loss(x, up, down, chosen, gate):
         layout = gmm.group_layout(chosen, held, bm, first=0)
-        product = lambda a, w: gmm.grouped_matmul(
-            a, w, layout, block_m=bm, interpret=False)
+        product = lambda a, w, **kw: gmm.grouped_matmul(
+            a, w, layout, block_m=bm, interpret=False, **kw)
         hidden = product(gmm.dispatch(x, layout), up)
-        out = product(jnp.square(jax.nn.relu(hidden)), down)
+        out = product(hidden, down, activation=RELU2)
         mine = gmm.combine(out, layout).astype(jnp.float32)
         return jnp.sum(mine * gate[..., None])
 
@@ -160,6 +164,13 @@ def test_held_top_k_grouped_matmul_compiles_for_v5e(one_chip):
     rows = gmm.padded_rows(m * k, held, bm)
     assert rows == 61312 and f"bf16[{rows},{mid}]" in text
     assert " sort(" not in text and " scatter(" not in text
+    # the [61312, 2688] buffer is a kernel's output and a kernel's operand
+    # and nothing else's: relu^2 (forward) and 2 relu (backward) are not
+    # fusions over rows that three times in four hold nothing
+    wide = [line.split(" = ")[0].strip() for line in text.splitlines()
+            if re.search(rf" = \(?bf16\[{rows},{mid}\]", line)
+            and "parameter(" not in line]
+    assert len(wide) == 2 and all("moe_gmm" in name for name in wide), wide
     # no [assignments, assignments] grid: the largest integer or boolean
     # array is the [row tiles, rows a tile, tokens] one, as pred
     assert f"[{m * k},{m * k}]" not in text
