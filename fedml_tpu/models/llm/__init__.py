@@ -3,14 +3,14 @@ object answers ``cfg.module()`` with its flax module, and
 :func:`config_from_args` picks the configuration class from the ``model``
 a user's yaml names.
 
-A family is added as a file beside ``llama.py``, ``zaya.py`` and
-``nemotron_h.py`` that edits
-no shared file: a configuration (``PRESETS``, ``YAML_FIELDS`` and a
-``from_args`` over :func:`preset_from_args`), a block under the protocol
-at the top of ``causal_lm.py`` built from ``layers.py``, a
-``class <Family>ForCausalLM(CausalLM)`` that names the block — and a
-branch in :func:`config_from_args`. ``docs/llm_finetune.md`` ("Adding a
-model family") has the fields, and the reference and tests it comes with.
+A family is added as a file beside ``llama.py``, ``zaya.py``,
+``nemotron_h.py`` and ``glm_moe_lite.py`` that edits no shared file: a
+configuration (``PRESETS``, ``YAML_FIELDS`` and a ``from_args`` over
+:func:`preset_from_args`), a block under the protocol at the top of
+``causal_lm.py`` built from ``layers.py``, a ``class
+<Family>ForCausalLM(CausalLM)`` that names the block — and a branch in
+:func:`config_from_args`. ``docs/llm_finetune.md`` ("Adding a model
+family") has the fields, and the reference and tests it comes with.
 """
 from __future__ import annotations
 
@@ -19,7 +19,8 @@ from typing import Any, Optional
 
 def config_from_args(args: Any, vocab_size: Optional[int] = None):
     """``ZayaConfig`` for ``model: zaya``, ``NemotronHConfig`` for ``model:
-    nemotron_h``, else ``LlamaConfig``."""
+    nemotron_h``, ``GlmMoeLiteConfig`` for ``model: glm4_moe_lite``, else
+    ``LlamaConfig``."""
     model = str(getattr(args, "model", "")).lower()
     if model == "zaya":
         from fedml_tpu.models.llm.zaya import ZayaConfig
@@ -29,6 +30,10 @@ def config_from_args(args: Any, vocab_size: Optional[int] = None):
         from fedml_tpu.models.llm.nemotron_h import NemotronHConfig
 
         return NemotronHConfig.from_args(args, vocab_size=vocab_size)
+    if model == "glm4_moe_lite":
+        from fedml_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig
+
+        return GlmMoeLiteConfig.from_args(args, vocab_size=vocab_size)
     from fedml_tpu.models.llm.llama import LlamaConfig
 
     return LlamaConfig.from_args(args, vocab_size=vocab_size)

@@ -1,10 +1,13 @@
 """What every causal-LM family of the LLM path is built from: RMSNorm,
 rotary tables and their application, a dense layer with an optional
-low-rank adapter (its base may be stored quantized), and the choice of the
-causal attention product. A family's file imports these and no other
-family's file."""
+low-rank adapter (its base may be stored quantized), the choice of the
+causal attention product, the SwiGLU of a dense FFN, and what the families
+with routed experts share (a stack of expert matrices, the gated experts
+over ``ops/grouped_matmul.py``, the sigmoid top-k router). A family's file
+imports these and no other family's file."""
 from __future__ import annotations
 
+import math
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -12,7 +15,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fedml_tpu.ops import grouped_matmul as gmm
 from fedml_tpu.telemetry import get_tracer
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class RMSNorm(nn.Module):
@@ -245,3 +251,98 @@ def merge_heads(out: jax.Array) -> jax.Array:
     # its own, so a device trace can tell the glue from the matmuls
     with jax.named_scope("attn_layout"):
         return out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+class SwiGLU(nn.Module):
+    """``(silu(u W_g) * (u W_u)) W_d`` at ``width``: a dense layer's FFN or
+    a shared expert. Frozen under LoRA: the adapters sit on the attention
+    projections."""
+
+    cfg: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, u):
+        cfg, up_axes = self.cfg, ("embed", "mlp")
+        gate = lora_dense(cfg, self.width, "gate_proj", up_axes,
+                          adapters=False)(u)
+        up = lora_dense(cfg, self.width, "up_proj", up_axes,
+                        adapters=False)(u)
+        return lora_dense(cfg, cfg.hidden_size, "down_proj", ("mlp", "embed"),
+                          adapters=False)(nn.silu(gate) * up)
+
+
+def expert_stack(module, cfg, count: int, name: str, shape, in_axis: str,
+                 out_axis: str) -> jax.Array:
+    """``count`` experts' matrices ``name`` as one parameter of ``module``,
+    ``[count, *shape]`` with the leading dimension on the mesh's expert
+    axis, in the compute type."""
+    return module.param(
+        name, nn.with_logical_partitioning(
+            nn.initializers.lecun_normal(), ("expert", in_axis, out_axis)),
+        (count, *shape), cfg.param_dtype).astype(cfg.dtype)
+
+
+class GatedExperts(nn.Module):
+    """``(silu(x G_e) * (x U_e)) D_e`` for ``experts`` experts of
+    ``cfg.moe_intermediate_size``, over rows already sorted by expert:
+    three grouped products, the SwiGLU between them outside the kernels."""
+
+    cfg: Any
+    experts: int
+
+    @nn.compact
+    def __call__(self, xs, layout):
+        cfg = self.cfg
+        hid, mid = cfg.hidden_size, cfg.moe_intermediate_size
+        stack = lambda name, shape, *axes: expert_stack(
+            self, cfg, self.experts, name, shape, *axes)
+        product = lambda a, w: gmm.grouped_matmul(
+            a, w, layout, block_m=cfg.moe_block_rows)
+        gate = product(xs, stack("gate_proj", (hid, mid), "embed", "mlp"))
+        up = product(xs, stack("up_proj", (hid, mid), "embed", "mlp"))
+        return product(nn.silu(gate) * up,
+                       stack("down_proj", (mid, hid), "mlp", "embed"))
+
+
+def sigmoid_topk(module, u, total: int, k: int, scale: float):
+    """A token's ``k`` of ``total`` experts by sigmoid scores with a
+    selection bias (``noaux_tc``), under the scope ``router``; the float32
+    parameters ``router_weight`` ``[hidden, total]`` and ``router_bias``
+    are ``module``'s. ``chosen [m, k]`` = the largest of ``score + bias``
+    (the bias picks, it does not weigh) and ``weights [m, total]`` = the
+    chosen experts' scores over their sum times ``scale``, zero elsewhere.
+    The weights stay a dense table: no gather of scalars on the way in, no
+    scatter-add on the way back."""
+    hid = u.shape[-1]
+    gate = module.param(
+        "router_weight", nn.with_logical_partitioning(
+            nn.initializers.normal(1.0 / math.sqrt(hid)), ("embed", None)),
+        (hid, total), jnp.float32)
+    bias = module.param("router_bias", nn.initializers.zeros, (total,),
+                        jnp.float32)
+    with jax.named_scope("router"):
+        # float32 at full precision: a rounded score is a token sent
+        # to another expert
+        scores = jax.nn.sigmoid(jnp.matmul(
+            u.reshape(-1, hid).astype(jnp.float32), gate,
+            precision=HIGHEST))
+        _, chosen = jax.lax.top_k(
+            jax.lax.stop_gradient(scores) + bias, k)              # [m, k]
+        chosen = chosen.astype(jnp.int32)
+        picked = jnp.any(
+            chosen[:, :, None] == jnp.arange(total, dtype=jnp.int32),
+            axis=1)
+        kept = jnp.where(picked, scores, 0.0)
+        weights = kept / (jnp.sum(kept, -1, keepdims=True) + 1e-20) * scale
+    return chosen, weights
+
+
+def choice_weights(chosen, weights, first: int, held: int):
+    """``[m, k]``: each choice's weight out of :func:`sigmoid_topk`'s
+    table, zero for an expert outside ``first .. first + held`` (held on
+    another chip)."""
+    own = (chosen[:, :, None] - first
+           == jnp.arange(held, dtype=jnp.int32))                # [m, k, Eh]
+    return jnp.sum(
+        jnp.where(own, weights[:, None, first:first + held], 0.0), axis=2)

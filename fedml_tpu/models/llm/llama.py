@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from fedml_tpu.models.llm import preset_from_args
 from fedml_tpu.models.llm.causal_lm import CausalLM
-from fedml_tpu.models.llm.layers import (RMSNorm, apply_rope,
+from fedml_tpu.models.llm.layers import (RMSNorm, SwiGLU, apply_rope,
                                          causal_attention, lora_dense,
                                          merge_heads)
 
@@ -210,20 +210,6 @@ class LlamaAttention(nn.Module):
         return out, new_cache
 
 
-class LlamaMLP(nn.Module):
-    cfg: LlamaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        mid, up_axes = cfg.intermediate_size, ("embed", "mlp")
-        # frozen under LoRA: the adapters sit on the attention projections
-        gate = lora_dense(cfg, mid, "gate_proj", up_axes, adapters=False)(x)
-        up = lora_dense(cfg, mid, "up_proj", up_axes, adapters=False)(x)
-        return lora_dense(cfg, cfg.hidden_size, "down_proj", ("mlp", "embed"),
-                          adapters=False)(nn.silu(gate) * up)
-
-
 class LlamaMoE(nn.Module):
     """Mixture-of-experts FFN (Mixtral/Switch shape) with expert parallelism.
 
@@ -340,7 +326,7 @@ class LlamaBlock(nn.Module):
         x = x + attn_out
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         ffn = (LlamaMoE(cfg, name="moe") if cfg.num_experts > 0
-               else LlamaMLP(cfg, name="mlp"))
+               else SwiGLU(cfg, cfg.intermediate_size, name="mlp"))
         x = x + ffn(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="post_attn_norm")(x)
         )

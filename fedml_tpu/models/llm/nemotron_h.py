@@ -69,11 +69,12 @@ import jax.numpy as jnp
 from fedml_tpu.models.llm import preset_from_args
 from fedml_tpu.models.llm.causal_lm import CausalLM
 from fedml_tpu.models.llm.layers import (RMSNorm, causal_attention,
-                                         lora_dense, merge_heads)
+                                         choice_weights, expert_stack,
+                                         lora_dense, merge_heads,
+                                         sigmoid_topk)
 from fedml_tpu.ops import grouped_matmul as gmm
 from fedml_tpu.ops.ssd import ssd
 
-HIGHEST = jax.lax.Precision.HIGHEST
 KINDS = "M*E"
 
 
@@ -345,13 +346,8 @@ class NemotronHExperts(nn.Module):
         lat, mid, e = (cfg.moe_latent_size, cfg.moe_intermediate_size,
                        cfg.n_routed_experts)
 
-        def experts(name, shape, in_axis, out_axis):
-            return self.param(
-                name, nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(),
-                    ("expert", in_axis, out_axis)),
-                (e, *shape), cfg.param_dtype).astype(cfg.dtype)
-
+        experts = lambda name, shape, *axes: expert_stack(
+            self, cfg, e, name, shape, *axes)
         product = lambda a, w, **kw: gmm.grouped_matmul(
             a, w, layout, block_m=cfg.moe_block_rows, **kw)
         up = product(xs, experts("up_proj", (lat, mid), "embed", "mlp"))
@@ -384,36 +380,11 @@ class NemotronHMoE(nn.Module):
         total, held, first, k = (cfg.experts_total, cfg.n_routed_experts,
                                  cfg.held_experts_first,
                                  cfg.num_experts_per_tok)
-        gate = self.param(
-            "router_weight", nn.with_logical_partitioning(
-                nn.initializers.normal(1.0 / math.sqrt(hid)), ("embed", None)),
-            (hid, total), jnp.float32)
-        bias = self.param("router_bias", nn.initializers.zeros, (total,),
-                          jnp.float32)
-        with jax.named_scope("router"):
-            # float32 at full precision: a rounded score is a token sent
-            # to another expert
-            scores = jax.nn.sigmoid(jnp.matmul(
-                u.reshape(b * t, hid).astype(jnp.float32), gate,
-                precision=HIGHEST))
-            _, chosen = jax.lax.top_k(
-                jax.lax.stop_gradient(scores) + bias, k)          # [m, k]
-            chosen = chosen.astype(jnp.int32)
-            # the weights stay a dense [m, E] table: no gather of scalars
-            # on the way in, no scatter-add on the way back
-            picked = jnp.any(
-                chosen[:, :, None] == jnp.arange(total, dtype=jnp.int32),
-                axis=1)
-            kept = jnp.where(picked, scores, 0.0)
-            weights = kept / (jnp.sum(kept, -1, keepdims=True) + 1e-20) \
-                * cfg.routed_scaling_factor
+        chosen, weights = sigmoid_topk(self, u, total, k,
+                                       cfg.routed_scaling_factor)
         with jax.named_scope("moe_dispatch"):
             layout = gmm.group_layout(chosen, held, cfg.moe_block_rows, first)
-            own = (chosen[:, :, None] - first
-                   == jnp.arange(held, dtype=jnp.int32))            # [m, k, Eh]
-            w_held = jnp.sum(
-                jnp.where(own, weights[:, None, first:first + held], 0.0),
-                axis=2)                                             # [m, k]
+            w_held = choice_weights(chosen, weights, first, held)   # [m, k]
         latent = lora_dense(cfg, cfg.moe_latent_size, "latent_in",
                             ("embed", "mlp"), adapters=False)(u)
         with jax.named_scope("moe_dispatch"):

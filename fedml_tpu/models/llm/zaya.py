@@ -59,7 +59,7 @@ import jax.numpy as jnp
 
 from fedml_tpu.models.llm import preset_from_args
 from fedml_tpu.models.llm.causal_lm import CausalLM
-from fedml_tpu.models.llm.layers import (RMSNorm, apply_rope,
+from fedml_tpu.models.llm.layers import (GatedExperts, RMSNorm, apply_rope,
                                          causal_attention, lora_dense,
                                          merge_heads)
 from fedml_tpu.ops import grouped_matmul as gmm
@@ -281,32 +281,6 @@ class ZayaRouter(nn.Module):
             return jax.nn.softmax(z, axis=-1), state
 
 
-class ZayaExperts(nn.Module):
-    """The experts' SwiGLU over rows already sorted by expert (step 9)."""
-
-    cfg: ZayaConfig
-
-    @nn.compact
-    def __call__(self, xs, layout):
-        cfg = self.cfg
-        hid, mid, e = (cfg.hidden_size, cfg.moe_intermediate_size,
-                       cfg.num_experts)
-
-        def experts(name, shape, in_axis, out_axis):
-            return self.param(
-                name, nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(),
-                    ("expert", in_axis, out_axis)),
-                (e, *shape), cfg.param_dtype).astype(cfg.dtype)
-
-        product = lambda a, w: gmm.grouped_matmul(
-            a, w, layout, block_m=cfg.moe_block_rows)
-        gate = product(xs, experts("gate_proj", (hid, mid), "embed", "mlp"))
-        up = product(xs, experts("up_proj", (hid, mid), "embed", "mlp"))
-        return product(nn.silu(gate) * up,
-                       experts("down_proj", (mid, hid), "mlp", "embed"))
-
-
 class ZayaMoE(nn.Module):
     cfg: ZayaConfig
 
@@ -322,7 +296,8 @@ class ZayaMoE(nn.Module):
             layout = gmm.group_layout(expert, cfg.num_experts,
                                       cfg.moe_block_rows)
             xs = gmm.dispatch(h.reshape(b * t, hid), layout)
-        ys = ZayaExperts(cfg, name="experts")(xs, layout)
+        # the experts' SwiGLU over rows sorted by expert (step 9)
+        ys = GatedExperts(cfg, cfg.num_experts, name="experts")(xs, layout)
         with jax.named_scope("moe_combine"):
             y = gmm.combine(ys, layout).astype(jnp.float32) * p_e
         return y.astype(cfg.dtype).reshape(b, t, hid), state, layout.counts

@@ -74,7 +74,8 @@ def create(args: Any, output_dim: int = 10) -> nn.Module:
         if "stackoverflow" in dataset or "reddit" in dataset:
             return RNNStackOverflow(vocab_size=max(output_dim, 4))
         return RNNOriginalFedAvg(vocab_size=max(output_dim, 4))
-    if name in ("llama", "llama_lora", "transformer", "zaya", "nemotron_h"):
+    if name in ("llama", "llama_lora", "transformer", "zaya", "nemotron_h",
+                "glm4_moe_lite"):
         from fedml_tpu.models.llm import config_from_args
 
         return config_from_args(args, vocab_size=max(output_dim, 32)).module()

@@ -116,6 +116,13 @@ def _block(n: int, d: int, unaligned: int) -> int:
     updates. 2048 is where the v5e stopped gaining (kernels alone at
     T4096: 512 x 1024 5.31 ms, 1024 x 2048 4.50, 2048 x 2048 4.40;
     4096 x 4096 4.34 for 22 s more of Mosaic compile; PERF.md, PR 28).
+    Past a head of 128 the cap shrinks with the head, so a block's bytes
+    stay what they were: at D256 the blocks are 1024 x 1024, which compile
+    under ``_VMEM_LIMIT`` and read, at ``[1, 20, 4096, 256]`` on the v5e,
+    fwd 1.400, dq 1.748, dkv 2.250 ms a call = 72.7 % of the kernels' own
+    9 products at the bf16 peak (the same call at ``[1, 32, 4096, 64]``
+    1.309 / 1.333 / 1.808 ms = 35.3 %, at 32 / 4 heads of 128 1.317 /
+    1.322 / 1.818 ms = 70.4 %; PERF.md, PR 37).
     A sequence that is no multiple of ``_SUB`` keeps the former blocks.
     """
     if n % _SUB != 0:

@@ -598,8 +598,10 @@ class MasterAgent(BrokerJsonAgent):
                 if current not in RunStatus.TERMINAL:
                     view.rank_status[run_id] = status
                     view.rank_rc[run_id] = returncode
-                    if status == RunStatus.RUNNING and \
-                            run_id in self._awaiting_resume:
+                    # a resumed life that ran to its end between two of
+                    # the node's status polls is first seen FINISHED
+                    if status in (RunStatus.RUNNING, RunStatus.FINISHED) \
+                            and run_id in self._awaiting_resume:
                         self._awaiting_resume.discard(run_id)
                         resumed = True
                     needs_resched = (

@@ -125,24 +125,28 @@ def _qkv(t, heads, kv_heads, d, dtype, seed=0):
 
 
 # T spans three q blocks or more, so a call holds blocks above, on and below
-# the diagonal; 100 and 160 are ragged, 320 ragged with sub-tiles
+# the diagonal; 100 and 160 are ragged, 320 ragged with sub-tiles. Heads of
+# 32 throughout, and heads of 256 (``glm4_moe_lite``'s: as many key-value
+# heads as query heads) whole and ragged
 PARITY = [
-    # t, heads, kv_heads, block_q, block_k
-    (512, 2, 2, 128, 256),
-    (512, 4, 1, 128, 256),
-    (768, 2, 2, 256, 256),
-    (512, 4, 1, 256, 128),
-    (100, 2, 2, 32, 32),
-    (160, 4, 1, 32, 64),
-    (320, 2, 2, 128, 128),
+    # t, heads, kv_heads, block_q, block_k, d
+    (512, 2, 2, 128, 256, 32),
+    (512, 4, 1, 128, 256, 32),
+    (768, 2, 2, 256, 256, 32),
+    (512, 4, 1, 256, 128, 32),
+    (100, 2, 2, 32, 32, 32),
+    (160, 4, 1, 32, 64, 32),
+    (320, 2, 2, 128, 128, 32),
+    (512, 4, 4, 128, 256, 256),
+    (200, 4, 4, 64, 64, 256),
 ]
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-@pytest.mark.parametrize("t,heads,kv_heads,block_q,block_k", PARITY)
+@pytest.mark.parametrize("t,heads,kv_heads,block_q,block_k,d", PARITY)
 def test_kernels_match_reference_in_float32(t, heads, kv_heads, block_q,
-                                            block_k, causal):
-    q, k, v, w = _qkv(t, heads, kv_heads, 32, jnp.float32)
+                                            block_k, d, causal):
+    q, k, v, w = _qkv(t, heads, kv_heads, d, jnp.float32)
     plan = causal_plan(t, t, block_q, block_k, causal)
     if causal and t % block_q == 0:
         assert plan["skipped"] and plan["unmasked"] and plan["masked"]

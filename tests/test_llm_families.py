@@ -17,6 +17,7 @@ from fedml_tpu.models.llm.causal_lm import CausalLM
 from fedml_tpu.models.llm.layers import (RMSNorm, apply_rope,
                                          causal_attention, lora_dense,
                                          merge_heads)
+from fedml_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig
 from fedml_tpu.models.llm.llama import LlamaConfig
 from fedml_tpu.models.llm.zaya import ZayaConfig
 from fedml_tpu.train.llm.sharding import unbox
@@ -161,10 +162,24 @@ def _zaya():
     return (cfg, *seeded(cfg))
 
 
-@pytest.mark.parametrize("family", [
-    lambda: _llama(True), lambda: _llama(False), _zaya,
-], ids=["llama-tied", "llama-untied", "zaya"])
-def test_head_inputs_give_the_loss_the_default_calls_logits_give(family):
+def _glm():
+    from tests.test_glm_moe_lite import seeded
+
+    cfg = GlmMoeLiteConfig.tiny(lora_rank=4, dtype=jnp.float32,
+                                param_dtype=jnp.float32)
+    return (cfg, *seeded(cfg))
+
+
+@pytest.mark.parametrize("family, rtol", [
+    (lambda: _llama(True), 0), (lambda: _llama(False), 0), (_zaya, 0),
+    # one entry of 8,192 of the embedding's gradient is 17.8 here, and the
+    # two float32 sums of it lie 2.1e-5 apart: 1.2e-6 of it (about ten
+    # float32 epsilons), just over the absolute bound that entries of
+    # order 1 keep. Only this family gets a relative term.
+    (_glm, 2e-6),
+], ids=["llama-tied", "llama-untied", "zaya", "glm4_moe_lite"])
+def test_head_inputs_give_the_loss_the_default_calls_logits_give(family,
+                                                                 rtol):
     """The default call returns the head's product in the compute type,
     then float32 (one line of the shell, whatever the family);
     ``head_inputs=True`` stops before that product, and the loss made from
@@ -207,7 +222,7 @@ def test_head_inputs_give_the_loss_the_default_calls_logits_give(family):
                        for g in (grads, want_grads))
     assert flat.keys() == want_flat.keys()
     for path, g in flat.items():
-        np.testing.assert_allclose(g, want_flat[path], atol=2e-5, rtol=0,
+        np.testing.assert_allclose(g, want_flat[path], atol=2e-5, rtol=rtol,
                                    err_msg=str(path))
     assert float(jnp.abs(grads["params"]["embed_tokens"]).max()) > 1e-3
 
@@ -248,7 +263,32 @@ _ZAYA_TINY = dict(
     head_dim=4, moe_intermediate_size=64, router_hidden_size=8,
     max_position_embeddings=128, remat=False, moe_block_rows=8)
 
+_GLM_FLASH = dict(
+    vocab_size=154880, hidden_size=2048, num_hidden_layers=47,
+    first_k_dense_replace=1, intermediate_size=10240, num_attention_heads=20,
+    num_key_value_heads=20, q_lora_rank=768, kv_lora_rank=512,
+    qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    attention_bias=False, rope_theta=1000000.0, rope_scaling=None,
+    max_position_embeddings=202752, n_routed_experts=64,
+    num_experts_per_tok=4, moe_intermediate_size=1536, n_shared_experts=1,
+    routed_scaling_factor=1.8, norm_topk_prob=True, topk_method="noaux_tc",
+    n_group=1, topk_group=1, hidden_act="silu", rms_norm_eps=1e-5,
+    tie_word_embeddings=False, lora_rank=0, lora_alpha=16.0, dtype=BF16,
+    param_dtype=F32, remat=True, remat_policy="full", use_flash=True,
+    moe_block_rows=64)
+_GLM_TINY = dict(
+    _GLM_FLASH, vocab_size=256, hidden_size=32, num_hidden_layers=3,
+    intermediate_size=160, num_attention_heads=4, num_key_value_heads=4,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4,
+    v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
+    moe_intermediate_size=24, max_position_embeddings=128, remat=False,
+    moe_block_rows=8)
+
 PRESETS = [
+    ("glm4_moe_lite", None, _GLM_TINY), ("glm4_moe_lite", "tiny", _GLM_TINY),
+    ("glm4_moe_lite", "glm_4_7_flash", _GLM_FLASH),
+    ("glm4_moe_lite", "GLM-4.7-Flash", _GLM_FLASH),
+    ("glm4_moe_lite", "30b_a3b", _GLM_FLASH),
     ("llama", None, _LLAMA_TINY), ("llama", "tiny", _LLAMA_TINY),
     ("llama", "llama2_7b", _LLAMA_7B), ("llama", "7b", _LLAMA_7B),
     ("llama", "Llama2-7B", _LLAMA_7B),
@@ -258,9 +298,12 @@ PRESETS = [
     ("zaya", "zaya1_8b", _ZAYA_8B), ("zaya", "8b", _ZAYA_8B),
     ("zaya", "ZAYA1-8B", _ZAYA_8B),
 ]
-CLASSES = {"llama": LlamaConfig, "zaya": ZayaConfig}
-TINY = {"llama": _LLAMA_TINY, "zaya": _ZAYA_TINY}
-EIGHT_B = {"llama": _LLAMA_8B, "zaya": _ZAYA_8B}
+CLASSES = {"llama": LlamaConfig, "zaya": ZayaConfig,
+           "glm4_moe_lite": GlmMoeLiteConfig}
+TINY = {"llama": _LLAMA_TINY, "zaya": _ZAYA_TINY, "glm4_moe_lite": _GLM_TINY}
+# a preset at published widths a family, by a name its PRESETS know
+BIG = {"llama": ("8b", _LLAMA_8B), "zaya": ("8b", _ZAYA_8B),
+       "glm4_moe_lite": ("30b_a3b", _GLM_FLASH)}
 
 
 def _args(model, **kw):
@@ -300,6 +343,13 @@ OVERRIDES = [
     ("zaya", "num_hidden_layers", 3.0, 3),
     ("zaya", "max_position_embeddings", "512", 512),
     ("zaya", "moe_block_rows", "16", 16),
+    ("glm4_moe_lite", "lora_rank", "8", 8),
+    ("glm4_moe_lite", "num_hidden_layers", 6.0, 6),
+    ("glm4_moe_lite", "first_k_dense_replace", "2", 2),
+    ("glm4_moe_lite", "moe_block_rows", "128", 128),
+    ("glm4_moe_lite", "use_flash_attention", 0, ("use_flash", False)),
+    ("glm4_moe_lite", "remat_policy", "dots", "dots"),
+    ("glm4_moe_lite", "base_params_bf16", True, ("param_dtype", BF16)),
     # the three switches every family reads under the same names
     ("llama", "use_flash_attention", 0, ("use_flash", False)),
     ("zaya", "use_flash_attention", 0, ("use_flash", False)),
@@ -315,7 +365,7 @@ OVERRIDES = [
     ids=[f"{m}-{k}" for m, k, _, _ in OVERRIDES])
 def test_from_args_overrides_a_field_and_no_other(model, key, said, want):
     field, value = want if isinstance(want, tuple) else (key, want)
-    for size, base in (("tiny", TINY[model]), ("8b", EIGHT_B[model])):
+    for size, base in (("tiny", TINY[model]), BIG[model]):
         cfg = config_from_args(_args(model, model_size=size, **{key: said}))
         assert type(getattr(cfg, field)) is type(value)
         assert cfg == CLASSES[model](**{**base, field: value})

@@ -198,8 +198,10 @@ def test_chunked_scan_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
-def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2):
-    """``llm/fused_round`` at the 7B widths, lowered from shapes alone.
+def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2,
+                         cfg=None, seq_len=512):
+    """``llm/fused_round`` at the 7B widths (or for ``cfg``, another
+    family's configuration), lowered from shapes alone.
 
     The trainer's constructor places nothing; params, shardings and the
     optimizer state are handed to it as ``ShapeDtypeStruct`` trees on the
@@ -219,12 +221,12 @@ def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2):
     )
 
     class Args:
-        max_seq_length = 512
+        max_seq_length = seq_len
         per_device_batch_size = 1
         learning_rate = 1e-4
 
     mesh = make_mesh(fsdp=fsdp, devices=devices[:fsdp])
-    cfg = LlamaConfig.llama2_7b(
+    cfg = cfg or LlamaConfig.llama2_7b(
         num_hidden_layers=layers, lora_rank=16, param_dtype=jnp.bfloat16,
         remat_policy="none", use_flash=True)
     tr = LLMTrainer(cfg, Args(), mesh=mesh)
@@ -273,6 +275,41 @@ def test_fused_round_7b_widths_compiles_for_v5e(topo, monkeypatch, fsdp):
         # weights are gathered per layer
         assert mem.argument_size_in_bytes < 0.3 * 1.36e9
         assert "all-gather" in text
+
+
+def test_fused_round_glm_moe_lite_widths_compiles_for_v5e(topo, monkeypatch):
+    """``model: glm4_moe_lite`` at GLM-4.7-Flash's published widths, the
+    leading dense layer and one expert layer at T4096 as
+    ``glm-4.7-flash.round-4k`` runs them: the flash kernels at heads of 256
+    (blocks of 1024) under their VMEM limit, the grouped products at 2048 x
+    1536 with 64 experts held, each kernel under its own name, no sort but
+    the router's top-k and no scatter, and a plan that fits the chip."""
+    from fedml_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig
+    from fedml_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "default_platform", lambda: "tpu")
+    cfg = GlmMoeLiteConfig(
+        num_hidden_layers=2, lora_rank=16, param_dtype=jnp.bfloat16,
+        remat_policy="none", use_flash=True)
+    compiled = _compile_fused_round(topo.devices, 1, cfg=cfg, seq_len=4096)
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert resident < V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes > 0.99 * mem.output_size_in_bytes
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    # two layers' flash kernels; the expert layer's gate, up and down
+    # products and their row gradients
+    assert sorted(calls) == sorted(
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * 2
+        + ["moe_gmm"] * 3 + ["moe_gmm_t"] * 3)
+    assert "bf16[1,20,4096,256]" in text
+    # the one sort is the router's top-4 of 64; the layout has none
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert len(sorts) == 1 and "/moe/router/top_k" in sorts[0]
+    assert " scatter(" not in text
 
 
 def _unfused(text):
