@@ -127,9 +127,11 @@ class GlmMoeLiteConfig:
 
     # what the round's program hands back beside the loss, summed over the
     # round: per expert layer the assignments placed with each expert, the
-    # experts that got any, the assignments made and the rows that hold one
+    # experts that got any, the sorted buffer's row tiles that hold an
+    # assignment, the assignments made and the rows that hold one
     # (``dropped`` = their difference)
-    STATS = ("moe_tokens", "moe_live", "moe_held", "moe_placed")
+    STATS = ("moe_tokens", "moe_live", "moe_tiles", "moe_held",
+             "moe_placed")
     # nothing trains the router, so no load-balance term joins the loss
     aux_loss_weight = 0.0
 
@@ -316,6 +318,7 @@ class GlmMoeLiteMoE(nn.Module):
             name="shared")(u)
         stats = {"moe_tokens": layout.counts,
                  "moe_live": jnp.sum(layout.counts > 0, dtype=jnp.int32),
+                 "moe_tiles": layout.live_tiles[0],
                  "moe_held": jnp.sum(layout.held, dtype=jnp.int32),
                  "moe_placed": jnp.sum(layout.valid, dtype=jnp.int32)}
         return out, stats
@@ -357,7 +360,7 @@ class GlmMoeLiteForCausalLM(CausalLM):
     """:class:`CausalLM` over :class:`GlmMoeLiteBlock`: dense before
     ``first_k_dense_replace``, expert after. Every call sows, per EXPERT
     layer (first first), ``moe_tokens`` ``[layers, experts]``, ``moe_live``,
-    ``moe_held`` and ``moe_placed`` ``[layers]``."""
+    ``moe_tiles``, ``moe_held`` and ``moe_placed`` ``[layers]``."""
 
     block = GlmMoeLiteBlock
 

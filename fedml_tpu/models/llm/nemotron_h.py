@@ -134,9 +134,11 @@ class NemotronHConfig:
 
     # what the round's program hands back beside the loss, summed over the
     # round: per expert layer the assignments placed with each held expert,
-    # the held experts that got any, the assignments whose expert is held
-    # here and the rows that hold one (``dropped`` = their difference)
-    STATS = ("moe_tokens", "moe_live", "moe_held", "moe_placed")
+    # the held experts that got any, the sorted buffer's row tiles that
+    # hold an assignment, the assignments whose expert is held here and the
+    # rows that hold one (``dropped`` = their difference)
+    STATS = ("moe_tokens", "moe_live", "moe_tiles", "moe_held",
+             "moe_placed")
     # nothing trains the router, so no load-balance term joins the loss
     aux_loss_weight = 0.0
 
@@ -401,6 +403,7 @@ class NemotronHMoE(nn.Module):
         in_range = (chosen >= first) & (chosen < first + held)
         stats = {"moe_tokens": layout.counts,
                  "moe_live": jnp.sum(layout.counts > 0, dtype=jnp.int32),
+                 "moe_tiles": layout.live_tiles[0],
                  "moe_held": jnp.sum(in_range, dtype=jnp.int32),
                  "moe_placed": jnp.sum(layout.valid, dtype=jnp.int32)}
         return out, stats
@@ -454,7 +457,8 @@ class NemotronHForCausalLM(CausalLM):
     """:class:`CausalLM` over :class:`NemotronHBlock`, the kind of layer
     ``i`` read from the configuration's pattern. Every call sows, per
     EXPERT layer (first first), ``moe_tokens`` ``[layers, held experts]``,
-    ``moe_live``, ``moe_held`` and ``moe_placed`` ``[layers]``."""
+    ``moe_live``, ``moe_tiles``, ``moe_held`` and ``moe_placed``
+    ``[layers]``."""
 
     block = NemotronHBlock
 

@@ -102,9 +102,10 @@ class ZayaConfig:
 
     # what the round's program hands back beside the loss, summed over the
     # round (LLMTrainer.compile_federated_round): tokens per layer and
-    # expert, and per layer the experts that got any (a step's grouped
-    # products read only those experts' matrices)
-    round_stats = ("moe_tokens", "moe_live")
+    # expert, per layer the experts that got any (a step's grouped
+    # products read only those experts' matrices) and the row tiles that
+    # hold a token (a weight copy hides under tiles / live products)
+    round_stats = ("moe_tokens", "moe_live", "moe_tiles")
     # nothing trains the router, so no load-balance term joins the loss
     aux_loss_weight = 0.0
 
@@ -300,7 +301,8 @@ class ZayaMoE(nn.Module):
         ys = GatedExperts(cfg, cfg.num_experts, name="experts")(xs, layout)
         with jax.named_scope("moe_combine"):
             y = gmm.combine(ys, layout).astype(jnp.float32) * p_e
-        return y.astype(cfg.dtype).reshape(b, t, hid), state, layout.counts
+        return (y.astype(cfg.dtype).reshape(b, t, hid), state,
+                (layout.counts, layout.live_tiles[0]))
 
 
 class ZayaBlock(nn.Module):
@@ -335,8 +337,9 @@ class ZayaForCausalLM(CausalLM):
     What flows from layer to layer is the pair ``(x, s)``: the residual
     stream and the router's state. Every call sows ``moe_tokens``, the
     ``[layers, experts]`` count of tokens each expert was sent (they sum
-    to ``B * T`` in every layer: nothing is dropped), and ``moe_live``,
-    per layer the number of experts that were sent any.
+    to ``B * T`` in every layer: nothing is dropped), ``moe_live``, per
+    layer the number of experts that were sent any, and ``moe_tiles``, per
+    layer the row tiles of the sorted buffer that hold a token.
     """
 
     block = ZayaBlock
@@ -348,6 +351,7 @@ class ZayaForCausalLM(CausalLM):
 
     @nn.nowrap
     def layer_stats(self, stats):
-        counts = jnp.stack(stats)
+        counts, tiles = (jnp.stack(each) for each in zip(*stats))
         return {"moe_tokens": counts,
-                "moe_live": jnp.sum(counts > 0, axis=1, dtype=jnp.int32)}
+                "moe_live": jnp.sum(counts > 0, axis=1, dtype=jnp.int32),
+                "moe_tiles": tiles}

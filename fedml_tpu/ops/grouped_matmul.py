@@ -14,18 +14,38 @@ kernel is a plain tiled product whose weight block is chosen per row tile
 by a scalar-prefetched table. The padded buffer has ``padded_rows(a, E,
 block_m)`` rows whatever the routing is (shapes stay static; ``a`` the most
 assignments that can be held, every one of every token's choices); the tiles
-past the last run are neither computed nor fetched (their block indices
-repeat the last live tile's, so the pipeline issues no copy) and their
-rows of the output stay unwritten — nothing reads them, since the way back
-(:func:`combine`) gathers each token's own row.
+past the last run are neither computed nor fetched (their x and output
+block indices repeat the last live tile's, so the pipeline issues no copy,
+and the kernel asks for no weights there) and their rows of the output stay
+unwritten — nothing reads them, since the way back (:func:`combine`)
+gathers each token's own row.
 
 Grid ``(column tiles, row tiles)`` with the row tiles innermost: consecutive
-row tiles of one expert name the same weight block, which is then copied
-once per expert and column tile — the product reads every live expert's
-matrix once, which is the whole cost at B1 (an expert sees tens of rows and
-its 2048x2048 matrix is 8 MB). The contraction is not tiled: a block holds
-the full contracted width (``[block_m, K] x [K, block_n]``), so there is no
-accumulator and no revisit.
+row tiles of one expert (a RUN) multiply by the same weight block, which is
+copied once per expert and column tile — the product reads every live
+expert's matrix once, which is the whole cost at B1 (an expert sees tens of
+rows and its 2048x2048 matrix is 8 MB). The contraction is not tiled: a
+block holds the full contracted width (``[block_m, K] x [K, block_n]``), so
+there is no accumulator and no revisit.
+
+The weights' schedule is the kernel's own: the expert stack stays in HBM
+and the kernel holds ``WEIGHT_SLOTS`` = 2 blocks in VMEM (what Pallas's
+double buffer held), one copy semaphore a slot. At the FIRST tile of a run
+it asks for the NEXT run's block — found by a scalar walk down the table
+from the next tile until the expert changes; past the last live tile it is
+the first run of the next column tile — into the slot the run before has
+just left, then waits for its own, which was asked for a whole run ago. So
+a copy has the ``r`` products of a run of ``r`` tiles to hide under (Pallas
+asks for a grid step's blocks one step ahead: one product, whatever ``r``;
+at 256 rows an expert, where a block's copy takes as long as three
+products, copies and products then ADD: PERF.md, PR 38), and a run of one
+tile asks at that tile: the step-ahead schedule, to the step. The copy goes
+to the background queue (``priority=1``): the pipeline's x tile for the
+next grid step is asked for after it and needed first. Dead tiles start and
+wait for nothing; a call with no live tile issues no copy; every copy
+started is waited for by the run it is for. Both grid axes carry that state
+(the slot in use, what is in flight — across a column tile's end too), so
+both are ``arbitrary``: neither may be split over cores.
 
 The experts are frozen: the custom VJP gives the gradient with respect to
 the ROWS only (``dx = dy @ w[e]^T``, the same kernel contracting over the
@@ -84,6 +104,9 @@ BLOCK_M = 64
 # the widest column tile; the tile itself is chosen from N (column_tile)
 BLOCK_N = 1024
 _VMEM_LIMIT = 64 * 1024 * 1024
+# weight blocks the kernel holds: the run being multiplied and the next
+# run's, in flight (what Pallas's own double buffer held)
+WEIGHT_SLOTS = 2
 
 
 class GroupLayout(NamedTuple):
@@ -223,22 +246,66 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # -- the kernel ------------------------------------------------------------
-def _kernel(tile_group, live_tiles, x_ref, w_ref, *refs, transpose_rhs,
-            activation):
-    """One live tile's product. With an ``activation``: its value on the x
-    tile before the product, or (``transpose_rhs``, the row gradient) its
-    derivative of the saved tile ``refs[0]`` on the product after it."""
-    del tile_group
-    *saved, o_ref = refs
+def _kernel(tile_group, live_tiles, x_ref, w_hbm, *refs, transpose_rhs,
+            activation, block_n):
+    """One live tile's product, and at the FIRST tile of a run the weights'
+    schedule: start the copy of the next run's block into the other slot,
+    then wait for this run's (module docstring). With an ``activation``:
+    its value on the x tile before the product, or (``transpose_rhs``, the
+    row gradient) its derivative of the saved tile ``refs[0]`` on the
+    product after it."""
+    *saved, o_ref, w_buf, arrived, slot = refs
+    j, i = pl.program_id(0), pl.program_id(1)
+    live = live_tiles[0]
 
-    @pl.when(pl.program_id(1) < live_tiles[0])
+    def block_copy(expert, column, s):
+        columns = pl.ds(column * block_n, block_n)
+        block = w_hbm.at[expert, columns, :] if transpose_rhs \
+            else w_hbm.at[expert, :, columns]
+        return pltpu.make_async_copy(block, w_buf.at[s], arrived.at[s])
+
+    @pl.when(i < live)
     def _():
+        expert = tile_group[i]
+
+        @pl.when((i == 0) | (tile_group[jnp.maximum(i - 1, 0)] != expert))
+        def _():
+            # the call's first block has nothing to hide under; it goes
+            # to slot 0, where the flip below lands
+            @pl.when((j == 0) & (i == 0))
+            def _():
+                slot[0] = 1
+                block_copy(expert, j, 0).start()
+
+            here = 1 - slot[0]
+            slot[0] = here
+            # the next run: the first later live tile of another expert,
+            # or past the last one the next column tile's first run
+            last = tile_group.shape[0] - 1
+            nxt = jax.lax.while_loop(
+                lambda t: (t < live)
+                & (tile_group[jnp.minimum(t, last)] == expert),
+                lambda t: t + 1, i + 1)
+            wraps = nxt >= live
+
+            # asked for BEFORE this run's block is waited for (the other
+            # slot's run ended a grid step ago), so the copy engine always
+            # has a block queued; in the background queue, so the next
+            # grid step's x tile does not wait behind it
+            @pl.when(~wraps | (j + 1 < pl.num_programs(0)))
+            def _():
+                block_copy(tile_group[jnp.where(wraps, 0, nxt)],
+                           jnp.where(wraps, j + 1, j),
+                           1 - here).start(priority=1)
+
+            block_copy(expert, j, here).wait()
+
         x = x_ref[...]
         if activation is not None and not transpose_rhs:
             x = activation.value(x.astype(jnp.float32)).astype(x.dtype)
         contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
         out = jax.lax.dot_general(
-            x, w_ref[...], (contract, ((), ())),
+            x, w_buf[slot[0]], (contract, ((), ())),
             preferred_element_type=jnp.float32)
         if saved:
             out = out * activation.derivative(
@@ -273,6 +340,7 @@ def _plan(x, n, transpose_rhs, block_m, block_n, activation, form):
         block_n=block_n, row_tiles=rows // block_m, column_tiles=n // block_n,
         transpose=transpose_rhs,
         activation=activation.name if activation else None,
+        weight_prefetch="run", weight_slots=WEIGHT_SLOTS,
         dtype=jnp.dtype(x.dtype).name, form=form)
 
 
@@ -294,26 +362,30 @@ def _gmm(x, w, tile_group, live_tiles, saved, transpose_rhs, block_m, block_n,
 
     x_spec = pl.BlockSpec(
         (block_m, k), lambda j, i, tg, lt: (row_tile(j, i, tg, lt), 0))
-    if transpose_rhs:   # w [E, n, k]: contract over its last axis
-        w_spec = pl.BlockSpec((None, block_n, k),
-                              lambda j, i, tg, lt: (tg[i], j, 0))
-    else:               # w [E, k, n]
-        w_spec = pl.BlockSpec((None, k, block_n),
-                              lambda j, i, tg, lt: (tg[i], 0, j))
+    # w [E, n, k] (transposed: contract over its last axis) or [E, k, n]
+    # stays where it is; the kernel copies a block a run into its two slots
+    block = (block_n, k) if transpose_rhs else (k, block_n)
     o_spec = pl.BlockSpec(
         (block_m, block_n), lambda j, i, tg, lt: (row_tile(j, i, tg, lt), j))
-    operands, in_specs = [x, w], [x_spec, w_spec]
+    operands, in_specs = [x, w], [x_spec, pl.BlockSpec(memory_space=pl.ANY)]
     if saved is not None:   # tiled like the output it scales
         operands, in_specs = operands + [saved], in_specs + [o_spec]
     return pl.pallas_call(
         functools.partial(_kernel, transpose_rhs=transpose_rhs,
-                          activation=activation),
+                          activation=activation, block_n=block_n),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(n // block_n, rows // block_m),
-            in_specs=in_specs, out_specs=o_spec),
+            in_specs=in_specs, out_specs=o_spec,
+            scratch_shapes=[
+                pltpu.VMEM((WEIGHT_SLOTS, *block), w.dtype),
+                pltpu.SemaphoreType.DMA((WEIGHT_SLOTS,)),
+                pltpu.SMEM((1,), jnp.int32)]),   # the current run's slot
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+        # both axes carry state: the slots and what is in flight pass from
+        # row tile to row tile and from a column tile's last run to the
+        # next one's first, so neither may be split over cores
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="moe_gmm_t" if transpose_rhs else "moe_gmm",

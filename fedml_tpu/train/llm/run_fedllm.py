@@ -169,7 +169,10 @@ class FedLLMAPI:
         assignments that reached no row in some layer (a dropless layer
         reads 0); ``live_share`` the share of (step, layer, held expert)
         triples in which the expert got a token — a step's grouped products
-        read only those experts' matrices."""
+        read only those experts' matrices; ``tiles_per_run`` the live row
+        tiles (``moe_tiles`` ``[expert layers]``, summed like ``moe_live``)
+        over those triples: how many of a grouped product's tile products
+        an expert's weight copy has to hide under."""
         static = cfg.moe_static
         counts = stats["moe_tokens"]
         layers, held = counts.shape
@@ -190,6 +193,8 @@ class FedLLMAPI:
                 (counts.max(axis=1) * held / np.maximum(per_layer, 1)).max()),
             live_share=float(
                 stats["moe_live"].sum() / (steps * layers * held)),
+            tiles_per_run=float(
+                stats["moe_tiles"].sum() / max(stats["moe_live"].sum(), 1)),
             dropped=int((here - placed).max()))
 
     def train_one_round(self, round_idx: int) -> Dict:

@@ -469,6 +469,8 @@ def test_the_fused_round_of_a_tiny_glm_is_the_host_loops():
         engine.batch_size * engine.seq_len)
     assert 1.0 <= attrs["max_over_mean"] <= cfg.n_routed_experts
     assert 0.0 < attrs["live_share"] <= 1.0
+    assert 1.0 <= attrs["tiles_per_run"] \
+        <= attrs["capacity_rows"] / cfg.moe_block_rows
     assert any(r["name"] == "mla/plan" for r in records)
     names = [r["name"] for r in records]
     assert names.index("round/1/wait") < names.index("round/1/moe") \

@@ -176,6 +176,43 @@ def test_held_top_k_grouped_matmul_compiles_for_v5e(one_chip):
     assert f"[{m * k},{m * k}]" not in text
 
 
+def test_gated_top_k_grouped_matmul_compiles_for_v5e(one_chip):
+    """The four grouped-product shapes of ``glm-4.7-flash.round-4k``: 4,096
+    tokens, 4 choices a token of 64 experts, all held, 64-row tiles, 2048
+    into 1536 and back (column tiles of 768 and 1024) and both row
+    gradients — runs of four and five tiles, so the kernels' two weight
+    slots and the copy a run ahead are what compiles here, each kernel
+    still under its own name."""
+    from fedml_tpu.ops import grouped_matmul as gmm
+
+    m, k, hid, mid, e, bm = 4096, 4, 2048, 1536, 64, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, up, down, chosen):
+        layout = gmm.group_layout(chosen, e, bm)
+        product = lambda a, w: gmm.grouped_matmul(
+            a, w, layout, block_m=bm, interpret=False)
+        out = product(product(gmm.dispatch(x, layout), up), down)
+        return gmm.combine(out, layout).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        sds((m, hid), jnp.bfloat16), sds((e, hid, mid), jnp.bfloat16),
+        sds((e, mid, hid), jnp.bfloat16), sds((m, k), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(c.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                  for c in calls) == ["moe_gmm"] * 2 + ["moe_gmm_t"] * 2
+    rows = gmm.padded_rows(m * k, e, bm)
+    assert rows == 20416
+    assert sorted(re.search(r" = \(?(bf16\[\d+,\d+\])", c).group(1)
+                  for c in calls) == sorted(
+        [f"bf16[{rows},{mid}]", f"bf16[{rows},{hid}]"] * 2)
+    # no transposed copy of an expert stack (403 MB) for the backward
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
 def test_chunked_scan_compiles_for_v5e(one_chip):
     """``ops/ssd.py`` at the Mamba-2 widths of the same cell (128 heads x
     64, 8 groups, state 128, chunks of 128 at T2048), forward and backward

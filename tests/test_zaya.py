@@ -173,14 +173,23 @@ def test_a_herded_router_drops_nothing():
 
 
 @pytest.mark.parametrize("sizes", [
-    [6, 3, 4, 23, 4], [0, 40, 0, 0, 0], [8, 8, 8, 8, 8], [1, 0, 0, 0, 39]],
-    ids=["uneven", "one_expert", "whole_tiles", "ends"])
+    [6, 3, 4, 23, 4], [0, 40, 0, 0, 0], [8, 8, 8, 8, 8], [1, 0, 0, 0, 39],
+    [40, 33, 5, 64], [8, 0, 17, 1, 0, 32, 9], [24, 0, 0, 0, 0, 0, 0, 0]],
+    ids=["uneven", "one_expert", "whole_tiles", "ends", "long_runs",
+         "mixed_runs", "long_dead_tail"])
 @pytest.mark.parametrize("interpret", [None, True],
                          ids=["reference", "interpreter"])
 def test_grouped_product_and_its_row_gradient(sizes, interpret):
     """``moe_gmm`` (the Pallas kernel under the interpreter, and the XLA
     form the CPU gets) against a gather of each row's own matrix: values,
-    and the gradient with respect to the rows (the experts are frozen)."""
+    and the gradient with respect to the rows (the experts are frozen).
+    Three column tiles (48 columns in tiles of 16) in every case, so a
+    run's weight block is asked for during the run before it, the first
+    run's of a column tile during the last run of the one before:
+    ``long_runs`` has runs of 5, 5, 1 and 8 row tiles, ``mixed_runs`` of
+    1, 3, 1, 4 and 2 with experts that got nothing between them,
+    ``one_expert`` one live run only, ``long_dead_tail`` 3 live tiles
+    before 7 dead ones."""
     rng = np.random.default_rng(0)
     e, k, n, bm = len(sizes), 32, 48, 8
     expert = jnp.asarray(rng.permutation(np.repeat(np.arange(e), sizes)),
@@ -200,6 +209,31 @@ def test_grouped_product_and_its_row_gradient(sizes, interpret):
     got = jax.grad(lambda x: jnp.sum(jnp.sin(routed(x))))(x)
     want = jax.grad(lambda x: jnp.sum(jnp.sin(plain(x))))(x)
     np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("tiles,live,want", [
+    ([[9, 0], [4, 5]], [[2, 0], [1, 3]], 3.0),   # 18 tiles in 6 runs
+    ([[286] * 5] * 16, [[64] * 5] * 16, 286 / 64),
+    ([[0, 0]], [[0, 0]], 0.0)],                  # nothing held: no run
+    ids=["uneven", "glm_cell", "no_run"])
+def test_the_moe_event_says_how_many_tiles_a_weight_copy_hides_under(
+        tiles, live, want):
+    """``tiles_per_run`` of ``round/<n>/moe`` from hand-made counts (here
+    still ``[steps, layers]``; the round sums them over its steps): the
+    live row tiles over the (step, layer, expert) triples whose expert got
+    a row."""
+    from fedml_tpu.train.llm.run_fedllm import FedLLMAPI
+
+    tiles, live = np.asarray(tiles), np.asarray(live)
+    steps, layers = tiles.shape
+    cfg = ZayaConfig.tiny()
+    stats = {"moe_tokens": np.ones((layers, cfg.num_experts), np.int64),
+             "moe_live": live.sum(0), "moe_tiles": tiles.sum(0)}
+    telemetry.reset_tracer()
+    FedLLMAPI._moe_event(3, stats, 64 * steps, steps, cfg)
+    (event,) = [r for r in telemetry.get_tracer().records()
+                if r["name"] == "round/3/moe"]
+    assert event["attrs"]["tiles_per_run"] == pytest.approx(want)
 
 
 def test_the_yaml_names_the_model():
@@ -281,6 +315,8 @@ def test_the_fused_round_of_a_tiny_zaya_is_the_host_loops():
         engine.batch_size * engine.seq_len)
     assert 1.0 <= moe["attrs"]["max_over_mean"] <= cfg.num_experts
     assert 1 / cfg.num_experts <= moe["attrs"]["live_share"] <= 1.0
+    assert 1.0 <= moe["attrs"]["tiles_per_run"] \
+        <= moe["attrs"]["capacity_rows"] / cfg.moe_block_rows
     names = [r["name"] for r in records]
     assert names.index("round/1/wait") < names.index("round/1/moe") \
         < names.index("round/1/run")

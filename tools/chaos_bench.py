@@ -35,21 +35,27 @@ def _seam_s(mgr, make_msg, n: int) -> float:
     (here: no-op) transport call."""
     from fedml_tpu.core.distributed.message import Message
 
-    msgs = [make_msg() for _ in range(n)]
     noop = lambda: None
     retry_on = mgr._retry_on
-    t0 = time.perf_counter()
-    for m in msgs:
-        if m.get(Message.MSG_ARG_KEY_MSG_ID) is None:
-            m.add_params(Message.MSG_ARG_KEY_MSG_ID,
-                         mgr._msg_id_prefix + str(next(mgr._send_seq)))
-        if mgr._chaos is not None:  # pragma: no cover - production: None
-            mgr._chaos.on_send(m)
-        try:
-            noop()
-        except retry_on:  # pragma: no cover - noop never raises
-            pass
-    return time.perf_counter() - t0
+    # the least of three: the loop lasts about a millisecond, and one pause
+    # of the collector in a process with a large heap (a test worker that
+    # ran JAX models before) is several times that and not the seam's cost
+    best = float("inf")
+    for _ in range(3):
+        msgs = [make_msg() for _ in range(n)]
+        t0 = time.perf_counter()
+        for m in msgs:
+            if m.get(Message.MSG_ARG_KEY_MSG_ID) is None:
+                m.add_params(Message.MSG_ARG_KEY_MSG_ID,
+                             mgr._msg_id_prefix + str(next(mgr._send_seq)))
+            if mgr._chaos is not None:  # pragma: no cover - production: None
+                mgr._chaos.on_send(m)
+            try:
+                noop()
+            except retry_on:  # pragma: no cover - noop never raises
+                pass
+        best = min(best, time.perf_counter() - t0)
+    return best
 
 
 def bench_send_overhead(n: int = 20_000) -> dict:
