@@ -1,0 +1,5 @@
+from benchmarks.metrics.setup_spans import stage_s
+
+
+def read(ctx):
+    return stage_s(ctx, "trace")

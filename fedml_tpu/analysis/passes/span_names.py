@@ -32,6 +32,9 @@ _ROUND_SHAPE = re.compile(
 # compression spans are exactly the two codec phases — anything else
 # under compress/ is taxonomy drift
 _COMPRESS_SHAPE = re.compile(r"^compress/(?:encode|decode)$")
+# a cataloged program's first call is exactly its three stages
+# (profiling/catalog.py); the program's name rides the `program` attr
+_PROGRAM_SHAPE = re.compile(r"^program/(?:trace|lower|compile)$")
 # run-health namespaces: one segment after the prefix, per-entity
 # dimensions (client id, phase) ride LABELS, never the name — and memory
 # readings are instantaneous by definition, so mem/* must be gauges
@@ -185,6 +188,10 @@ def _check_structured(entries) -> List[Tuple[str, int, str]]:
             if not _COMPRESS_SHAPE.match(name):
                 bad(f"span {name!r} must be compress/encode "
                     "or compress/decode")
+        if kind == "span" and name.startswith("program/"):
+            if not _PROGRAM_SHAPE.match(name):
+                bad(f"span {name!r} must be program/trace, "
+                    "program/lower or program/compile")
         if kind == "span" and name.startswith(
                 ("mem/", "health/", "resilience/", "tier/", "live/",
                  "secagg/", "profile/", "sched/", "integrity/",

@@ -17,10 +17,10 @@ Each sample lands three ways:
   its memory-growth slope over;
 - the flight-recorder ring, so a crash dump shows where memory stood.
 
-XLA compile-cache behaviour rides the same module: ``jax.monitoring``
-listeners count compilation-cache hit/miss/request events
-(``jax/compile_cache_*``; actual compiles are already the
-``jax/compile_ms`` histogram's count), so
+XLA compile-cache behaviour is counted beside it: the process's one
+``jax.monitoring`` listener (``spans.install_jax_compile_listener``)
+counts compilation-cache hit/miss/request events (``jax/compile_cache_*``;
+actual compiles are the ``jax/compile_ms`` histogram's count), so
 "round N recompiled" shows up as a counter step, not a mystery stall.
 """
 from __future__ import annotations
@@ -32,6 +32,7 @@ from typing import Any, Dict, Optional
 
 from fedml_tpu.telemetry import flight_recorder
 from fedml_tpu.telemetry.registry import get_registry
+from fedml_tpu.telemetry.spans import install_jax_compile_listener
 
 __all__ = [
     "DeviceStatsSampler",
@@ -42,39 +43,14 @@ __all__ = [
 
 _PAGE_SIZE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
-_cache_counters_installed = False
-_cache_counters_lock = threading.Lock()
-
 
 def install_compile_cache_counters() -> None:
-    """Count XLA compiles and compilation-cache traffic as typed counters.
-
-    Installed once per process. The jax compilation-cache events are
-    matched by substring so hits/misses/requests each land in their own
-    counter (a miss is an entry written; a hit is a compile skipped).
-    (The number of actual backend compiles is already the ``count`` of
-    the ``jax/compile_ms`` histogram the span layer maintains — no
-    second duration listener needed.)
-    """
-    global _cache_counters_installed
-    with _cache_counters_lock:
-        if _cache_counters_installed:
-            return
-        try:
-            import jax.monitoring
-        except ImportError:  # pragma: no cover - jax is a hard dep in-tree
-            return
-
-        def _on_event(event: str, **kw) -> None:
-            if "cache_hit" in event:
-                get_registry().counter("jax/compile_cache_hits").inc()
-            elif "cache_miss" in event:
-                get_registry().counter("jax/compile_cache_misses").inc()
-            elif "compilation_cache" in event:
-                get_registry().counter("jax/compile_cache_requests").inc()
-
-        jax.monitoring.register_event_listener(_on_event)
-        _cache_counters_installed = True
+    """Count compilation-cache hits/misses/requests as typed counters
+    (``jax/compile_cache_*``; a miss is an entry written, a hit a compile
+    skipped). Kept for its callers: it installs the process's one compile
+    listener, :func:`~fedml_tpu.telemetry.spans.install_jax_compile_listener`,
+    which counts them (idempotent)."""
+    install_jax_compile_listener()
 
 
 def _host_rss_bytes() -> float:

@@ -8,12 +8,16 @@ the serving decode/prefill family) registers under a stable name via
 :func:`wrap_jit`; the returned :class:`CatalogedProgram` then OWNS
 execution:
 
-- first call per input signature: ``jitted.lower(*args).compile()`` —
-  exactly ONE backend compile (the jit path and the AOT path do not share
-  an executable cache, so letting both run would double-compile), and
-  the executable's ``cost_analysis()`` FLOPs / bytes-accessed plus
-  ``memory_analysis()`` argument/output/temp HBM come free off the same
-  object;
+- first call per input signature: ``jitted.trace(*args)``, ``.lower()``,
+  ``.compile()`` — each stage a span of the process tracer
+  (``program/trace``, ``program/lower``, ``program/compile``, attribute
+  ``program=<name>``; the last also ``cache`` = ``hit`` / ``miss`` /
+  ``off`` for the persistent compilation cache), children of whatever
+  span is open — and exactly ONE backend compile (the jit path and the
+  AOT path do not share an executable cache, so letting both run would
+  double-compile); the executable's ``cost_analysis()`` FLOPs /
+  bytes-accessed plus ``memory_analysis()`` argument/output/temp HBM
+  come free off the same object;
 - subsequent calls: a last-used fastpath straight into the compiled
   executable. ``Compiled.__call__`` validates pytree + avals itself and
   raises ``TypeError`` *before* dispatch (donated buffers still alive),
@@ -25,11 +29,12 @@ A call whose signature the AOT staging API rejects (``TypeError``) falls
 back permanently to the raw jitted callable for that signature; a
 compiler error is raised to the caller, once. The
 catalog records the fallback and the program still gets compile-time
-attribution via the ``jax.monitoring`` listener (compiles that fire while
-a cataloged call is on this thread's stack are booked to that program;
+attribution via the process's one ``jax.monitoring`` listener
+(``spans.install_jax_compile_listener``: compiles that fire while a
+cataloged call is on this thread's stack are booked to that program;
 all others land in ``uncataloged``, so
 ``sum(per-program compile events) + uncataloged == jax/compile_ms count``
-holds exactly).
+holds exactly — the histogram and this booking are one listener).
 
 Snapshots persist as ``<run_dir>/programs.jsonl`` (one line per program,
 rewritten whole at each flush) and as ``profile/*`` registry instruments
@@ -96,8 +101,8 @@ class ProgramRecord:
         self.temp_bytes = 0.0
         self.peak_hbm_bytes = 0.0   # max over variants of arg+out+temp
         self.generated_code_bytes = 0.0
-        self.compile_ms = 0.0       # attributed backend-compile wall (listener)
-        self.compile_wall_ms = 0.0  # measured lower+compile wall (AOT path)
+        self.compile_ms = 0.0       # backend compile-or-load (listener)
+        self.compile_wall_ms = 0.0  # program/{trace,lower,compile} spans' sum
         self.compile_events = 0     # backend_compile events booked here
         self.n_signatures = 0       # distinct compiled input signatures
         self.calls = 0
@@ -395,11 +400,20 @@ class CatalogedProgram:
 
     def _compile_variant(self, key: Tuple, args: Sequence[Any],
                          kwargs: Dict[str, Any]) -> _Variant:
+        from fedml_tpu.telemetry.spans import get_tracer
+
         rec = self.record
         statics = tuple((i, args[i]) for i in self._static if i < len(args))
-        t0 = time.perf_counter()
+        tracer, name = get_tracer(), self._name
         try:
-            compiled = self._jitted.lower(*args, **kwargs).compile()
+            with tracer.span("program/trace", program=name) as trace_s:
+                traced = self._jitted.trace(*args, **kwargs)
+            with tracer.span("program/lower", program=name) as lower_s:
+                lowered = traced.lower()
+            # the compile listener turns "off" into "miss" or "hit"
+            with tracer.span("program/compile", program=name,
+                             cache="off") as compile_s:
+                compiled = lowered.compile()
         except TypeError as e:
             # AOT staging rejected the call's signature (an argument the
             # staged API cannot take) — fall back to the raw jit forever.
@@ -412,7 +426,8 @@ class CatalogedProgram:
                 self._variants[key] = variant
                 rec.analysis_error = f"{type(e).__name__}: {e}"[:200]
             return variant
-        wall_ms = (time.perf_counter() - t0) * 1e3
+        wall_ms = (trace_s.duration_ms + lower_s.duration_ms
+                   + compile_s.duration_ms)
         variant = _Variant(compiled=compiled, statics=statics)
         self._analyze(compiled, variant)
         try:
@@ -482,7 +497,9 @@ class ProgramCatalog:
         self.uncataloged_compile_ms = 0.0
         self._pump_t0: Optional[float] = None
         self._pump_flops = 0.0
-        _install_compile_listener()
+        from fedml_tpu.telemetry.spans import install_jax_compile_listener
+
+        install_jax_compile_listener()
 
     # -- registration -----------------------------------------------------
     def _record(self, name: str, multi_shape: bool = False) -> ProgramRecord:
@@ -508,7 +525,7 @@ class ProgramCatalog:
         with self._lock:
             return self._programs.get(name)
 
-    # -- compile attribution (jax.monitoring) ------------------------------
+    # -- compile attribution (spans' jax.monitoring listener) --------------
     def on_compile_event(self, ms: float) -> None:
         name = _PROGRAM_VAR.get()
         if name is None:
@@ -639,39 +656,14 @@ class ProgramCatalog:
 
 _catalog: Optional[ProgramCatalog] = None
 _catalog_lock = threading.Lock()
-_listener_installed = False
-_listener_lock = threading.Lock()
 
 
-def _install_compile_listener() -> None:
-    """Book backend-compile events to the cataloged program on this
-    thread's stack (installed once per process; reads the CURRENT global
-    catalog at event time so registry/test resets stay honest)."""
-    global _listener_installed
-    with _listener_lock:
-        if _listener_installed:
-            return
-        try:
-            import jax.monitoring
-        except ImportError:  # pragma: no cover - jax is a hard dep in-tree
-            return
-        # the jax/compile_ms histogram listener must observe the SAME
-        # event stream, or the exact accounting invariant
-        # (hist.count == attributed + uncataloged) breaks when a tracer
-        # is constructed later than the first cataloged program
-        from fedml_tpu.telemetry.spans import install_jax_compile_listener
-
-        install_jax_compile_listener()
-
-        def _on_duration(event: str, duration_secs: float, **kw) -> None:
-            if "backend_compile" not in event:
-                return
-            cat = _catalog
-            if cat is not None:
-                cat.on_compile_event(duration_secs * 1e3)
-
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-        _listener_installed = True
+def book_compile(ms: float) -> None:
+    """The compile listener's booking into the CURRENT global catalog
+    (read at event time, so registry/test resets stay honest)."""
+    cat = _catalog
+    if cat is not None:
+        cat.on_compile_event(ms)
 
 
 def get_catalog() -> ProgramCatalog:

@@ -286,7 +286,12 @@ def build_report(run_dir) -> Dict:
     }
 
     # -- compile vs execute ----------------------------------------------
-    compile_ms = sum(s.get("compile_ms", 0.0) for s in spans)
+    # a program/* stage span already reports to the span it ran under
+    # (telemetry/spans.py): count each stage there, once
+    outer = [s for s in spans if not s.get("name", "").startswith("program/")]
+    compile_ms = sum(s.get("compile_ms", 0.0) for s in outer)
+    trace_ms = sum(s.get("trace_ms", 0.0) for s in outer)
+    lower_ms = sum(s.get("lower_ms", 0.0) for s in outer)
     round_total = sum(r["wall_ms"] for r in round_rows)
 
     # -- comm bytes (latest snapshot per metric name+labels) --------------
@@ -427,8 +432,11 @@ def build_report(run_dir) -> Dict:
         "phases": phase_rows,
         "stragglers": stragglers,
         "stage_overlap": stage_overlap,
+        "trace_ms": trace_ms,
+        "lower_ms": lower_ms,
         "compile_ms": compile_ms,
-        "execute_ms": max(round_total - compile_ms, 0.0),
+        "execute_ms": max(round_total - trace_ms - lower_ms - compile_ms,
+                          0.0),
         "comm_bytes": comm,
         "compression": compression,
         "client_health": client_health,
@@ -474,7 +482,12 @@ def format_report(report: Dict) -> str:
         add(f"  overall overlap ratio: {overlap['ratio']:.2f}")
     if report["compile_ms"]:
         add("")
-        add(f"jax compile-vs-execute: compile {report['compile_ms']:.1f} ms, "
+        staged = ""
+        if report["trace_ms"] or report["lower_ms"]:
+            staged = (f"trace {report['trace_ms']:.1f} ms, "
+                      f"lower {report['lower_ms']:.1f} ms, ")
+        add(f"jax compile-vs-execute: {staged}"
+            f"compile {report['compile_ms']:.1f} ms, "
             f"execute {report['execute_ms']:.1f} ms")
     if report["stragglers"]:
         add("")

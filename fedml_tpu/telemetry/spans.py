@@ -17,10 +17,15 @@ Span naming follows the taxonomy ``round/<n>[/client/<id>]/<phase>`` for
 round work and ``<subsystem>/<what>`` elsewhere; ``tools/
 check_span_names.py`` lints the instrumented literals.
 
-JAX compile-vs-execute split: a ``jax.monitoring`` duration listener
-attributes backend-compile seconds to whatever span is open when XLA
-compiles, so a span's ``compile_ms`` attr separates "first round pays the
-bridge" from steady-state execution.
+JAX compile-vs-execute split: one ``jax.monitoring`` listener
+(:func:`install_jax_compile_listener`) attributes backend compile-or-load
+seconds to whatever span is open when XLA compiles, so a span's
+``compile_ms`` separates "first round pays the bridge" from steady-state
+execution. A cataloged program's first call runs as three child spans,
+``program/trace``, ``program/lower`` and ``program/compile``
+(``profiling/catalog.py``); their times roll up into the span they ran
+under as ``trace_ms``, ``lower_ms`` and ``compile_ms``, and its
+``execute_ms`` is what is left.
 
 One clock with the device trace: ``Tracer.span`` also opens a
 ``jax.profiler.TraceAnnotation`` of the same name for the same interval,
@@ -77,16 +82,19 @@ _current: "contextvars.ContextVar[Optional[_ActiveSpan]]" = contextvars.ContextV
 class _ActiveSpan:
     """Mutable in-flight span; becomes an immutable record at end()."""
 
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "started",
-                 "started_mono", "attrs", "remote_parent", "placeholder",
-                 "compile_ms")
+    __slots__ = ("name", "trace_id", "span_id", "parent_id", "parent",
+                 "started", "started_mono", "attrs", "remote_parent",
+                 "placeholder", "compile_ms", "trace_ms", "lower_ms",
+                 "duration_ms")
 
     def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
-                 remote_parent: bool, attrs: Dict[str, Any]):
+                 remote_parent: bool, attrs: Dict[str, Any],
+                 parent: "Optional[_ActiveSpan]" = None):
         self.name = name
         self.trace_id = trace_id
         self.span_id = uuid.uuid4().hex[:16]
         self.parent_id = parent_id
+        self.parent = parent
         # wall clock for human-readable placement, monotonic for durations:
         # an NTP step mid-run shifts `started` but cannot corrupt the
         # measured length of the span
@@ -95,7 +103,12 @@ class _ActiveSpan:
         self.attrs = attrs
         self.remote_parent = remote_parent
         self.placeholder = False
+        # backend compile-or-load: booked while this span is innermost, or
+        # rolled up from a program/compile child; the children's durations
         self.compile_ms = 0.0
+        self.trace_ms = 0.0
+        self.lower_ms = 0.0
+        self.duration_ms: Optional[float] = None  # set at end()
 
     def context(self) -> TraceContext:
         return TraceContext(self.trace_id, self.span_id)
@@ -181,14 +194,56 @@ def unwrap_frame_body(body: bytes):
 _jax_listener_installed = False
 _jax_listener_lock = threading.Lock()
 
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kw) -> None:
+    # JAX's backend_compile event wraps compile_or_get_cached: on a warm
+    # cache it times the executable's LOAD, not a compile
+    if "backend_compile" not in event:
+        return
+    ms = duration_secs * 1e3
+    get_registry().histogram("jax/compile_ms").observe(ms)
+    span = _current.get()
+    if span is not None:
+        span.compile_ms += ms
+    # the catalog books it to the program whose call is on this stack
+    from fedml_tpu.telemetry.profiling.catalog import book_compile
+
+    book_compile(ms)
+
+
+def _on_jax_event(event: str, **kw) -> None:
+    # a miss is an entry written; a hit is a compile skipped
+    if "cache_hit" in event:
+        get_registry().counter("jax/compile_cache_hits").inc()
+    elif "cache_miss" in event:
+        get_registry().counter("jax/compile_cache_misses").inc()
+    elif "compilation_cache" in event:
+        get_registry().counter("jax/compile_cache_requests").inc()
+    span = _current.get()
+    if span is not None and span.name == "program/compile":
+        # JAX records cache_misses only for an entry it writes, so "miss"
+        # is a request that no hit followed: asked, then compiled
+        if event == _CACHE_REQUEST:
+            span.attrs["cache"] = "miss"
+        elif event == _CACHE_HIT:
+            span.attrs["cache"] = "hit"
+
 
 def install_jax_compile_listener() -> None:
-    """Attribute XLA backend-compile time to the currently open span.
+    """The process's one ``jax.monitoring`` listener over compiles (a
+    duration callback and an event callback).
 
-    Installed once per process, lazily on first Tracer construction; the
-    listener is a few ns when no compile happens and writes into both the
-    active span (``compile_ms`` attr) and the global ``jax/compile_ms``
-    histogram.
+    Installed once per process (lazily, by the first Tracer, catalog or
+    device-stats sampler); a few ns when no compile happens. Each backend
+    compile-or-load lands in the open span's ``compile_ms``, the
+    ``jax/compile_ms`` histogram and the catalog record of the program on
+    the caller's stack; each compilation-cache event in the
+    ``jax/compile_cache_{hits,misses,requests}`` counters and, under a
+    ``program/compile`` span, its ``cache`` attribute (``hit``, ``miss``,
+    or ``off`` where the cache was not asked).
     """
     global _jax_listener_installed
     with _jax_listener_lock:
@@ -198,17 +253,8 @@ def install_jax_compile_listener() -> None:
             import jax.monitoring
         except ImportError:  # pragma: no cover - jax is a hard dep in-tree
             return
-
-        def _on_duration(event: str, duration_secs: float, **kw) -> None:
-            if "backend_compile" not in event:
-                return
-            ms = duration_secs * 1e3
-            get_registry().histogram("jax/compile_ms").observe(ms)
-            span = _current.get()
-            if span is not None:
-                span.compile_ms += ms
-
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        jax.monitoring.register_event_listener(_on_jax_event)
         _jax_listener_installed = True
 
 
@@ -263,6 +309,14 @@ def _notify_span_listeners(rec: Dict) -> None:
             pass
 
 
+# a cataloged program's first call (profiling/catalog.py) is three spans
+# that report to the span they ran under: tracing and lowering by their
+# duration, compile-or-load by what the listener booked (the rest of the
+# compile span, building the executable's Python object, stays execute)
+_STAGE_TOTALS = {"program/trace": "trace_ms", "program/lower": "lower_ms",
+                 "program/compile": "compile_ms"}
+
+
 # a memory-only tracer keeps this many of its newest records (a fused LLM
 # round leaves six spans: several hundred rounds)
 RING_RECORDS = 4096
@@ -307,7 +361,7 @@ class Tracer:
             # only the DIRECT child of an adopted remote context is marked
             # stitched; its own descendants are ordinary local spans
             span = _ActiveSpan(name, parent.trace_id, parent.span_id,
-                               parent.placeholder, attrs)
+                               parent.placeholder, attrs, parent)
         else:
             span = _ActiveSpan(name, new_trace_id(), None, False, attrs)
         return span
@@ -332,9 +386,20 @@ class Tracer:
             "ended": ended,
             "duration_ms": duration_ms,
         }
+        span.duration_ms = duration_ms
+        staged = span.trace_ms + span.lower_ms
+        if staged:
+            rec["trace_ms"] = span.trace_ms
+            rec["lower_ms"] = span.lower_ms
         if span.compile_ms:
             rec["compile_ms"] = span.compile_ms
-            rec["execute_ms"] = max(rec["duration_ms"] - span.compile_ms, 0.0)
+        if staged or span.compile_ms:
+            rec["execute_ms"] = max(
+                duration_ms - staged - span.compile_ms, 0.0)
+        total = _STAGE_TOTALS.get(span.name)
+        if total is not None and span.parent is not None:
+            took = span.compile_ms if total == "compile_ms" else duration_ms
+            setattr(span.parent, total, getattr(span.parent, total) + took)
         if span.remote_parent:
             rec["remote_parent"] = True
         if self.service:

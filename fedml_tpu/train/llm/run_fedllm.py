@@ -42,28 +42,33 @@ class FedLLMAPI:
         """``cfg``: a model family's configuration object, whose
         ``module()`` is a ``models/llm/causal_lm.py::CausalLM`` bound to
         the family's block; without one it is built from ``args`` by the
-        ``model`` they name (``models.llm.config_from_args``)."""
-        self.args = args
-        self.dataset = dataset
-        self.cfg = cfg or config_from_args(args, vocab_size=dataset.class_num)
-        # one engine serves every simulated client (params are swapped in);
-        # this is exactly the reference's sp-backend memory model
-        self.client = LLMClientTrainer(self.cfg, args, mesh=mesh)
-        self.aggregator = LLMAggregator(
-            self.cfg, args, mesh=mesh, engine=self.client.engine
-        )
-        self.global_exchange = self.aggregator.get_init_params()
-        self.test_history: List[dict] = []
-        # on_device_round: true fuses the ENTIRE round (client-switch,
-        # local steps, LoRA FedAvg) into one donated-buffer XLA program —
-        # see LLMTrainer.compile_federated_round. The trust-stack hooks
-        # intercept per-client payloads on the host, which that program
-        # bypasses, so the two are mutually exclusive by construction.
-        self.on_device = bool(getattr(args, "on_device_round", False))
-        self._fed_round = None
-        self._fed_round_key = None
-        if self.on_device:
-            self._check_no_host_hooks()
+        ``model`` they name (``models.llm.config_from_args``). Runs under
+        the span ``llm/build``, whose children are the ``program/*`` stages
+        of ``llm/init_params``."""
+        with get_tracer().span("llm/build"):
+            self.args = args
+            self.dataset = dataset
+            self.cfg = cfg or config_from_args(
+                args, vocab_size=dataset.class_num)
+            # one engine serves every simulated client (params are swapped
+            # in); this is exactly the reference's sp-backend memory model
+            self.client = LLMClientTrainer(self.cfg, args, mesh=mesh)
+            self.aggregator = LLMAggregator(
+                self.cfg, args, mesh=mesh, engine=self.client.engine
+            )
+            self.global_exchange = self.aggregator.get_init_params()
+            self.test_history: List[dict] = []
+            # on_device_round: true fuses the ENTIRE round (client-switch,
+            # local steps, LoRA FedAvg) into one donated-buffer XLA program
+            # — see LLMTrainer.compile_federated_round. The trust-stack
+            # hooks intercept per-client payloads on the host, which that
+            # program bypasses, so the two are mutually exclusive by
+            # construction.
+            self.on_device = bool(getattr(args, "on_device_round", False))
+            self._fed_round = None
+            self._fed_round_key = None
+            if self.on_device:
+                self._check_no_host_hooks()
 
     def _check_no_host_hooks(self) -> None:
         from fedml_tpu.core.dp.fedml_differential_privacy import (

@@ -131,6 +131,11 @@ def init_sharded_params(model, sample_tokens, mesh: Mesh, seed: int = 0,
             lambda: jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), ab),
             out_shardings=sh)
         return zeros_fn(), sh
-    init_fn = jax.jit(init, out_shardings=shardings)
+    from fedml_tpu.telemetry.profiling import wrap_jit
+
+    # cataloged, so its first call's trace / lower / compile-or-load are
+    # spans of their own (children of FedLLMAPI's llm/build)
+    init_fn = wrap_jit("llm/init_params",
+                       jax.jit(init, out_shardings=shardings))
     params = init_fn(key, sample_tokens)
     return unbox(params), unbox(shardings)

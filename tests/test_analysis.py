@@ -453,6 +453,29 @@ def test_span_names_shard_namespace_rules(tmp_path):
     assert "'shard/devices'" not in msgs  # the well-shaped gauge passes
 
 
+def test_span_names_program_namespace_is_three_stages(tmp_path):
+    """program/* spans are exactly a cataloged program's three stages;
+    the program's name rides the `program` attribute, never the name."""
+    repo = make_repo(tmp_path, {"fedml_tpu/t.py": """
+        def f(tracer):
+            with tracer.span("program/trace", program="llm/fused_round"):
+                pass
+            with tracer.span("program/lower"):
+                pass
+            with tracer.span("program/compile", cache="off"):
+                pass
+            with tracer.span("program/execute"):
+                pass
+            with tracer.span("program/llm/fused_round"):
+                pass
+    """})
+    msgs = [f.message for f in span_names.run(repo)]
+    assert len(msgs) == 2
+    assert all("must be program/trace, program/lower or program/compile"
+               in m for m in msgs)
+    assert any("'program/execute'" in m for m in msgs)
+
+
 def test_lint_pass_on_fixture(tmp_path):
     repo = make_repo(tmp_path, {"fedml_tpu/t.py": """
         import os

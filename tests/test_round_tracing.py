@@ -60,6 +60,8 @@ def traced_run(tmp_path_factory):
         reports = [api.train_one_round(r) for r in ROUNDS]
         jax.profiler.stop_trace()
         records = telemetry.get_tracer().records()
+        programs = {r.name: (r.compile_events, r.n_signatures)
+                    for r in telemetry.get_catalog().records()}
         text = telemetry.get_catalog().program(
             "llm/fused_round").last_compiled.as_text()
         engine = api.client.engine
@@ -80,6 +82,7 @@ def traced_run(tmp_path_factory):
                    if plane.name.startswith("/host:")
                    for line in plane.lines for ev in line.events}
     return {"api": api, "reports": reports, "records": records,
+            "programs": programs,
             "text": text, "lowered": lowered, "host_events": host_events,
             "logs": tmp / "logs"}
 
@@ -136,6 +139,53 @@ def test_compile_lands_on_the_first_dispatch_only(traced_run):
     for rnd in (first, second):
         for name in ("sample", "stage", "wait"):
             assert "compile_ms" not in rnd[name]
+
+
+STAGES = ["program/trace", "program/lower", "program/compile"]
+
+
+def _stages_under(records, parent):
+    return [(r["name"], r["attrs"]["program"]) for r in records
+            if r["name"].startswith("program/")
+            and r["parent_id"] == parent["span_id"]]
+
+
+def test_the_constructor_is_llm_build_over_the_init_program(traced_run):
+    records = traced_run["records"]
+    (build,) = [r for r in records if r["name"] == "llm/build"]
+    assert build["parent_id"] is None
+    assert _stages_under(records, build) == [
+        (s, "llm/init_params") for s in STAGES]
+    assert build["trace_ms"] + build["lower_ms"] + build.get(
+        "compile_ms", 0.0) <= build["duration_ms"]
+    assert records.index(build) < min(
+        i for i, r in enumerate(records) if r["name"] == "round/1/run")
+
+
+def test_the_round_program_stages_under_the_first_dispatch_only(traced_run):
+    records = traced_run["records"]
+    first = _round(records, ROUNDS[0])[1]["dispatch"]
+    second = _round(records, ROUNDS[1])[1]["dispatch"]
+    assert _stages_under(records, first) == [
+        (s, "llm/fused_round") for s in STAGES]
+    assert _stages_under(records, second) == []
+    (compiled,) = [r for r in records if r["name"] == "program/compile"
+                   and r["parent_id"] == first["span_id"]]
+    assert compiled["attrs"]["cache"] == "off"  # the fixture asks no cache
+    # execute_ms is the dispatch less tracing, lowering and compile-or-load
+    assert first["execute_ms"] == pytest.approx(
+        first["duration_ms"] - first["trace_ms"] - first["lower_ms"]
+        - first["compile_ms"])
+    assert first["execute_ms"] < first["duration_ms"] - first["trace_ms"]
+
+
+def test_one_listener_books_each_program_one_compile(traced_run):
+    """``(compile_events, n_signatures)`` after two rounds: the round
+    program's read (1, 1) before the listeners were folded into one (PR
+    38's tree, the same rounds) and still do; the init program's compile,
+    uncataloged before, is its own now."""
+    assert traced_run["programs"]["llm/fused_round"] == (1, 1)
+    assert traced_run["programs"]["llm/init_params"] == (1, 1)
 
 
 def test_fedllm_opens_no_second_tracer(traced_run):
