@@ -302,7 +302,7 @@ class NemotronHMamba(nn.Module):
             x = xbc[..., :d].reshape(b, t, h, p)
             bm = xbc[..., d:d + g * n].reshape(b, t, g, n)
             cm = xbc[..., d + g * n:].reshape(b, t, g, n)
-        # the scan's backward computes its inside again (ops/ssd.py): a
+        # the scan keeps its inputs alone for the backward (ops/ssd.py): a
         # layer keeps none of the [H, Q, Q] arrays
         y = ssd(x, dt, -jnp.exp(a_log), bm, cm, cfg.chunk_size)
         with jax.named_scope("ssm_gate_norm"):

@@ -1,8 +1,9 @@
 """Which form of a Pallas kernel a call gets.
 
-Both kernels in this package (flash attention, the fused int8
-dequant-matmul) exist in three forms: the compiled Mosaic kernel, the
-same kernel under the Pallas interpreter, and a plain-XLA reference.
+Every kernel in this package (flash attention, the fused int8
+dequant-matmul, the grouped expert product, the Mamba-2 scan) exists in
+three forms: the compiled Mosaic kernel, the same kernel under the Pallas
+interpreter, and a plain-XLA reference.
 The choice is made HERE, from the default backend's platform, and
 nowhere else — and device discovery is not wrapped: a machine whose
 chip cannot be found must fail, not quietly train on a reference path.
@@ -35,8 +36,9 @@ def kernel_mode(interpret: Optional[bool], off_tpu: str) -> str:
     """``COMPILED`` / ``INTERPRET`` / ``REFERENCE`` for one kernel call.
 
     ``off_tpu`` is what ``interpret=None`` means away from a TPU:
-    ``REFERENCE`` for flash attention, ``INTERPRET`` for the dequant
-    matmul (the behaviour the CPU tests rely on).
+    ``REFERENCE`` for flash attention, the grouped product and the scan,
+    ``INTERPRET`` for the dequant matmul (the behaviour the CPU tests rely
+    on).
     """
     if interpret is False:
         return COMPILED

@@ -172,24 +172,32 @@ def _scan_inputs(t, heads=4, p=3, groups=2, n=5, seed=0):
             jax.random.normal(keys[4], (2, t, groups, n)))
 
 
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["chunked_xla", "kernel"])
 @pytest.mark.parametrize("groups", [2, 4], ids=["2_heads_a_group",
                                                "1_head_a_group"])
 @pytest.mark.parametrize("t,chunk", [(16, 8), (19, 8), (5, 8), (24, 24),
                                      (33, 4)])
-def test_the_chunked_scan_is_the_sequential_recurrence(t, chunk, groups):
-    """``ops/ssd.py`` (chunks, one state a chunk, a scan over chunk
-    states) against the token-by-token loop of the reference: values, and
-    the gradient with respect to all five inputs; also at a ``T`` that is
-    not a whole number of chunks (padded inside) and under one chunk, with
-    heads that share ``B`` and ``C`` in pairs and with a group a head."""
+def test_the_chunked_scan_is_the_sequential_recurrence(t, chunk, groups,
+                                                       interpret):
+    """``ops/ssd.py`` in both forms — the chunked XLA one (chunks, one
+    state a chunk, a scan over chunk states) and the kernels under the
+    interpreter (a chunk a grid step, the state and its cotangent carried
+    in scratch) — against the token-by-token loop of the reference: values,
+    and the gradient with respect to all five inputs; also at a ``T`` that
+    is not a whole number of chunks (padded inside), under one chunk and
+    over three and nine (a state and its cotangent cross two boundaries and
+    more), with heads that share ``B`` and ``C`` in pairs and with a group
+    a head."""
     args = _scan_inputs(t, groups=groups)
     sequential = jax.vmap(ref.recurrence, in_axes=(0, 0, None, 0, 0))
-    got = ssd(*args, chunk=chunk)
+    got = ssd(*args, chunk=chunk, interpret=interpret)
     want = sequential(*args)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
     weigh = jax.random.normal(jax.random.key(9), want.shape)
-    g = jax.grad(lambda *a: jnp.sum(ssd(*a, chunk=chunk) * weigh),
-                 argnums=(0, 1, 2, 3, 4))(*args)
+    g = jax.grad(lambda *a: jnp.sum(
+        ssd(*a, chunk=chunk, interpret=interpret) * weigh),
+        argnums=(0, 1, 2, 3, 4))(*args)
     w = jax.grad(lambda *a: jnp.sum(sequential(*a) * weigh),
                  argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("x dt a b c".split(), g, w):
@@ -204,15 +212,21 @@ def test_the_scan_refuses_heads_that_no_group_divides():
         ssd(x, dt, a, b, c, chunk=4)
 
 
-def test_the_scan_leaves_its_plan():
+@pytest.mark.parametrize("interpret,form", [
+    (None, {"form": "chunked_xla", "heads_per_step": 4,
+            # the chunk states it writes: [B, chunks, H, P, N] float32
+            "state_bytes": 2 * 3 * 4 * 3 * 5 * 4}),
+    (True, {"form": "kernel", "heads_per_step": 2,
+            # the state a grid step carries in scratch: [heads a group x P, N]
+            "state_bytes": 2 * 3 * 5 * 4})], ids=["chunked_xla", "kernel"])
+def test_the_scan_leaves_its_plan(interpret, form):
     telemetry.reset_tracer()
-    ssd(*_scan_inputs(19), chunk=8)
+    ssd(*_scan_inputs(19), chunk=8, interpret=interpret)
     (plan,) = [r for r in telemetry.get_tracer().records()
                if r["name"] == "ssd/plan"]
     assert plan["point"] and plan["attrs"] == {
         "rows": 38, "heads": 4, "head_dim": 3, "groups": 2, "state": 5,
-        "chunk": 8, "chunks": 3, "form": "chunked_xla",
-        "dtype": "float32"}
+        "chunk": 8, "chunks": 3, "block_t": 8, **form, "dtype": "float32"}
     telemetry.reset_tracer()
 
 

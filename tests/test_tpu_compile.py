@@ -235,6 +235,46 @@ def test_chunked_scan_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
+def test_scan_kernels_compile_for_v5e(one_chip):
+    """The scan's kernels (``ssd_fwd``; ``ssd_states`` and ``ssd_bwd`` for
+    the backward) at the same widths, under a layer's ``mamba`` scope as
+    the model calls them: fewer temporaries than the chunked form compiled
+    beside them, no float32 ``[..., 128, 128]`` array (an ``[H, Q, Q]``
+    decay) anywhere in the module, and every kernel, forward and backward,
+    under ``mamba/jit(_ssd)`` in its ``op_name`` — the part
+    ``ssm_scan_time_pct`` and ``ssd_roofline`` read."""
+    from fedml_tpu.ops.ssd import ssd
+
+    t, h, p, g, n = 2048, 128, 64, 8, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled(interpret):
+        def loss(x, dt, a, b, c):
+            with jax.named_scope("layer_0"), jax.named_scope("mamba"):
+                y = ssd(x, dt, a, b, c, chunk=128, interpret=interpret)
+            return y.astype(jnp.float32).sum()
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            sds((1, t, h, p), jnp.bfloat16), sds((1, t, h), jnp.float32),
+            sds((h,), jnp.float32), sds((1, t, g, n), jnp.bfloat16),
+            sds((1, t, g, n), jnp.bfloat16)).compile()
+
+    kernels, chunked = compiled(False), compiled(None)
+    assert (kernels.memory_analysis().temp_size_in_bytes
+            < chunked.memory_analysis().temp_size_in_bytes)
+    text = kernels.as_text()
+    assert re.search(r"f32\[[\d,]*128,128\]", chunked.as_text())
+    assert not re.search(r"f32\[[\d,]*128,128\]", text)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(c.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                  for c in calls) == ["ssd_bwd", "ssd_fwd", "ssd_states"]
+    for call in calls:
+        op_name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert re.search(r"/mamba/jit\(_ssd\)", op_name), op_name
+
+
 def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2,
                          cfg=None, seq_len=512):
     """``llm/fused_round`` at the 7B widths (or for ``cfg``, another
