@@ -1,10 +1,11 @@
-"""What every causal-LM family of the LLM path is built from: RMSNorm,
-rotary tables and their application, a dense layer with an optional
-low-rank adapter (its base may be stored quantized), the choice of the
-causal attention product, the SwiGLU of a dense FFN, and what the families
-with routed experts share (a stack of expert matrices, the gated experts
-over ``ops/grouped_matmul.py``, the sigmoid top-k router). A family's file
-imports these and no other family's file."""
+"""What every causal-LM family of the LLM path is built from: RMSNorm (and
+its zero-centred form), rotary tables and their application, a dense layer
+with an optional low-rank adapter (its base may be stored quantized), the
+choice of the causal attention product, the SwiGLU of a dense FFN, and
+what the families with routed experts share (a stack of expert matrices,
+the gated experts over ``ops/grouped_matmul.py``, the sigmoid and the
+softmax top-k routers). A family's file imports these and no other
+family's file."""
 from __future__ import annotations
 
 import math
@@ -31,6 +32,25 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + self.eps)
         return (normed * scale).astype(self.dtype)
+
+
+class ZeroCenteredRMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` in float32, rounded once to
+    ``dtype``: the gain is stored as its offset from one, ``w`` (the
+    parameter ``weight``) zero at init. At ``w = 0`` it is
+    :class:`RMSNorm` at a scale of ones."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("weight", nn.initializers.zeros, (x.shape[-1],),
+                       jnp.float32)
+        x32 = x.astype(jnp.float32)
+        normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True)
+                                     + self.eps)
+        return (normed * (1.0 + w)).astype(self.dtype)
 
 
 def rope_tables(positions: jax.Array, head_dim: int, theta: float):
@@ -338,10 +358,40 @@ def sigmoid_topk(module, u, total: int, k: int, scale: float):
     return chosen, weights
 
 
+def softmax_topk(module, u, total: int, k: int):
+    """A token's ``k`` of ``total`` experts by a softmax over the router's
+    logits, under the scope ``router``; the float32 parameter
+    ``router_weight`` ``[hidden, total]`` is ``module``'s. ``p =
+    softmax(u W_r)`` over all ``total``; ``chosen [m, k]`` = the largest of
+    ``p`` (a tie goes to the lower index) and ``weights [m, total]`` =
+    ``p`` at the chosen experts over their sum (``norm_topk_prob``), zero
+    elsewhere: the same dense table as :func:`sigmoid_topk`'s, so
+    :func:`choice_weights` reads either."""
+    hid = u.shape[-1]
+    gate = module.param(
+        "router_weight", nn.with_logical_partitioning(
+            nn.initializers.normal(1.0 / math.sqrt(hid)), ("embed", None)),
+        (hid, total), jnp.float32)
+    with jax.named_scope("router"):
+        # float32 at full precision: a rounded logit is a token sent to
+        # another expert
+        p = jax.nn.softmax(jnp.matmul(
+            u.reshape(-1, hid).astype(jnp.float32), gate,
+            precision=HIGHEST), axis=-1)
+        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(p), k)   # [m, k]
+        chosen = chosen.astype(jnp.int32)
+        picked = jnp.any(
+            chosen[:, :, None] == jnp.arange(total, dtype=jnp.int32),
+            axis=1)
+        kept = jnp.where(picked, p, 0.0)
+        weights = kept / jnp.sum(kept, -1, keepdims=True)
+    return chosen, weights
+
+
 def choice_weights(chosen, weights, first: int, held: int):
-    """``[m, k]``: each choice's weight out of :func:`sigmoid_topk`'s
-    table, zero for an expert outside ``first .. first + held`` (held on
-    another chip)."""
+    """``[m, k]``: each choice's weight out of :func:`sigmoid_topk`'s or
+    :func:`softmax_topk`'s table, zero for an expert outside ``first ..
+    first + held`` (held on another chip)."""
     own = (chosen[:, :, None] - first
            == jnp.arange(held, dtype=jnp.int32))                # [m, k, Eh]
     return jnp.sum(

@@ -126,8 +126,9 @@ def _qkv(t, heads, kv_heads, d, dtype, seed=0):
 
 # T spans three q blocks or more, so a call holds blocks above, on and below
 # the diagonal; 100 and 160 are ragged, 320 ragged with sub-tiles. Heads of
-# 32 throughout, and heads of 256 (``glm4_moe_lite``'s: as many key-value
-# heads as query heads) whole and ragged
+# 32 throughout, heads of 256 (``glm4_moe_lite``'s: as many key-value
+# heads as query heads) whole and ragged, and heads of 256 with eight query
+# heads a key-value head (``qwen3_next``'s gated attention)
 PARITY = [
     # t, heads, kv_heads, block_q, block_k, d
     (512, 2, 2, 128, 256, 32),
@@ -139,6 +140,7 @@ PARITY = [
     (320, 2, 2, 128, 128, 32),
     (512, 4, 4, 128, 256, 256),
     (200, 4, 4, 64, 64, 256),
+    (512, 8, 1, 128, 256, 256),
 ]
 
 

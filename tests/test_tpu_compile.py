@@ -8,6 +8,7 @@ Everything that touches the TPU library lives in fixtures of THIS file:
 one process at a time may load libtpu, so only the xdist worker that is
 handed this file does (see /opt/skills/guides/on-chip-measurement §2).
 """
+import json
 import math
 import os
 import re
@@ -387,6 +388,52 @@ def test_fused_round_glm_moe_lite_widths_compiles_for_v5e(topo, monkeypatch):
     sorts = [line for line in text.splitlines() if " sort(" in line]
     assert len(sorts) == 1 and "/moe/router/top_k" in sorts[0]
     assert " scatter(" not in text
+
+
+def test_fused_round_qwen3_next_cell_compiles_for_v5e(topo, monkeypatch):
+    """``model: qwen3_next`` as ``qwen3-next-80b-a3b-instruct.round-4k``
+    runs it (its configuration file through the benchmark family's
+    ``model_config``: 4 layers, 128 of 512 experts held, 38,016 vocabulary
+    rows, T4096, ``remat_policy: none``): the flash kernels at 16 query
+    heads and 2 key-value heads of 256 under their VMEM limit, the grouped
+    products at 2048 x 512, the chunked delta rule with no gather or scatter
+    of its own, no sort but the four routers' top-10, and a plan that fills
+    more than 40 % of the chip and fits it."""
+    import importlib.util
+
+    from fedml_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "default_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "qwen3-next-80b-a3b-instruct.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "qwen3_next_program", os.path.join(
+            root, "benchmarks", "families", "qwen3_next", "program.py"))
+    program = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(program)
+    cfg = program.model_config(config, {"remat_policy": "none"})
+    compiled = _compile_fused_round(topo.devices, 1, cfg=cfg, seq_len=4096)
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0.4 * 16.9e9 < resident < V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes > 0.99 * mem.output_size_in_bytes
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    # one attention layer's flash kernels; four expert layers' gate, up
+    # and down products and their row gradients
+    assert sorted(calls) == sorted(
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+        + ["moe_gmm"] * 12 + ["moe_gmm_t"] * 12)
+    assert "bf16[1,16,4096,256]" in text and "bf16[1,2,4096,256]" in text
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert len(sorts) == 4 and all("/moe/router/top_k" in s for s in sorts)
+    assert " scatter(" not in text
+    assert re.search(r'op_name="[^"]*/layer_2/linear_attn/jit\(_gated_delta\)',
+                     text)
 
 
 def _unfused(text):
