@@ -119,7 +119,7 @@ class Qwen3NextConfig:
     linear_value_head_dim: int = 128
     linear_conv_kernel_dim: int = 4
     # tokens a chunk of ops/gated_delta.py (the program's, not the row's)
-    chunk_size: int = 64
+    chunk_size: int = 128
     # expert layers: ``num_experts`` are HELD here, from
     # ``held_experts_first`` on, of ``num_experts_total`` the router scores
     # (0 = all of them are held)

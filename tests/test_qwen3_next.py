@@ -24,6 +24,7 @@ from fedml_tpu.models.llm.layers import (RMSNorm, ZeroCenteredRMSNorm,
 from fedml_tpu.models.llm.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextGatedDeltaNet,
                                              Qwen3NextMoE)
+from fedml_tpu.ops import gated_delta as gd
 from fedml_tpu.ops.flash_attention import flash_attention
 from fedml_tpu.ops.gated_delta import gated_delta
 from fedml_tpu.train.llm.sharding import unbox
@@ -134,8 +135,37 @@ def _delta_inputs(t, heads=3, dk=16, dv=8, decay=(-3.0, 0.0),
 
 
 _SEQUENTIAL = jax.vmap(ref.recurrence)
+# the two forms of ops/gated_delta.py: the chunked XLA form (off a TPU, the
+# default) and the kernels under the Pallas interpreter
+_FORMS = {"chunked_xla": None, "kernel": True}
 
 
+def _assert_values_and_gradients_agree(op, args, valid=None):
+    """``op`` against the sequential loop over ``args`` (or, where
+    ``valid`` tokens are given, over their first ``valid`` only), in
+    float32: values to 2e-5 of values of order 1; the gradient with
+    respect to all five inputs to 1e-4 of each gradient's largest entry,
+    or of a thousandth of the largest entry of all five, whichever is more
+    (the five come out of the same sums, and where every decay is near 0
+    ``g``'s own gradient is of the size of their rounding)."""
+    cut = (lambda a: a[:, :valid]) if valid else (lambda a: a)
+    with jax.default_matmul_precision("highest"):
+        want = _SEQUENTIAL(*map(cut, args))
+        np.testing.assert_allclose(cut(op(*args)), want, atol=2e-5, rtol=0)
+        weigh = jax.random.normal(jax.random.key(9), want.shape)
+        g = jax.grad(lambda *a: jnp.sum(cut(op(*a)) * weigh),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+        w = jax.grad(lambda *a: jnp.sum(_SEQUENTIAL(*a) * weigh),
+                     argnums=(0, 1, 2, 3, 4))(*map(cut, args))
+    largest = max(float(jnp.abs(b).max()) for b in w)
+    for name, a, b in zip("q k v g beta".split(), g, w):
+        scale = max(float(jnp.abs(b).max()), 1e-3 * largest)
+        assert scale > 0, name
+        np.testing.assert_allclose(cut(a), b, atol=1e-4 * scale, rtol=0,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("form", list(_FORMS))
 @pytest.mark.parametrize("t,chunk", [(8, 8), (16, 8), (24, 8), (12, 4),
                                      (64, 64)],
                          ids=["1_chunk", "2_chunks", "3_chunks",
@@ -144,66 +174,86 @@ _SEQUENTIAL = jax.vmap(ref.recurrence)
     ((-3.0, 3.0), (-3.0, 3.0)), ((-6.0, -3.0), (3.0, 6.0)),
     ((2.0, 3.0), (-6.0, -3.0))],
     ids=["spread", "decay_near_1_beta_near_1", "decay_near_0_beta_near_0"])
-def test_the_chunked_delta_rule_is_the_sequential_one(t, chunk, decay, beta):
+def test_the_chunked_delta_rule_is_the_sequential_one(t, chunk, decay, beta,
+                                                      form):
     """``ops/gated_delta.py`` (chunks, the inverse by halving, one state a
-    chunk) against the token-by-token loop of the reference, in float32:
-    values, and the gradient with respect to all five inputs (float32
-    rounding: 2e-5 of values of order 1; 1e-4 of each gradient's largest
-    entry, or of a thousandth of the largest entry of all five, whichever
-    is more: the five come out of the same sums, and where every decay is
-    near 0 ``g``'s own gradient is of the size of their rounding); within
-    one chunk, across one boundary and two, over a chunk of 64 at keys of
-    128 lanes, with heads whose decay is near 1 (a state kept across
-    chunks) or near 0 (forgotten within a token) and whose beta is near 1
-    or near 0."""
+    chunk), in either form, against the token-by-token loop of the
+    reference (:func:`_assert_values_and_gradients_agree`): within one
+    chunk, across one boundary and two, over a chunk of 64 at keys of 128
+    lanes, with heads whose decay is near 1 (a state kept across chunks) or
+    near 0 (forgotten within a token) and whose beta is near 1 or near 0;
+    keys out of a silu, in one orthant."""
     wide = dict(dk=128, dv=32) if chunk == 64 else {}
     args = _delta_inputs(t, decay=decay, beta=beta, **wide)
-    with jax.default_matmul_precision("highest"):
-        got = gated_delta(*args, chunk=chunk)
-        want = _SEQUENTIAL(*args)
-        np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
-        weigh = jax.random.normal(jax.random.key(9), want.shape)
-        g = jax.grad(lambda *a: jnp.sum(gated_delta(*a, chunk=chunk) * weigh),
-                     argnums=(0, 1, 2, 3, 4))(*args)
-        w = jax.grad(lambda *a: jnp.sum(_SEQUENTIAL(*a) * weigh),
-                     argnums=(0, 1, 2, 3, 4))(*args)
-    largest = max(float(jnp.abs(b).max()) for b in w)
-    for name, a, b in zip("q k v g beta".split(), g, w):
-        scale = max(float(jnp.abs(b).max()), 1e-3 * largest)
-        assert scale > 0, name
-        np.testing.assert_allclose(a, b, atol=1e-4 * scale, rtol=0,
-                                   err_msg=name)
+    _assert_values_and_gradients_agree(
+        lambda *a: gated_delta(*a, chunk=chunk, interpret=_FORMS[form]), args)
 
 
-def test_the_inverse_by_halving_is_the_inverse():
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_a_padded_tail_changes_nothing(form):
+    """Heads of 128 lanes (what the compiled kernels tile), three chunks
+    of 16 of which the last holds 5 tokens of padding as the layer pads
+    (``q = k = v = 0``, ``g = beta = 0``): the first 43 tokens' values and
+    gradients are the sequential loop's over those 43 alone."""
+    valid, t = 43, 48
+    args = _delta_inputs(valid, heads=2, dk=128, dv=128)
+    args = [jnp.pad(a, [(0, 0), (0, t - valid)] + [(0, 0)] * (a.ndim - 2))
+            for a in args]
+    _assert_values_and_gradients_agree(
+        lambda *a: gated_delta(*a, chunk=16, interpret=_FORMS[form]), args,
+        valid=valid)
+
+
+def _inverse_in_a_kernel(a):
+    """The kernels' inverse of one ``[C, C]`` matrix, run inside a
+    ``pallas_call`` under the interpreter."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(a_ref, t_ref, work):
+        t_ref[...] = gd._vmem_inverse(a_ref[...], work)
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+        scratch_shapes=[pltpu.VMEM(a.shape, a.dtype)], interpret=True)(a)
+
+
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_the_inverse_by_halving_is_the_inverse(form):
     """``(I + A)^-1`` of a chunk of 64 correlated keys of 128 lanes with
     beta near 1, against float64's: float32 rounding (the doubling product
     ``(I - A)(I + A^2)...``, exact in exact arithmetic, misses such a chunk
     by orders of magnitude in float32: its powers of ``A`` grow before they
-    cancel); also at a chunk that is no power of two."""
-    from fedml_tpu.ops.gated_delta import _inverse
-
-    for c in (64, 12):
+    cancel); also at a chunk that is no power of two and at 128. The
+    chunked form's batched halving and the kernels' halving on whole
+    matrices alike."""
+    inverse = {"chunked_xla": lambda a: gd._inverse(a[None])[0],
+               "kernel": _inverse_in_a_kernel}[form]
+    for c in (64, 12, 128):
         _, k, _, _, _ = _delta_inputs(c, heads=1, dk=128)
         keys = np.asarray(k[0, :, 0], np.float64)
         a = 0.95 * np.tril(keys @ keys.T, -1)
         want = np.linalg.inv(np.eye(c) + a)
-        got = np.asarray(_inverse(jnp.asarray(a, jnp.float32)[None]))[0]
+        got = np.asarray(inverse(jnp.asarray(a, jnp.float32)))
         np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
 
 
-def test_the_delta_rule_in_bfloat16_stays_near_float32():
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_the_delta_rule_in_bfloat16_stays_near_float32(form):
     """The program's form: bfloat16 inputs, every product's operands in
     bfloat16 (the inverse and the carried state float32): within a few
-    bfloat16 roundings of the float32 loop over three chunks of 64."""
-    args = _delta_inputs(192, heads=4, dk=32, dv=32)
+    bfloat16 roundings of the float32 loop over three chunks of 64 (heads
+    of 128 lanes for the kernels, as they are compiled)."""
+    width = dict(heads=4, dk=32, dv=32) if form == "chunked_xla" else dict(
+        heads=2, dk=128, dv=128)
+    args = _delta_inputs(192, **width)
     low = [a.astype(jnp.bfloat16) if i < 3 else a for i, a in enumerate(args)]
-    got = gated_delta(*low, chunk=64).astype(jnp.float32)
+    out = gated_delta(*low, chunk=64, interpret=_FORMS[form])
+    got = out.astype(jnp.float32)
     want = _SEQUENTIAL(*[a.astype(jnp.float32) for a in low])
     scale = float(jnp.abs(want).max())
     assert float(jnp.abs(got - want).max()) < 2e-2 * scale
-    assert got.dtype == jnp.float32 and gated_delta(*low, chunk=64).dtype \
-        == jnp.bfloat16
+    assert out.dtype == jnp.bfloat16
 
 
 def test_the_delta_rule_refuses_a_part_of_a_chunk():
@@ -211,21 +261,36 @@ def test_the_delta_rule_refuses_a_part_of_a_chunk():
         gated_delta(*_delta_inputs(12), chunk=8)
 
 
-def test_the_mixer_leaves_its_plan(f32):
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_the_mixer_leaves_its_plan(f32, form, monkeypatch):
+    """The ``gdn/plan`` event names the form the op computes: off a TPU
+    the chunked form, which writes ``[B, chunks, value heads, dk, dv]``
+    float32 chunk states, every head at once; under the interpreter (as on
+    a TPU) the kernels, whose state is a step's heads in VMEM; and the
+    layer's output is the same either way."""
     cfg, params, tokens = f32
+    if form == "kernel":
+        monkeypatch.setattr(gd, "kernel_mode",
+                            lambda *a, **kw: gd.INTERPRET)
     telemetry.reset_tracer()
     u = jax.random.normal(jax.random.key(2), (B, T, cfg.hidden_size))
-    Qwen3NextGatedDeltaNet(cfg).apply(
+    layer = lambda: Qwen3NextGatedDeltaNet(cfg).apply(
         {"params": params["params"]["layer_0"]["linear_attn"]}, u)
+    got = layer()
     (plan,) = [r for r in telemetry.get_tracer().records()
                if r["name"] == "gdn/plan"]
-    # T 20 padded to 3 chunks of 8; the chunk states it writes: [B, chunks,
-    # value heads, dk, dv] float32
+    # T 20 padded to 3 chunks of 8; 4 value heads: one step holds them all
+    state_bytes, inverse = {
+        "chunked_xla": (B * 3 * 4 * 16 * 16 * 4, "halving"),
+        "kernel": (4 * 16 * 16 * 4, "substitution_halving")}[form]
     assert plan["point"] and plan["attrs"] == {
         "rows": B * T, "key_heads": 2, "value_heads": 4, "key_dim": 16,
-        "value_dim": 16, "chunk": 8, "chunks": 3, "form": "chunked_xla",
-        "inverse": "halving", "state_bytes": B * 3 * 4 * 16 * 16 * 4,
-        "dtype": "float32"}
+        "value_dim": 16, "chunk": 8, "chunks": 3, "form": form,
+        "heads_per_step": 4, "inverse": inverse,
+        "state_bytes": state_bytes, "dtype": "float32"}
+    monkeypatch.undo()
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(got, layer(), rtol=0, atol=2e-5)
     telemetry.reset_tracer()
 
 

@@ -276,6 +276,52 @@ def test_scan_kernels_compile_for_v5e(one_chip):
         assert re.search(r"/mamba/jit\(_ssd\)", op_name), op_name
 
 
+def test_gated_delta_kernels_compile_for_v5e(one_chip):
+    """The delta rule's kernels (``gdn_fwd``; ``gdn_states`` and
+    ``gdn_bwd`` for the backward) at the widths of
+    ``qwen3-next-80b-a3b-instruct.round-4k`` (B1 T4096, 32 value heads of
+    128, bfloat16, the model's chunk), under a layer's ``linear_attn``
+    scope as the model calls them: fewer temporaries than the chunked form
+    compiled beside them, no float32 ``[..., C, C]`` array (the chunks'
+    decays, inverses and their relayouts) anywhere in the module, and every
+    kernel, forward and backward, under ``linear_attn/jit(_gated_delta)``
+    in its ``op_name`` — the part ``gdn_scan_time_pct`` and ``gdn_roofline``
+    read."""
+    from fedml_tpu.models.llm.qwen3_next import Qwen3NextConfig
+    from fedml_tpu.ops.gated_delta import gated_delta
+
+    b, t, h, d = 1, 4096, 32, 128
+    chunk = Qwen3NextConfig().chunk_size
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled(interpret):
+        def loss(q, k, v, g, beta):
+            with jax.named_scope("layer_0"), jax.named_scope("linear_attn"):
+                o = gated_delta(q, k, v, g, beta, chunk, interpret=interpret)
+            return o.astype(jnp.float32).sum()
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *[sds((b, t, h, d), jnp.bfloat16)] * 3,
+            *[sds((b, t, h), jnp.float32)] * 2).compile()
+
+    kernels, chunked = compiled(False), compiled(None)
+    assert (kernels.memory_analysis().temp_size_in_bytes
+            < chunked.memory_analysis().temp_size_in_bytes)
+    text = kernels.as_text()
+    square = rf"f32\[[\d,]*{chunk},{chunk}\]"
+    assert re.search(square, chunked.as_text())
+    assert not re.search(square, text)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(c.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                  for c in calls) == ["gdn_bwd", "gdn_fwd", "gdn_states"]
+    for call in calls:
+        op_name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert re.search(r"/linear_attn/jit\(_gated_delta\)", op_name), \
+            op_name
+
+
 def _compile_fused_round(devices, fsdp, layers=2, clients=8, steps=2,
                          cfg=None, seq_len=512):
     """``llm/fused_round`` at the 7B widths (or for ``cfg``, another
@@ -396,8 +442,9 @@ def test_fused_round_qwen3_next_cell_compiles_for_v5e(topo, monkeypatch):
     ``model_config``: 4 layers, 128 of 512 experts held, 38,016 vocabulary
     rows, T4096, ``remat_policy: none``): the flash kernels at 16 query
     heads and 2 key-value heads of 256 under their VMEM limit, the grouped
-    products at 2048 x 512, the chunked delta rule with no gather or scatter
-    of its own, no sort but the four routers' top-10, and a plan that fills
+    products at 2048 x 512, the delta rule's three kernels in each of the
+    three Gated DeltaNet layers under ``linear_attn/jit(_gated_delta)``, no
+    sort but the four routers' top-10, no scatter, and a plan that fills
     more than 40 % of the chip and fits it."""
     import importlib.util
 
@@ -424,10 +471,17 @@ def test_fused_round_qwen3_next_cell_compiles_for_v5e(topo, monkeypatch):
     calls = [line.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for line in text.splitlines() if "tpu_custom_call" in line]
     # one attention layer's flash kernels; four expert layers' gate, up
-    # and down products and their row gradients
+    # and down products and their row gradients; three Gated DeltaNet
+    # layers' delta rule, forward and backward
     assert sorted(calls) == sorted(
         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
-        + ["moe_gmm"] * 12 + ["moe_gmm_t"] * 12)
+        + ["moe_gmm"] * 12 + ["moe_gmm_t"] * 12
+        + ["gdn_fwd", "gdn_states", "gdn_bwd"] * 3)
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " = " in line \
+                and line.split(" = ")[0].strip().lstrip("%").startswith("gdn_"):
+            assert re.search(r'op_name="[^"]*/linear_attn/jit\(_gated_delta\)',
+                             line), line
     assert "bf16[1,16,4096,256]" in text and "bf16[1,2,4096,256]" in text
     sorts = [line for line in text.splitlines() if " sort(" in line]
     assert len(sorts) == 4 and all("/moe/router/top_k" in s for s in sorts)
