@@ -4,8 +4,8 @@ object answers ``cfg.module()`` with its flax module, and
 a user's yaml names.
 
 A family is added as a file beside ``llama.py``, ``zaya.py``,
-``nemotron_h.py``, ``glm_moe_lite.py`` and ``qwen3_next.py`` that edits no
-shared file: a
+``nemotron_h.py``, ``glm_moe_lite.py``, ``qwen3_next.py`` and
+``longcat_flash.py`` that edits no shared file: a
 configuration (``PRESETS``, ``YAML_FIELDS`` and a ``from_args`` over
 :func:`preset_from_args`), a block under the protocol at the top of
 ``causal_lm.py`` built from ``layers.py``, a ``class
@@ -21,7 +21,8 @@ from typing import Any, Optional
 def config_from_args(args: Any, vocab_size: Optional[int] = None):
     """``ZayaConfig`` for ``model: zaya``, ``NemotronHConfig`` for ``model:
     nemotron_h``, ``GlmMoeLiteConfig`` for ``model: glm4_moe_lite``,
-    ``Qwen3NextConfig`` for ``model: qwen3_next``, else ``LlamaConfig``."""
+    ``Qwen3NextConfig`` for ``model: qwen3_next``, ``LongcatFlashConfig``
+    for ``model: longcat_flash``, else ``LlamaConfig``."""
     model = str(getattr(args, "model", "")).lower()
     if model == "zaya":
         from fedml_tpu.models.llm.zaya import ZayaConfig
@@ -39,6 +40,10 @@ def config_from_args(args: Any, vocab_size: Optional[int] = None):
         from fedml_tpu.models.llm.qwen3_next import Qwen3NextConfig
 
         return Qwen3NextConfig.from_args(args, vocab_size=vocab_size)
+    if model == "longcat_flash":
+        from fedml_tpu.models.llm.longcat_flash import LongcatFlashConfig
+
+        return LongcatFlashConfig.from_args(args, vocab_size=vocab_size)
     from fedml_tpu.models.llm.llama import LlamaConfig
 
     return LlamaConfig.from_args(args, vocab_size=vocab_size)

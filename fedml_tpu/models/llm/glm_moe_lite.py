@@ -25,8 +25,9 @@ here, not the published ``[nope | rope]``: :func:`layers.apply_rope` turns
 the leading lanes of whole heads in one pass and lets the rest through. A
 score is a sum over lanes, so the order changes nothing as long as ``q``
 and ``k`` agree; the map from a published checkpoint is a fixed permutation
-of ``W_qb``'s columns (:func:`published_lanes`) and the key is assembled
-``[k_r | k_nope]``. ``W_kvb``'s columns keep the published order.
+of ``W_qb``'s columns (``layers.published_lanes``) and the key is assembled
+``[k_r | k_nope]``. ``W_kvb``'s columns keep the published order. Steps
+1-4 are ``layers.LatentAttention``, which ``longcat_flash`` shares.
 
 dense FFN (layers before ``first_k_dense_replace``)
 5. ``(silu(u W_g) * (u W_u)) W_d`` at ``intermediate_size``.
@@ -65,18 +66,15 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from fedml_tpu.models.llm import preset_from_args
 from fedml_tpu.models.llm.causal_lm import CausalLM
-from fedml_tpu.models.llm.layers import (GatedExperts, RMSNorm, SwiGLU,
-                                         apply_rope, causal_attention,
-                                         choice_weights, lora_dense,
-                                         merge_heads, sigmoid_topk)
+from fedml_tpu.models.llm.layers import (GatedExperts, LatentAttention,
+                                         RMSNorm, SwiGLU, choice_weights,
+                                         sigmoid_topk)
+# the lane map by the name the glm-4.7-flash configuration's file gives it
+from fedml_tpu.models.llm.layers import published_lanes  # noqa: F401
 from fedml_tpu.ops import grouped_matmul as gmm
-from fedml_tpu.telemetry import get_tracer
-
-LANE_ORDER = "rope|nope"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,9 +141,6 @@ class GlmMoeLiteConfig:
                 (self.num_key_value_heads != self.num_attention_heads,
                  "fewer key-value heads than query heads (every head's keys "
                  "and values come from the one latent)"),
-                (self.v_head_dim != self.head_dim,
-                 "a value head of another size than the score lanes' "
-                 "(nope + rope): the attention product takes one head size"),
                 (self.qk_rope_head_dim % 2, "an odd rotary width"),
                 (self.rope_scaling is not None, "rope_scaling"),
                 (self.hidden_act != "silu", "an activation other than silu"),
@@ -241,56 +236,6 @@ class GlmMoeLiteConfig:
         return preset_from_args(cls, args, vocab_size)
 
 
-def published_lanes(cfg: GlmMoeLiteConfig) -> np.ndarray:
-    """``W_qb``'s columns as published from the columns as they lie here:
-    ``published = here[:, published_lanes(cfg)]`` (a head ``[nope | rope]``
-    there, ``[rope | nope]`` here); what a loader of a public checkpoint
-    applies backwards, and what the parity test applies."""
-    rope, d = cfg.qk_rope_head_dim, cfg.head_dim
-    head = np.concatenate([np.arange(rope, d), np.arange(rope)])
-    return (np.arange(cfg.num_attention_heads)[:, None] * d + head).reshape(-1)
-
-
-class GlmMoeLiteAttention(nn.Module):
-    """Steps 1-4."""
-
-    cfg: GlmMoeLiteConfig
-
-    @nn.compact
-    def __call__(self, u, cos, sin, attention_fn=None):
-        cfg = self.cfg
-        b, t, _ = u.shape
-        h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                             cfg.qk_rope_head_dim, cfg.v_head_dim)
-        d, lat = cfg.head_dim, cfg.kv_lora_rank
-        get_tracer().event(
-            "mla/plan", rows=b * t, heads=h, q_latent=cfg.q_lora_rank,
-            kv_latent=lat, nope=nope, rope=rope, v_dim=dv,
-            lane_order=LANE_ORDER, dtype=jnp.dtype(cfg.dtype).name)
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-        c_q = norm("q_a_norm")(lora_dense(
-            cfg, cfg.q_lora_rank, "q_a_proj", ("embed", None))(u))
-        q = lora_dense(cfg, h * d, "q_b_proj", (None, "heads"))(c_q)
-        kv_a = lora_dense(cfg, lat + rope, "kv_a_proj", ("embed", None))(u)
-        with jax.named_scope("mla_assemble"):
-            c_kv, k_r = kv_a[..., :lat], kv_a[..., lat:]
-        kv = lora_dense(cfg, h * (nope + dv), "kv_b_proj", (None, "heads"))(
-            norm("kv_a_norm")(c_kv))
-        with jax.named_scope("attn_layout"):
-            q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
-        q = apply_rope(q, cos, sin)        # the leading ``rope`` lanes
-        with jax.named_scope("mla_assemble"):
-            kv = kv.reshape(b, t, h, nope + dv).transpose(0, 2, 1, 3)
-            k_nope, v = kv[..., :nope], kv[..., nope:]
-            # one rotary key a token, turned once, seen by every head
-            k_r = apply_rope(k_r[:, None], cos, sin)           # [B, 1, T, r]
-            k = jnp.concatenate(
-                [jnp.broadcast_to(k_r, (b, h, t, rope)), k_nope], axis=-1)
-        out = merge_heads(causal_attention(q, k, v, cfg, attention_fn))
-        return lora_dense(cfg, cfg.hidden_size, "o_proj", ("heads", "embed"))(
-            out)
-
-
 class GlmMoeLiteMoE(nn.Module):
     """Steps 6-8; also what the layer counted."""
 
@@ -310,8 +255,7 @@ class GlmMoeLiteMoE(nn.Module):
             xs = gmm.dispatch(u.reshape(b * t, hid), layout)
         ys = GatedExperts(cfg, e, name="experts")(xs, layout)
         with jax.named_scope("moe_combine"):
-            mine = gmm.combine(ys, layout).astype(jnp.float32)   # [m, k, hid]
-            routed = jnp.sum(mine * w[..., None], axis=1).astype(
+            routed = gmm.combine_weighted(ys, layout, w).astype(
                 cfg.dtype).reshape(b, t, hid)
         out = routed + SwiGLU(
             cfg, cfg.moe_intermediate_size * cfg.n_shared_experts,
@@ -342,7 +286,7 @@ class GlmMoeLiteBlock(nn.Module):
                 "the up-projections absorbed into q and o); training only")
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-        x = x + GlmMoeLiteAttention(cfg, name="attn")(
+        x = x + LatentAttention(cfg, name="attn")(
             norm("input_norm")(x), cos, sin, attention_fn)
         u, stats = norm("post_attn_norm")(x), None
         if self.dense:

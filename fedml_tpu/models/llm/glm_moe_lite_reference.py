@@ -42,7 +42,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig, published_lanes
+from fedml_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig
+from fedml_tpu.models.llm.layers import published_lanes
 
 
 def published(cfg: GlmMoeLiteConfig, params):

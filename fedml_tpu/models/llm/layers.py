@@ -1,11 +1,11 @@
 """What every causal-LM family of the LLM path is built from: RMSNorm (and
 its zero-centred form), rotary tables and their application, a dense layer
 with an optional low-rank adapter (its base may be stored quantized), the
-choice of the causal attention product, the SwiGLU of a dense FFN, and
-what the families with routed experts share (a stack of expert matrices,
-the gated experts over ``ops/grouped_matmul.py``, the sigmoid and the
-softmax top-k routers). A family's file imports these and no other
-family's file."""
+choice of the causal attention product, low-rank latent attention (MLA),
+the SwiGLU of a dense FFN, and what the families with routed experts share
+(a stack of expert matrices, the gated experts over
+``ops/grouped_matmul.py``, the sigmoid and the softmax top-k routers). A
+family's file imports these and no other family's file."""
 from __future__ import annotations
 
 import math
@@ -23,14 +23,21 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` in float32, rounded once to
+    ``dtype``; ``gain`` is a fixed factor of the model's (a latent's scale
+    in ``LatentAttention``), not a parameter."""
+
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    gain: float = 1.0
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + self.eps)
+        if self.gain != 1.0:
+            normed = normed * self.gain
         return (normed * scale).astype(self.dtype)
 
 
@@ -273,6 +280,91 @@ def merge_heads(out: jax.Array) -> jax.Array:
         return out.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
+# a head's score lanes as they lie in ``LatentAttention``
+LATENT_LANE_ORDER = "rope|nope"
+
+
+def published_lanes(cfg) -> np.ndarray:
+    """``W_qb``'s columns as published from the columns as they lie in
+    :class:`LatentAttention`: ``published = here[:, published_lanes(cfg)]``
+    (a head ``[nope | rope]`` there, ``[rope | nope]`` here); what a loader
+    of a public checkpoint applies backwards, and what the parity tests
+    apply."""
+    rope, d = cfg.qk_rope_head_dim, cfg.head_dim
+    head = np.concatenate([np.arange(rope, d), np.arange(rope)])
+    return (np.arange(cfg.num_attention_heads)[:, None] * d + head).reshape(-1)
+
+
+class LatentAttention(nn.Module):
+    """Low-rank latent attention (MLA) over ``cfg``'s fields
+    (``num_attention_heads``, ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``, ``head_dim``
+    = nope + rope). With ``u`` the normed stream and ``H`` heads:
+
+    1. ``c_q = RMSNorm(u W_qa)``; ``q = q_scale (c_q W_qb)`` as ``[T, H,
+       rope | nope]``;
+    2. ``[c_kv | k_r] = u W_kva``; ``c_kv <- kv_scale RMSNorm(c_kv)``;
+       ``[k_nope | v] = c_kv W_kvb`` as ``[T, H, nope | v]``;
+    3. rotary embedding on ``q``'s ``rope`` lanes and on the ONE ``k_r``;
+       ``k = [k_r | k_nope]`` with ``k_r`` the same for all ``H`` heads;
+    4. ``o = softmax(q k^T / sqrt(nope + rope) + causal) v`` through the
+       trainer's attention product (the flash kernels take a value head
+       narrower than the score lanes), then ``W_o``.
+
+    A head's score lanes lie ``[rope | nope]`` (``LATENT_LANE_ORDER``), not
+    the published ``[nope | rope]``: :func:`apply_rope` turns the leading
+    lanes of whole heads in one pass. A score is a sum over lanes, so the
+    order changes nothing as long as ``q`` and ``k`` agree; the map from a
+    published checkpoint is a fixed permutation of ``W_qb``'s columns
+    (:func:`published_lanes`). ``W_kvb``'s columns keep the published order.
+    ``q_scale`` and ``kv_scale`` are fixed factors of the model (1: none);
+    every projection carries its adapter."""
+
+    cfg: Any
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, u, cos, sin, attention_fn=None):
+        cfg = self.cfg
+        b, t, _ = u.shape
+        h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+        d, lat = cfg.head_dim, cfg.kv_lora_rank
+        # the fixed scales are in the event where the model has any
+        scales = ({} if self.q_scale == self.kv_scale == 1.0 else
+                  {"q_scale": self.q_scale, "kv_scale": self.kv_scale})
+        get_tracer().event(
+            "mla/plan", rows=b * t, heads=h, q_latent=cfg.q_lora_rank,
+            kv_latent=lat, nope=nope, rope=rope, v_dim=dv,
+            lane_order=LATENT_LANE_ORDER, dtype=jnp.dtype(cfg.dtype).name,
+            **scales)
+        c_q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_a_norm")(
+            lora_dense(cfg, cfg.q_lora_rank, "q_a_proj", ("embed", None))(u))
+        q = lora_dense(cfg, h * d, "q_b_proj", (None, "heads"))(c_q)
+        kv_a = lora_dense(cfg, lat + rope, "kv_a_proj", ("embed", None))(u)
+        with jax.named_scope("mla_assemble"):
+            c_kv, k_r = kv_a[..., :lat], kv_a[..., lat:]
+            if self.q_scale != 1.0:
+                q = (q.astype(jnp.float32) * self.q_scale).astype(cfg.dtype)
+        kv = lora_dense(cfg, h * (nope + dv), "kv_b_proj", (None, "heads"))(
+            RMSNorm(cfg.rms_norm_eps, cfg.dtype, self.kv_scale,
+                    name="kv_a_norm")(c_kv))
+        with jax.named_scope("attn_layout"):
+            q = q.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+        q = apply_rope(q, cos, sin)        # the leading ``rope`` lanes
+        with jax.named_scope("mla_assemble"):
+            kv = kv.reshape(b, t, h, nope + dv).transpose(0, 2, 1, 3)
+            k_nope, v = kv[..., :nope], kv[..., nope:]
+            # one rotary key a token, turned once, seen by every head
+            k_r = apply_rope(k_r[:, None], cos, sin)           # [B, 1, T, r]
+            k = jnp.concatenate(
+                [jnp.broadcast_to(k_r, (b, h, t, rope)), k_nope], axis=-1)
+        out = merge_heads(causal_attention(q, k, v, cfg, attention_fn))
+        return lora_dense(cfg, cfg.hidden_size, "o_proj", ("heads", "embed"))(
+            out)
+
+
 class SwiGLU(nn.Module):
     """``(silu(u W_g) * (u W_u)) W_d`` at ``width``: a dense layer's FFN or
     a shared expert. Frozen under LoRA: the adapters sit on the attention
@@ -358,7 +450,8 @@ def sigmoid_topk(module, u, total: int, k: int, scale: float):
     return chosen, weights
 
 
-def softmax_topk(module, u, total: int, k: int):
+def softmax_topk(module, u, total: int, k: int, *, bias: bool = False,
+                 renormalize: bool = True, scale: float = 1.0):
     """A token's ``k`` of ``total`` experts by a softmax over the router's
     logits, under the scope ``router``; the float32 parameter
     ``router_weight`` ``[hidden, total]`` is ``module``'s. ``p =
@@ -366,25 +459,38 @@ def softmax_topk(module, u, total: int, k: int):
     ``p`` (a tie goes to the lower index) and ``weights [m, total]`` =
     ``p`` at the chosen experts over their sum (``norm_topk_prob``), zero
     elsewhere: the same dense table as :func:`sigmoid_topk`'s, so
-    :func:`choice_weights` reads either."""
+    :func:`choice_weights` reads either.
+
+    ``bias``: ``chosen`` is the largest of ``p + b`` with ``b`` the float32
+    parameter ``router_bias`` ``[total]`` (a selection bias: it picks, it
+    does not weigh). ``renormalize`` off: the weights are the chosen ``p``
+    themselves. Either way they are times ``scale``."""
     hid = u.shape[-1]
     gate = module.param(
         "router_weight", nn.with_logical_partitioning(
             nn.initializers.normal(1.0 / math.sqrt(hid)), ("embed", None)),
         (hid, total), jnp.float32)
+    selection = module.param("router_bias", nn.initializers.zeros, (total,),
+                             jnp.float32) if bias else None
     with jax.named_scope("router"):
         # float32 at full precision: a rounded logit is a token sent to
         # another expert
         p = jax.nn.softmax(jnp.matmul(
             u.reshape(-1, hid).astype(jnp.float32), gate,
             precision=HIGHEST), axis=-1)
-        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(p), k)   # [m, k]
+        score = jax.lax.stop_gradient(p)
+        if selection is not None:
+            score = score + selection
+        _, chosen = jax.lax.top_k(score, k)                       # [m, k]
         chosen = chosen.astype(jnp.int32)
         picked = jnp.any(
             chosen[:, :, None] == jnp.arange(total, dtype=jnp.int32),
             axis=1)
-        kept = jnp.where(picked, p, 0.0)
-        weights = kept / jnp.sum(kept, -1, keepdims=True)
+        weights = jnp.where(picked, p, 0.0)
+        if renormalize:
+            weights = weights / jnp.sum(weights, -1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
     return chosen, weights
 
 

@@ -394,8 +394,7 @@ class NemotronHMoE(nn.Module):
                               layout)
         ys = NemotronHExperts(cfg, name="experts")(xs, layout)
         with jax.named_scope("moe_combine"):
-            mine = gmm.combine(ys, layout).astype(jnp.float32)     # [m, k, lat]
-            routed = jnp.sum(mine * w_held[..., None], axis=1).astype(
+            routed = gmm.combine_weighted(ys, layout, w_held).astype(
                 cfg.dtype).reshape(b, t, cfg.moe_latent_size)
         out = lora_dense(cfg, hid, "latent_out", ("mlp", "embed"),
                          adapters=False)(routed)

@@ -400,8 +400,7 @@ class Qwen3NextMoE(nn.Module):
             xs = gmm.dispatch(u.reshape(b * t, hid), layout)
         ys = GatedExperts(cfg, held, name="experts")(xs, layout)
         with jax.named_scope("moe_combine"):
-            mine = gmm.combine(ys, layout).astype(jnp.float32)   # [m, k, hid]
-            routed = jnp.sum(mine * w_held[..., None], axis=1).astype(
+            routed = gmm.combine_weighted(ys, layout, w_held).astype(
                 cfg.dtype).reshape(b, t, hid)
         shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,
                         name="shared")(u)

@@ -75,7 +75,7 @@ def create(args: Any, output_dim: int = 10) -> nn.Module:
             return RNNStackOverflow(vocab_size=max(output_dim, 4))
         return RNNOriginalFedAvg(vocab_size=max(output_dim, 4))
     if name in ("llama", "llama_lora", "transformer", "zaya", "nemotron_h",
-                "glm4_moe_lite", "qwen3_next"):
+                "glm4_moe_lite", "qwen3_next", "longcat_flash"):
         from fedml_tpu.models.llm import config_from_args
 
         return config_from_args(args, vocab_size=max(output_dim, 32)).module()

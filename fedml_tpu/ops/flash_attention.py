@@ -31,6 +31,11 @@ Design notes (pallas_guide.md):
   of K/V and of the q-side blocks, fewer statistics updates;
 - a model calls the kernels once a layer with one signature, so ``_fwd`` and
   ``_bwd`` are ``jit``s: traced once and lowered once a program;
+- a value head may be narrower than the score lanes (latent attention:
+  192 score lanes, 128 value lanes): q and k are ``[.., dk]``, v, the
+  output and its cotangent ``[.., dv]``, and each block, accumulator and
+  gradient is as wide as what it holds. Where ``dk == dv`` every spec and
+  scratch is what one head size gave;
 - off-TPU (CPU tests) the same kernels run under ``interpret=True``;
   which form a call gets is decided in ``ops/dispatch.py``.
 
@@ -123,11 +128,14 @@ def _block(n: int, d: int, unaligned: int) -> int:
     9 products at the bf16 peak (the same call at ``[1, 32, 4096, 64]``
     1.309 / 1.333 / 1.808 ms = 35.3 %, at 32 / 4 heads of 128 1.317 /
     1.322 / 1.818 ms = 70.4 %; PERF.md, PR 37).
+    A head that is no power of two (192 score lanes) gets the cap of the
+    next one up, so a block still divides a sequence of whole 1024s.
     A sequence that is no multiple of ``_SUB`` keeps the former blocks.
     """
     if n % _SUB != 0:
         return unaligned
     cap = _BLOCK_CAP * 128 // max(d, 128)
+    cap = 1 << (cap.bit_length() - 1)
     if n <= cap:
         return n
     return next((b for b in (cap, cap // 2, cap // 4) if n % b == 0), cap)
@@ -227,15 +235,16 @@ def causal_plan(t: int, s: int, block_q: int, block_k: int,
     }
 
 
-def _geometry(kernels, q, k, block_q, block_k, causal) -> _Geometry:
+def _geometry(kernels, q, k, v, block_q, block_k, causal) -> _Geometry:
     """The call's schedule, and one ``kernel/flash/plan`` event for each of
     ``kernels`` (this runs when a kernel is traced, never in a round)."""
     (_, h, t, d), (_, hkv, s, _) = q.shape, k.shape
     plan = causal_plan(t, s, block_q, block_k, causal)
     for kernel in kernels:
         get_tracer().event(
-            "kernel/flash/plan", kernel=kernel, t=t, s=s, d=d, heads=h,
-            kv_heads=hkv, **{name: plan[name] for name in _PLAN_COUNTS})
+            "kernel/flash/plan", kernel=kernel, t=t, s=s, d=d,
+            v_dim=v.shape[-1], heads=h, kv_heads=hkv,
+            **{name: plan[name] for name in _PLAN_COUNTS})
     return plan["geometry"]
 
 
@@ -416,18 +425,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_i, l_i, *,
         lse_ref[0, 0] = m_i[:, :1] + jnp.log(l)
 
 
-def _specs(g: _Geometry, group: int, d: int):
-    """BlockSpecs of a ``(b, h, q_steps, kv_steps)`` grid (fwd, dq): the kv
-    index stops at the last block the q block needs."""
-    q_spec = pl.BlockSpec((1, 1, g.block_q, d),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, g.block_k, d),
-        lambda bi, hi, qi, ki: (bi, hi // group,
-                                jnp.minimum(ki, _last_kv(g, qi)), 0))
-    stat_spec = pl.BlockSpec((1, 1, g.block_q, 1),
-                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    return q_spec, kv_spec, stat_spec
+def _specs(g: _Geometry, group: int, d: int, dv: int):
+    """BlockSpecs of a ``(b, h, q_steps, kv_steps)`` grid (fwd, dq): q-side
+    and kv-side blocks at the score lanes ``d`` and at the value lanes
+    ``dv``, and the row statistic; the kv index stops at the last block the
+    q block needs."""
+    def q_side(width):
+        return pl.BlockSpec((1, 1, g.block_q, width),
+                            lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+
+    def kv_side(width):
+        return pl.BlockSpec(
+            (1, 1, g.block_k, width),
+            lambda bi, hi, qi, ki: (bi, hi // group,
+                                    jnp.minimum(ki, _last_kv(g, qi)), 0))
+
+    return q_side(d), kv_side(d), q_side(dv), kv_side(dv), q_side(1)
 
 
 # A model calls the kernels once a layer with one signature: under ``jit``
@@ -435,22 +448,23 @@ def _specs(g: _Geometry, group: int, d: int):
 # called from every layer), not once a layer.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    b, h, t, d = q.shape
-    _, hkv, s, _ = k.shape
-    g = _geometry(("flash_fwd",), q, k, block_q, block_k, causal)
-    q_spec, kv_spec, lse_spec = _specs(g, h // hkv, d)
+    b, h, t, _ = q.shape
+    _, hkv, s, dv = v.shape
+    g = _geometry(("flash_fwd",), q, k, v, block_q, block_k, causal)
+    q_spec, k_spec, o_spec, v_spec, lse_spec = _specs(
+        g, h // hkv, q.shape[-1], dv)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, g=g),
         grid=(b, h, g.q_steps, g.kv_steps),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, lse_spec],
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=[o_spec, lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g.block_q, d), jnp.float32),
+            pltpu.VMEM((g.block_q, dv), jnp.float32),
             pltpu.VMEM((g.block_q, 128), jnp.float32),
             pltpu.VMEM((g.block_q, 128), jnp.float32),
         ],
@@ -538,10 +552,10 @@ def _bwd_dkv_kernel(*refs, sm_scale, g: _Geometry):
 def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, out, lse = res
     b, h, t, d = q.shape
-    _, hkv, s, _ = k.shape
+    _, hkv, s, dv = v.shape
     group = h // hkv
-    g = _geometry(("flash_bwd_dq", "flash_bwd_dkv"), q, k, block_q, block_k,
-                  causal)
+    g = _geometry(("flash_bwd_dq", "flash_bwd_dkv"), q, k, v, block_q,
+                  block_k, causal)
     bq, bk = g.block_q, g.block_k
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
@@ -550,11 +564,11 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     def scratch(shape):
         return pltpu.VMEM(shape, jnp.float32)
 
-    q_spec, kv_spec, lse_spec = _specs(g, group, d)
+    q_spec, k_spec, do_spec, v_spec, lse_spec = _specs(g, group, d, dv)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, g=g),
         grid=(b, h, g.q_steps, g.kv_steps),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, lse_spec, lse_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, lse_spec, lse_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[scratch((bq, d))],
@@ -569,27 +583,36 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     def q_map(bi, hi, ki, qi):
         return (bi, hi, jnp.maximum(qi, _first_q(g, ki)), 0)
 
+    def kv_map(bi, hi, ki, qi):
+        return (bi, hi // group, ki, 0)
+
+    def out_map(bi, hi, ki, qi):
+        return (bi, hi, ki, 0)
+
     kq_spec = pl.BlockSpec((1, 1, bq, d), q_map)
-    kkv_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi // group, ki, 0))
+    kdo_spec = pl.BlockSpec((1, 1, bq, dv), q_map)
+    kk_spec = pl.BlockSpec((1, 1, bk, d), kv_map)
+    kv_spec = pl.BlockSpec((1, 1, bk, dv), kv_map)
     klse_spec = pl.BlockSpec((1, 1, bq, 1), q_map)
-    kout_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0))
+    dk_spec = pl.BlockSpec((1, 1, bk, d), out_map)
+    dv_spec = pl.BlockSpec((1, 1, bk, dv), out_map)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, g=g),
         grid=(b, h, g.kv_steps, g.q_steps),
-        in_specs=[kq_spec, kkv_spec, kkv_spec, kq_spec, klse_spec, klse_spec],
-        out_specs=[kout_spec, kout_spec],
+        in_specs=[kq_spec, kk_spec, kv_spec, kdo_spec, klse_spec, klse_spec],
+        out_specs=[dk_spec, dv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, s, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s, dv), jnp.float32),
         ],
-        scratch_shapes=[scratch((bk, d)), scratch((bk, d))],
+        scratch_shapes=[scratch((bk, d)), scratch((bk, dv))],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     dk = dk_h.reshape(b, hkv, group, s, d).sum(axis=2).astype(k.dtype)
-    dv = dv_h.reshape(b, hkv, group, s, d).sum(axis=2).astype(v.dtype)
-    return dq, dk, dv
+    return dq, dk, dv_h.reshape(b, hkv, group, s, dv).sum(axis=2).astype(
+        v.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -617,7 +640,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def reference_attention(q, k, v, causal: bool = True,
                         sm_scale: Optional[float] = None):
-    """Plain-XLA attention (numerics oracle + CPU fallback). [B,H,T,D] layout."""
+    """Plain-XLA attention (numerics oracle + CPU fallback). q, k
+    ``[B, H(kv), T, D]``, v ``[B, Hkv, S, Dv]``; the output is ``[B, H, T,
+    Dv]``."""
     b, h, t, d = q.shape
     _, hkv, s_len, _ = k.shape
     if hkv != h:
@@ -644,10 +669,12 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Tiled online-softmax attention. q: [B,H,T,D]; k/v: [B,Hkv,S,D].
+    """Tiled online-softmax attention. q: [B,H,T,D]; k: [B,Hkv,S,D];
+    v: [B,Hkv,S,Dv] (``Dv`` may be narrower than ``D``); out: [B,H,T,Dv].
 
     ``block_q`` / ``block_k`` are the grid's blocks (clipped to T / S); a
-    call that names none gets :func:`_block`'s, sized on the chip.
+    call that names none gets :func:`_block`'s, sized on the chip by the
+    wider of the two head sizes.
 
     ``interpret=None`` (model code): the compiled kernels on a TPU, the
     plain-XLA reference elsewhere. ``interpret=True`` runs the kernels
@@ -660,7 +687,7 @@ def flash_attention(
     mode = kernel_mode(interpret, off_tpu=REFERENCE)
     if mode == REFERENCE:
         return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    d = q.shape[-1]
+    d = max(q.shape[-1], v.shape[-1])
     return _flash(q, k, v, sm_scale, causal,
                   block_q or _block(q.shape[2], d, 512),
                   block_k or _block(k.shape[2], d, 1024),

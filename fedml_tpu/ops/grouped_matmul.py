@@ -245,6 +245,47 @@ def _combine_bwd(layout, g):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+@jax.custom_vjp
+def combine_weighted(y, layout: GroupLayout, w):
+    """Each token's held rows of the padded buffer weighed by ``w`` ``[m,
+    k]`` (float32) and summed over its ``k`` choices: ``[P, N] -> [m, N]``
+    in float32, an assignment that is not held here adding nothing. What
+    :func:`combine` and a weighted sum outside it compute, with no ``[m,
+    k, N]`` array either way: a choice at a time, ``k`` gathers of ``[m,
+    N]`` feed one running sum, and the transpose weighs each row's
+    cotangent as it gathers it. The way back of every top-k expert layer
+    (k 12 at N 6144: 1.2 GB a layer-step of float32 that the combine and an
+    outside sum would make, and its cotangent)."""
+    w = jnp.where(layout.held, w, 0.0)
+    out = None
+    for j in range(w.shape[1]):
+        part = w[:, j:j + 1] * y[layout.pos[:, j]].astype(jnp.float32)
+        out = part if out is None else out + part
+    return out
+
+
+def _combine_weighted_fwd(y, layout, w):
+    return combine_weighted(y, layout, w), (y, layout, w)
+
+
+def _combine_weighted_bwd(res, g):
+    y, layout, w = res
+    w = jnp.where(layout.held, w, 0.0)
+    k = w.shape[1]
+    # padding rows get an exact zero: the backward product reads them
+    row_w = jnp.where(layout.valid, w.reshape(-1)[layout.src], 0.0)
+    # the rows' cotangents gathered in the rows' own type: a float32
+    # gather would be a [P, N] float32 array of its own
+    dy = (row_w[:, None] * g.astype(y.dtype)[layout.src // k]).astype(
+        y.dtype)
+    dw = jnp.stack([jnp.sum(g * y[layout.pos[:, j]].astype(jnp.float32), -1)
+                    for j in range(k)], axis=1)
+    return dy, None, jnp.where(layout.held, dw, 0.0)
+
+
+combine_weighted.defvjp(_combine_weighted_fwd, _combine_weighted_bwd)
+
+
 # -- the kernel ------------------------------------------------------------
 def _kernel(tile_group, live_tiles, x_ref, w_hbm, *refs, transpose_rhs,
             activation, block_n):

@@ -177,7 +177,11 @@ class FedLLMAPI:
         read only those experts' matrices; ``tiles_per_run`` the live row
         tiles (``moe_tiles`` ``[expert layers]``, summed like ``moe_live``)
         over those triples: how many of a grouped product's tile products
-        an expert's weight copy has to hide under."""
+        an expert's weight copy has to hide under; ``buffer_live_share``
+        the rows that hold an assignment over the sorted buffer's static
+        rows (``capacity_rows`` a step and layer); ``zero_share``, where
+        the family counts ``moe_zero``, the share of all assignments that
+        went to an identity expert (no row, no product)."""
         static = cfg.moe_static
         counts = stats["moe_tokens"]
         layers, held = counts.shape
@@ -187,20 +191,27 @@ class FedLLMAPI:
         here = stats.get("moe_held", np.full(layers, assignments))
         placed = stats.get("moe_placed", per_layer)
         step_tokens = int(tokens) // max(int(steps), 1)
+        capacity = int(cfg.moe_capacity_rows(step_tokens))
+        extra = {}
+        if "moe_zero" in stats:
+            extra["zero_share"] = float(
+                stats["moe_zero"].sum() / (assignments * layers))
         return get_tracer().event(
             f"round/{round_idx}/moe",
             layers=int(layers), experts=int(static["experts"]),
             held=int(held), top_k=top_k, tokens=int(tokens),
             steps=int(steps), assignments=assignments,
             held_share=float(here.sum() / (assignments * layers)),
-            capacity_rows=int(cfg.moe_capacity_rows(step_tokens)),
+            capacity_rows=capacity,
+            buffer_live_share=float(
+                placed.sum() / (capacity * max(int(steps), 1) * layers)),
             max_over_mean=float(
                 (counts.max(axis=1) * held / np.maximum(per_layer, 1)).max()),
             live_share=float(
                 stats["moe_live"].sum() / (steps * layers * held)),
             tiles_per_run=float(
                 stats["moe_tiles"].sum() / max(stats["moe_live"].sum(), 1)),
-            dropped=int((here - placed).max()))
+            dropped=int((here - placed).max()), **extra)
 
     def train_one_round(self, round_idx: int) -> Dict:
         if self.on_device:

@@ -167,6 +167,55 @@ def test_kernels_match_reference_in_float32(t, heads, kv_heads, block_q,
         assert float(jnp.abs(a - b).max()) <= 1e-4, name
 
 
+# a value head narrower than the score lanes (latent attention: 192 score
+# lanes and 128 value lanes in ``longcat_flash``): as many key-value heads
+# as query heads and four query heads a key-value head, whole and ragged
+NARROW = [
+    # t, heads, kv_heads, block_q, block_k, d, dv
+    (512, 4, 4, 128, 256, 48, 32),
+    (512, 4, 1, 256, 128, 48, 32),
+    (200, 4, 4, 64, 64, 192, 128),
+    (384, 8, 2, 128, 128, 192, 128),
+]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("t,heads,kv_heads,block_q,block_k,d,dv", NARROW)
+def test_kernels_match_reference_at_a_narrower_value_head(
+        t, heads, kv_heads, block_q, block_k, d, dv, causal):
+    """q and k ``[.., d]``, v ``[.., dv]``: the output is ``[.., dv]``, and
+    it and the three gradients are ``reference_attention``'s in float32
+    under the interpreter (dq and dk at ``d`` lanes, dv at ``dv``)."""
+    q, k, _, _ = _qkv(t, heads, kv_heads, d, jnp.float32)
+    key = jax.random.key(9)
+    v = jax.random.normal(key, (1, kv_heads, t, dv))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (1, heads, t, dv))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=True,
+                               block_q=block_q, block_k=block_k)
+
+    def ref(q, k, v):
+        return reference_attention(q, k, v, causal=causal)
+
+    out = flash(q, k, v)
+    assert out.shape == (1, heads, t, dv)
+    assert float(jnp.abs(out - ref(q, k, v)).max()) <= 1e-4
+    got = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (ref(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b, width in zip(("dq", "dk", "dv"), got, want, (d, d, dv)):
+        assert a.shape[-1] == width, name
+        assert float(jnp.abs(a - b).max()) <= 1e-4, name
+
+
+def test_a_head_of_192_lanes_gets_blocks_of_1024():
+    """The cap on a block shrinks with the head past 128 lanes and is a
+    power of two: 192 lanes (and 256) get blocks of 1024 at T4096, which
+    divide the sequence; 128 lanes and fewer keep 2048."""
+    assert [_block(4096, d, 512) for d in (64, 128, 192, 256)] == [
+        2048, 2048, 1024, 1024]
+
+
 def test_kernels_match_reference_in_bfloat16_with_derived_blocks():
     q, k, v, w = _qkv(640, 4, 2, 64, jnp.bfloat16)  # one 640 block, 5 strips
 
@@ -207,7 +256,7 @@ def test_traced_kernels_leave_one_plan_event_each():
     plan = causal_plan(384, 384, 128, 256, True)
     for event in events:
         attrs = event["attrs"]
-        assert (attrs["t"], attrs["s"], attrs["d"], attrs["heads"],
-                attrs["kv_heads"]) == (384, 384, 32, 4, 2)
+        assert (attrs["t"], attrs["s"], attrs["d"], attrs["v_dim"],
+                attrs["heads"], attrs["kv_heads"]) == (384, 384, 32, 32, 4, 2)
         for key in ("visited", "masked", "unmasked", "skipped", "area_ratio"):
             assert attrs[key] == plan[key], key

@@ -15,12 +15,8 @@ import pytest
 from fedml_tpu import telemetry
 from fedml_tpu.models.llm import config_from_args
 from fedml_tpu.models.llm import glm_moe_lite_reference as ref
-from fedml_tpu.models.llm.glm_moe_lite import (
-    GlmMoeLiteAttention,
-    GlmMoeLiteConfig,
-    GlmMoeLiteMoE,
-    published_lanes,
-)
+from fedml_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig, GlmMoeLiteMoE
+from fedml_tpu.models.llm.layers import LatentAttention, published_lanes
 from fedml_tpu.ops import grouped_matmul as gmm
 from fedml_tpu.ops.flash_attention import flash_attention
 from fedml_tpu.train.llm.sharding import unbox
@@ -93,7 +89,7 @@ def test_the_tiny_preset_keeps_the_rows_shape_and_ratios():
 
 @pytest.mark.parametrize("bad", [
     {"tie_word_embeddings": True}, {"attention_bias": True},
-    {"num_key_value_heads": 2}, {"v_head_dim": 8}, {"qk_rope_head_dim": 3},
+    {"num_key_value_heads": 2}, {"topk_group": 2}, {"qk_rope_head_dim": 3},
     {"rope_scaling": {"type": "yarn"}}, {"hidden_act": "gelu"},
     {"topk_method": "greedy"}, {"n_group": 2}, {"norm_topk_prob": False},
     {"n_shared_experts": 2}, {"num_experts_per_tok": 9},
@@ -103,6 +99,21 @@ def test_what_is_not_implemented_is_refused(bad):
         bad = dict(bad, qk_nope_head_dim=13)
     with pytest.raises(ValueError, match="not implemented"):
         GlmMoeLiteConfig.tiny(**bad)
+
+
+def test_a_value_head_narrower_than_the_score_lanes_is_taken():
+    """Since the latent attention is shared with ``longcat_flash`` (192
+    score lanes, 128 value lanes), a narrower value head is no longer
+    refused: the attention's output projection takes ``H x v_head_dim``."""
+    cfg = GlmMoeLiteConfig.tiny(v_head_dim=8)
+    u = jnp.ones((1, 4, cfg.hidden_size))
+    cos, sin = (jnp.ones((4, cfg.rotary_dim // 2)),
+                jnp.zeros((4, cfg.rotary_dim // 2)))
+    module = LatentAttention(cfg)
+    params = unbox(module.init(jax.random.key(0), u, cos, sin))["params"]
+    assert params["o_proj"]["kernel"].shape == (
+        cfg.num_attention_heads * 8, cfg.hidden_size)
+    assert module.apply({"params": params}, u, cos, sin).shape == u.shape
 
 
 def test_the_published_lane_order_is_a_permutation_a_head():
@@ -260,7 +271,7 @@ def test_every_head_sees_the_one_rotary_key(f32):
         seen.update(q=q, k=k, v=v)
         return jnp.zeros_like(v)
 
-    module = GlmMoeLiteAttention(cfg)
+    module = LatentAttention(cfg)
     p = unbox(module.init(jax.random.key(3), u, cos, sin))
     module.apply(p, u, cos, sin, spy)
     q, k, v = seen["q"], seen["k"], seen["v"]

@@ -179,6 +179,15 @@ def _qwen3_next():
     return (cfg, *seeded(cfg))
 
 
+def _longcat_flash():
+    from fedml_tpu.models.llm.longcat_flash import LongcatFlashConfig
+    from tests.test_longcat_flash import seeded
+
+    cfg = LongcatFlashConfig.tiny(lora_rank=4, dtype=jnp.float32,
+                                  param_dtype=jnp.float32)
+    return (cfg, *seeded(cfg))
+
+
 @pytest.mark.parametrize("family, rtol", [
     (lambda: _llama(True), 0), (lambda: _llama(False), 0), (_zaya, 0),
     # one entry of 8,192 of the embedding's gradient is 17.8 here, and the
@@ -191,7 +200,9 @@ def _qwen3_next():
     # through the Gated DeltaNet layers, whose RMS norm of each head's
     # small output magnifies them (tests/test_qwen3_next.py)
     (_qwen3_next, 3e-4),
-], ids=["llama-tied", "llama-untied", "zaya", "glm4_moe_lite", "qwen3_next"])
+    (_longcat_flash, 0),
+], ids=["llama-tied", "llama-untied", "zaya", "glm4_moe_lite", "qwen3_next",
+        "longcat_flash"])
 def test_head_inputs_give_the_loss_the_default_calls_logits_give(family,
                                                                  rtol):
     """The default call returns the head's product in the compute type,

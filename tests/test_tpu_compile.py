@@ -82,6 +82,42 @@ def test_flash_attention_compiles_for_v5e(one_chip, backward, shape):
         assert name in line.split(" = ")[0]
 
 
+@pytest.mark.parametrize("hkv", [64, 8], ids=["mha", "gqa8"])
+def test_flash_attention_at_a_narrower_value_head_compiles_for_v5e(one_chip,
+                                                                   hkv):
+    """The three kernels at ``longcat_flash``'s latent attention: 64 heads
+    of 192 score lanes (no multiple of 128) and 128 value lanes at T4096,
+    blocks of 1024; and at 8 key-value heads. Each under its own name, the
+    output and dv at 128 lanes, dq and dk at 192."""
+    from fedml_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 64, 4096, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, hkv, 4096, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, hkv, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                  for line in calls) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                         "flash_fwd"]
+    fwd = next(line for line in calls if "flash_fwd" in line.split(" = ")[0])
+    assert "bf16[1,64,4096,128]" in fwd.split(" = ")[1].split(" custom")[0]
+    grads = compiled.out_info[1]
+    assert [g.shape for g in grads] == [(1, 64, 4096, 192),
+                                        (1, hkv, 4096, 192),
+                                        (1, hkv, 4096, 128)]
+
+
 @pytest.mark.parametrize("h,f", [(4096, 11008), (11008, 4096), (4096, 32000)])
 def test_dequant_matmul_compiles_for_v5e(one_chip, h, f):
     from fedml_tpu.ops.quant import pallas_dequant_matmul
@@ -488,6 +524,59 @@ def test_fused_round_qwen3_next_cell_compiles_for_v5e(topo, monkeypatch):
     assert " scatter(" not in text
     assert re.search(r'op_name="[^"]*/layer_2/linear_attn/jit\(_gated_delta\)',
                      text)
+
+
+def test_fused_round_longcat_flash_cell_compiles_for_v5e(topo, monkeypatch):
+    """``model: longcat_flash`` as ``longcat-flash-chat.round-4k-remat``
+    runs it (its configuration file through the benchmark family's
+    ``model_config``: 4 double layers, 16 of 512 FFN experts held beside
+    256 identity experts, 16,384 vocabulary rows, T4096, ``remat_policy:
+    full``): the flash kernels at 64 heads of 192 score and 128 value lanes
+    in both attentions of every double layer (forward, the recomputed
+    forward, backward), the grouped products at 6144 x 2048, no sort but
+    the routers' top-12 over 768, no scatter, no ``[m, k, hidden]`` float32
+    array of the weighted combine, and a plan that fills more than a
+    quarter of the chip and stays under 90 % of the allocator's 16.9e9."""
+    import importlib.util
+
+    from fedml_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "default_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "longcat-flash-chat.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "round-4k-remat.json")) as f:
+        traffic = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "longcat_flash_program", os.path.join(
+            root, "benchmarks", "families", "longcat_flash", "program.py"))
+    program = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(program)
+    cfg = program.model_config(config, traffic)
+    assert cfg.remat_policy == "full"
+    compiled = _compile_fused_round(topo.devices, 1, cfg=cfg, seq_len=4096)
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0.25 * 16.9e9 < resident < 0.9 * 16.9e9
+    assert mem.alias_size_in_bytes > 0.99 * mem.output_size_in_bytes
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    # 8 attentions: forward and recomputed forward, then the backward's
+    # two; 4 branches' gate, up and down products twice and their row
+    # gradients once
+    assert sorted(calls) == sorted(
+        ["flash_fwd"] * 16 + ["flash_bwd_dq", "flash_bwd_dkv"] * 8
+        + ["moe_gmm"] * 24 + ["moe_gmm_t"] * 12)
+    assert "bf16[1,64,4096,192]" in text and "bf16[1,64,4096,128]" in text
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert len(sorts) == 8 and all("/moe/router/top_k" in s for s in sorts)
+    assert " scatter(" not in text
+    assert not any(("f32", "4096,12,6144") in shapes
+                   for shapes, _ in _unfused(text))
 
 
 def _unfused(text):
